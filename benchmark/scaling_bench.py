@@ -89,10 +89,7 @@ def _dp_step_time(make_model, per_dev_batch, n_dev, iters, log,
     lr = 0.05
 
     if local_stats:
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def local_step(p, x, y):
             loss, grads = jax.value_and_grad(loss_fn)(p, x, y)
@@ -229,10 +226,7 @@ def build_tp_mlp(n):
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     d, h, b = 512, 2048, 256
     rng = onp.random.RandomState(0)

@@ -119,8 +119,9 @@ def random_seed(seed: int) -> None:
 
 def device_info() -> tuple:
     """(platform, device_count) of the default backend."""
-    from .base import safe_devices
-    devs = safe_devices()
+    import jax
+
+    devs = jax.devices()
     return devs[0].platform, len(devs)
 
 

@@ -214,7 +214,7 @@ def lint_callable(fn, *example_args, scope: str = "callable",
     import jax
 
     if enable_x64:
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(
                 fn, static_argnums=tuple(static_argnums))(*example_args)
     else:

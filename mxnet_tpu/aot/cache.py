@@ -45,7 +45,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
-from ..base import MXNetError, env_str, failsoft_call
+from ..base import MXNetError, env_str
 from ..resilience import chaos
 
 __all__ = [
@@ -173,39 +173,24 @@ def _backend_components() -> Dict[str, Any]:
     # the device probe (jax.devices + per-device attrs) is memoized —
     # this runs on the per-call dispatch path (CachedJit._sig) and a
     # full probe per served batch would be pure overhead. The memo is
-    # KEYED on the live jax.default_backend() (cheap: lru-cached inside
-    # jax): a mid-process fail-soft flip tpu→cpu re-probes instead of
-    # fingerprinting under the stale backend and quarantining healthy
-    # shared TPU entries. A down-backend probe ("?") is never memoized.
+    # keyed on jax.default_backend() (cheap: lru-cached inside jax);
+    # reset_backend_memo() drops it when the XLA client is rebuilt.
     global _backend_memo
-    try:
-        backend = failsoft_call(jax.default_backend)
-    except Exception:  # noqa: BLE001 — backend down: keyed as unknown
-        backend = "?"
+    backend = jax.default_backend()
     memo = _backend_memo
     if memo is not None and memo["backend"] == backend:
         return memo
-    try:
-        devs = failsoft_call(jax.devices)
-        kind = getattr(devs[0], "device_kind", "?")
-        n = len(devs)
-    except Exception:  # noqa: BLE001
-        kind, n = "?", 0
-    comps = {"backend": backend, "device_kind": str(kind), "n_devices": n}
-    if backend != "?":
-        _backend_memo = comps
-    return comps
+    devs = jax.devices()
+    _backend_memo = {"backend": backend,
+                     "device_kind": str(devs[0].device_kind),
+                     "n_devices": len(devs)}
+    return _backend_memo
 
 
 def _aval_of(x):
-    try:
-        from jax.api_util import shaped_abstractify
+    from jax.api_util import shaped_abstractify
 
-        return shaped_abstractify(x)
-    except Exception:  # noqa: BLE001 — older jax layouts
-        import jax.numpy as jnp
-
-        return jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x))
+    return shaped_abstractify(x)
 
 
 def _mesh_sig():
@@ -362,35 +347,26 @@ class CompileCache:
         # (armed by us or by base.py's import-time env arming): re-point
         # it, or this store's entries would publish while every backend
         # compile keeps hitting the old store's xla tier
-        try:
-            jax.config.update("jax_compilation_cache_dir", target)
-            _xla_armed_dir = target
-            # cache-everything write thresholds are an rw-store policy;
-            # an ro consumer arms the dir for READS of the baked xla
-            # tier and leaves jax's default write threshold alone (jax
-            # has no read-only cache mode — mount the dir read-only to
-            # forbid writes entirely)
-            if self.mode == "rw":
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.0)
-                try:
-                    jax.config.update(
-                        "jax_persistent_cache_min_entry_size_bytes", -1)
-                except Exception:  # noqa: BLE001 — knob absent, older jax
-                    pass
-            # jax initializes its compilation cache ONCE at the first
-            # compile; if this process already compiled something, the
-            # dir update above is a silent no-op until the cache object
-            # is reset (env-driven flows arm it at import in base.py —
-            # this is the programmatic-construction fallback)
-            try:
-                from jax._src import compilation_cache as _cc
+        jax.config.update("jax_compilation_cache_dir", target)
+        _xla_armed_dir = target
+        # cache-everything write thresholds are an rw-store policy; an ro
+        # consumer arms the dir for READS of the baked xla tier and
+        # leaves jax's default write threshold alone (jax has no
+        # read-only cache mode — mount the dir read-only to forbid
+        # writes entirely)
+        if self.mode == "rw":
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0.0)
+            jax.config.update(
+                "jax_persistent_cache_min_entry_size_bytes", -1)
+        # jax initializes its compilation cache ONCE at the first
+        # compile; if this process already compiled something, the dir
+        # update above is a silent no-op until the cache object is reset
+        # (env-driven flows arm it at import in base.py — this is the
+        # programmatic-construction path)
+        from jax.experimental.compilation_cache import compilation_cache
 
-                _cc.reset_cache()
-            except Exception:  # noqa: BLE001 — internal API drift
-                pass
-        except Exception:  # noqa: BLE001 — cache is an optimization
-            pass
+        compilation_cache.reset_cache()
 
     #: staging dirs younger than this are presumed to belong to a LIVE
     #: concurrent writer (a put() completes in seconds; an hour covers

@@ -37,7 +37,7 @@ import weakref
 
 import jax
 
-from .base import env_int, env_str, safe_devices
+from .base import env_int, env_str
 
 __all__ = ["waitall", "is_naive", "set_bulk_size", "bulk"]
 
@@ -133,7 +133,7 @@ def waitall() -> None:
     except Exception as e:
         if first_exc is None:
             first_exc = e
-    for d in safe_devices():
+    for d in jax.devices():
         try:
             jax.device_put(0, d).block_until_ready()
         except Exception:

@@ -107,14 +107,14 @@ def _sample(logits, key, greedy, temperature, top_k):
 _KV_CACHE_DTYPES = (None, "int8", "float32", "bfloat16", "float16")
 
 
-def _fused_state(cache_dtype) -> bool:
+def _fused_state() -> bool:
     """The fused-Pallas-decode arm state at program-build time — part of
     every paged program's cache key, so toggling
     ``MXNET_TPU_LLM_FUSED_DECODE`` between engines on one model never
     resurrects a program traced the other way."""
     from ...ops.pallas.fused_decode import fused_decode_armed
 
-    return bool(fused_decode_armed(kv_dtype=str(cache_dtype)))
+    return fused_decode_armed()
 
 
 def _resolve_cache_dtype(model, kv_cache_dtype):
@@ -436,7 +436,7 @@ def paged_decode_program(model, *, max_running, num_blocks, block_size,
     tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
     ckey = ("paged_decode", r, int(num_blocks), int(block_size), mb,
             bool(greedy), *tkey, cache_dtype, weight_dtype, bool(donate),
-            _fused_state(cache_dtype))
+            _fused_state())
     store, cached = _decode_cache(model, ckey)
     if cached is not None:
         return cached, params
@@ -561,7 +561,7 @@ def paged_suffix_prefill_program(model, *, suffix_len, num_blocks,
     tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
     ckey = ("paged_suffix", sb, int(num_blocks), bs, mb, bool(greedy),
             *tkey, cache_dtype, weight_dtype, bool(donate),
-            _fused_state(cache_dtype))
+            _fused_state())
     store, cached = _decode_cache(model, ckey)
     if cached is not None:
         return cached, params
@@ -698,7 +698,7 @@ def paged_spec_draft_program(model, *, max_running, draft_k, num_blocks,
     tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
     ckey = ("spec_draft", r, kk, int(num_blocks), int(block_size), mb,
             bool(greedy), *tkey, cache_dtype, weight_dtype, bool(donate),
-            _fused_state(cache_dtype))
+            _fused_state())
     store, cached = _decode_cache(model, ckey)
     if cached is not None:
         return cached, params
@@ -761,7 +761,7 @@ def paged_spec_verify_program(model, *, max_running, draft_k, num_blocks,
     tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
     ckey = ("spec_verify", r, kk, int(num_blocks), int(block_size), mb,
             bool(greedy), *tkey, cache_dtype, weight_dtype, bool(donate),
-            _fused_state(cache_dtype))
+            _fused_state())
     store, cached = _decode_cache(model, ckey)
     if cached is not None:
         return cached, params

@@ -191,8 +191,7 @@ class MultiHeadAttention(HybridBlock):
         units, heads = self._units, self._heads
         from ...ops.pallas import fused_decode as _fused
 
-        if self._fused_eligible() and _fused.fused_decode_armed(
-                kv_dtype=str(pool_k.dtype)):
+        if self._fused_eligible() and _fused.fused_decode_armed():
             return self._forward_step_paged_fused(
                 x, pool_k, pool_v, block_table, positions)
         proj = self.qkv(x)

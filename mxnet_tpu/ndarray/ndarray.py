@@ -23,8 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as onp
 
-from ..base import (MXNetError, dtype_from_any, bfloat16, failsoft_call,
-                    safe_devices)
+from ..base import MXNetError, dtype_from_any, bfloat16
 from ..context import Context, current_context
 from ..ops.dispatch import apply_op, autograd_state, is_recording
 
@@ -75,9 +74,7 @@ class ndarray:
             # float32 unless the caller asked for float64 explicitly
             if dt is None and data.dtype == onp.float64:
                 data = data.astype(onp.float32)
-        # failsoft: array creation can be the process's first backend
-        # touch — fall back to CPU instead of raising raw init errors
-        val = failsoft_call(jnp.asarray, data, dtype=dt)
+        val = jnp.asarray(data, dtype=dt)
         if ctx is not None:
             val = jax.device_put(val, ctx.jax_device)
         self._data = val
@@ -112,16 +109,16 @@ class ndarray:
         except Exception:  # tracer inside jit — context is abstract
             return current_context()
         if dev.platform == "cpu":
-            cpu_devs = [d for d in safe_devices() if d.platform == "cpu"]
+            cpu_devs = [d for d in jax.devices() if d.platform == "cpu"]
             try:
                 idx = cpu_devs.index(dev)
             except ValueError:
                 idx = 0
             # on the virtual-device CPU test rig, cpu devices double as tpus
-            if all(d.platform == "cpu" for d in safe_devices()):
+            if all(d.platform == "cpu" for d in jax.devices()):
                 return Context("tpu", idx) if idx else Context("cpu", 0)
             return Context("cpu", idx)
-        accel = [d for d in safe_devices() if d.platform != "cpu"]
+        accel = [d for d in jax.devices() if d.platform != "cpu"]
         return Context("tpu", accel.index(dev))
 
     context = ctx

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as onp
 
 from ..base import dtype_from_any, bfloat16, MXNetError
-from ..base import failsoft_call as _failsoft_call
 from ..context import Context, current_context
 from ..ndarray.ndarray import ndarray, _wrap, _unwrap
 from ..ops.dispatch import apply_op
@@ -83,10 +82,6 @@ def array(obj, dtype=None, ctx=None, device=None, copy=True):
 
 
 def _create(val, ctx=None):
-    # callables are evaluated here under the fail-soft guard: creation is
-    # often the process's first backend touch (VERDICT r4 weak #7)
-    if callable(val):
-        val = _failsoft_call(val)
     out = _wrap(val)
     if ctx is not None:
         out._data = jax.device_put(out._data, ctx.jax_device)
@@ -96,13 +91,13 @@ def _create(val, ctx=None):
 def zeros(shape, dtype=float32, ctx=None, device=None, order="C"):
     if isinstance(shape, int):
         shape = (shape,)
-    return _create(lambda: jnp.zeros(shape, dtype_from_any(dtype)), ctx or device)
+    return _create(jnp.zeros(shape, dtype_from_any(dtype)), ctx or device)
 
 
 def ones(shape, dtype=float32, ctx=None, device=None, order="C"):
     if isinstance(shape, int):
         shape = (shape,)
-    return _create(lambda: jnp.ones(shape, dtype_from_any(dtype)), ctx or device)
+    return _create(jnp.ones(shape, dtype_from_any(dtype)), ctx or device)
 
 
 def empty(shape, dtype=float32, ctx=None, device=None, order="C"):
@@ -114,7 +109,7 @@ def full(shape, fill_value, dtype=None, ctx=None, device=None):
         shape = (shape,)
     if isinstance(fill_value, ndarray):
         return _call(lambda f: jnp.full(shape, f, dtype and dtype_from_any(dtype)), (fill_value,), name="full")
-    return _create(lambda: jnp.full(shape, fill_value, dtype and dtype_from_any(dtype)), ctx or device)
+    return _create(jnp.full(shape, fill_value, dtype and dtype_from_any(dtype)), ctx or device)
 
 
 def zeros_like(a, dtype=None):
@@ -130,22 +125,22 @@ def full_like(a, fill_value, dtype=None):
 
 
 def arange(start, stop=None, step=1, dtype=None, ctx=None, device=None):
-    return _create(lambda: jnp.arange(start, stop, step, dtype and dtype_from_any(dtype)), ctx or device)
+    return _create(jnp.arange(start, stop, step, dtype and dtype_from_any(dtype)), ctx or device)
 
 
 def linspace(start, stop, num=50, endpoint=True, retstep=False, dtype=None, axis=0, ctx=None):
-    out = _failsoft_call(jnp.linspace, start, stop, num, endpoint=endpoint, retstep=retstep, dtype=dtype and dtype_from_any(dtype), axis=axis)
+    out = jnp.linspace(start, stop, num, endpoint=endpoint, retstep=retstep, dtype=dtype and dtype_from_any(dtype), axis=axis)
     if retstep:
         return _create(out[0], ctx), out[1]
     return _create(out, ctx)
 
 
 def logspace(start, stop, num=50, endpoint=True, base=10.0, dtype=None, ctx=None):
-    return _create(lambda: jnp.logspace(start, stop, num, endpoint, base, dtype and dtype_from_any(dtype)), ctx)
+    return _create(jnp.logspace(start, stop, num, endpoint, base, dtype and dtype_from_any(dtype)), ctx)
 
 
 def eye(N, M=None, k=0, dtype=float32, ctx=None):
-    return _create(lambda: jnp.eye(N, M, k, dtype_from_any(dtype)), ctx)
+    return _create(jnp.eye(N, M, k, dtype_from_any(dtype)), ctx)
 
 
 def identity(n, dtype=float32, ctx=None):
@@ -153,8 +148,7 @@ def identity(n, dtype=float32, ctx=None):
 
 
 def meshgrid(*xi, indexing="xy"):
-    outs = _failsoft_call(
-        lambda: jnp.meshgrid(*[_unwrap(x) for x in xi], indexing=indexing))
+    outs = jnp.meshgrid(*[_unwrap(x) for x in xi], indexing=indexing)
     return [_wrap(o) for o in outs]
 
 
@@ -173,7 +167,7 @@ def asarray(a, dtype=None, ctx=None):
 
 
 def frombuffer(buffer, dtype=float32, count=-1, offset=0):
-    return _create(lambda: jnp.asarray(
+    return _create(jnp.asarray(
         onp.frombuffer(buffer, onp.dtype(dtype), count, offset)))
 
 
@@ -194,7 +188,7 @@ def diagonal(a, offset=0, axis1=0, axis2=1):
 
 
 def tri(N, M=None, k=0, dtype=float32, ctx=None):
-    return _create(lambda: jnp.tri(N, M, k, dtype_from_any(dtype)), ctx)
+    return _create(jnp.tri(N, M, k, dtype_from_any(dtype)), ctx)
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as onp
 
 from ..base import dtype_from_any
-from ..base import failsoft_call as _failsoft_call
 from ..ndarray.ndarray import ndarray, _wrap, _unwrap
 
 __all__ = [
@@ -38,9 +37,7 @@ class _RNG(threading.local):
 
     def next_key(self):
         if self.key is None:
-            # often the process's FIRST backend touch (net.initialize())
-            # — fail-soft if the configured backend is unreachable
-            self.key = _failsoft_call(jax.random.PRNGKey, 0)
+            self.key = jax.random.PRNGKey(0)
         self.key, sub = jax.random.split(self.key)
         return sub
 

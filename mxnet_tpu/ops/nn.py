@@ -153,16 +153,11 @@ def stem_s2d_cache_key():
     may contain a convolution: ``_stem_s2d_wanted`` reads the
     ``MXNET_TPU_STEM_S2D`` knob and the active backend at TRACE time, so
     a cached executable is only valid while both still hold. Long-lived
-    serving processes make mid-process knob flips (equivalence tests,
-    fail-soft CPU fallback after a TPU trace) a real hazard rather than
-    a cosmetic one — cache keys must include this (ADVICE low #3).
-    ``jax.default_backend()`` is touched lazily: cache keys are built on
-    paths where the backend is already initialized."""
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — backend down: keyed as unknown
-        backend = "?"
-    return (os.environ.get("MXNET_TPU_STEM_S2D", "1"), backend)
+    serving processes make mid-process knob flips (equivalence tests) a
+    real hazard rather than a cosmetic one — cache keys must include
+    this."""
+    return (os.environ.get("MXNET_TPU_STEM_S2D", "1"),
+            jax.default_backend())
 
 
 def _stem_s2d_wanted(x, weight, ndim, stride, dilate, num_group, layout):
@@ -486,23 +481,6 @@ def batch_norm(
     return out, new_mean, new_var
 
 
-_PALLAS_NORM_STATE = {"ok": None}
-
-
-def _probe_once(state: dict, probe) -> bool:
-    """Memoized Mosaic compile probe: run ``probe()`` once per process;
-    any failure permanently selects the jnp fallback path. Probes must
-    cover a jitted call too — inside a hybridized trace a Mosaic reject
-    surfaces at outer-jit compile time where no fallback is possible."""
-    if state["ok"] is None:
-        try:
-            probe()
-            state["ok"] = True
-        except Exception:  # noqa: BLE001 — Mosaic quirk: jnp path instead
-            state["ok"] = False
-    return state["ok"]
-
-
 class _PallasDisabled(threading.local):
     def __init__(self):
         self.depth = 0
@@ -529,20 +507,23 @@ class no_pallas:
         return False
 
 
-def _pallas_norm_ok():
-    """One-time Mosaic compile probe for the fused norm kernels on this
-    backend; a failure permanently falls back to the jnp path."""
-    def probe():
-        from .pallas.layer_norm import fused_layer_norm
-        # probe BOTH extremes: the widest padded block the gate admits,
-        # and the minimal tile
-        fused_layer_norm(jnp.zeros((8, 128)), jnp.ones((128,)),
-                         jnp.zeros((128,)), 1e-5)
-        jax.jit(lambda x, g, b: fused_layer_norm(x, g, b, 1e-5))(
-            jnp.zeros((8, 8192)), jnp.ones((8192,)),
-            jnp.zeros((8192,))).block_until_ready()
+def _in_mesh_scope() -> bool:
+    from ..parallel.mesh import current_mesh
 
-    return _probe_once(_PALLAS_NORM_STATE, probe)
+    return current_mesh() is not None
+
+
+def _tpu_kernels_selected() -> bool:
+    """The one selection rule for the compiled Pallas kernels: the TPU
+    backend, outside :class:`no_pallas`, and outside a mesh scope. A
+    program traced inside ``parallel.use_mesh`` (``Trainer.shard``,
+    ``LLMEngine(mesh=)``) is partitioned by GSPMD, and the chip's
+    compiler refuses it with a kernel inside ("Mosaic kernels cannot be
+    automatically partitioned"); there the XLA-op paths are taken, which
+    the partitioner can split. (A ``shard_map`` over the head axis would
+    keep the attention kernels under ``tp``: ROADMAP S3.)"""
+    return (not _pallas_disabled.depth and jax.default_backend() == "tpu"
+            and not _in_mesh_scope())
 
 
 def layer_norm(x, gamma, beta, axis=-1, eps=1e-5):
@@ -555,16 +536,11 @@ def layer_norm(x, gamma, beta, axis=-1, eps=1e-5):
     if (ax == x.ndim - 1 and x.shape[-1] <= 8192
             and gamma.ndim == 1 and gamma.shape[0] == x.shape[-1]
             and beta.ndim == 1 and beta.shape[0] == x.shape[-1]
-            and not _pallas_disabled.depth
-            and jax.default_backend() == "tpu" and _pallas_norm_ok()):
+            and _tpu_kernels_selected()):
         from .pallas.layer_norm import fused_layer_norm
         shp = x.shape
-        try:
-            return fused_layer_norm(
-                x.reshape(-1, shp[-1]), gamma, beta,
-                float(eps)).reshape(shp)
-        except Exception:  # noqa: BLE001 — shape-specific Mosaic reject
-            pass  # fall through to the jnp path
+        return fused_layer_norm(
+            x.reshape(-1, shp[-1]), gamma, beta, float(eps)).reshape(shp)
     mean = jnp.mean(x, axis=axis, keepdims=True)
     var = jnp.var(x, axis=axis, keepdims=True)
     out = (x - mean) * lax.rsqrt(var + eps)
@@ -604,15 +580,11 @@ def rms_norm(x, gamma, axis=-1, eps=1e-6):
     if (ax == x.ndim - 1 and x.shape[-1] <= 8192
             and getattr(gamma, "ndim", 0) == 1
             and gamma.shape[0] == x.shape[-1]
-            and not _pallas_disabled.depth
-            and jax.default_backend() == "tpu" and _pallas_norm_ok()):
+            and _tpu_kernels_selected()):
         from .pallas.layer_norm import fused_rms_norm
         shp = x.shape
-        try:
-            return fused_rms_norm(
-                x.reshape(-1, shp[-1]), gamma, float(eps)).reshape(shp)
-        except Exception:  # noqa: BLE001 — shape-specific Mosaic reject
-            pass  # fall through to the jnp path
+        return fused_rms_norm(
+            x.reshape(-1, shp[-1]), gamma, float(eps)).reshape(shp)
     ms = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=axis, keepdims=True)
     out = x * lax.rsqrt(ms + eps).astype(x.dtype)
     return out * gamma
@@ -704,24 +676,6 @@ def log_softmax(x, axis=-1, temperature=None):
     return jax.nn.log_softmax(x, axis=axis)
 
 
-_PALLAS_CE_STATE = {"ok": None}
-
-
-def _pallas_ce_ok():
-    """One-time Mosaic compile probe for the fused online-lse CE kernel;
-    covers an UNALIGNED (N, V) — the historical reject case — and a
-    jitted call (see ``_probe_once``)."""
-    def probe():
-        from .pallas.cross_entropy import cross_entropy_with_logits
-        cross_entropy_with_logits(jnp.zeros((12, 1000)),
-                                  jnp.zeros((12,), jnp.int32))
-        jax.jit(cross_entropy_with_logits)(
-            jnp.zeros((8, 4096)),
-            jnp.zeros((8,), jnp.int32)).block_until_ready()
-
-    return _probe_once(_PALLAS_CE_STATE, probe)
-
-
 def softmax_cross_entropy(data, label, per_example=False):
     """Sparse-label softmax cross entropy (reference
     src/operator/loss_binary_op.cc:30 ``softmax_cross_entropy``).
@@ -743,15 +697,10 @@ def softmax_cross_entropy(data, label, per_example=False):
             f"softmax_cross_entropy expects (N, V) data and (N,) label, "
             f"got {data.shape} / {label.shape}")
     lab = label.astype(jnp.int32)
-    nll = None
-    if (not _pallas_disabled.depth
-            and jax.default_backend() == "tpu" and _pallas_ce_ok()):
+    if _tpu_kernels_selected():
         from .pallas.cross_entropy import cross_entropy_with_logits
-        try:
-            nll = cross_entropy_with_logits(data, lab)
-        except Exception:  # noqa: BLE001 — shape-specific Mosaic reject
-            pass  # fall through to the jnp path
-    if nll is None:
+        nll = cross_entropy_with_logits(data, lab)
+    else:
         x = data.astype(jnp.float32)
         lse = jax.scipy.special.logsumexp(x, axis=-1)
         picked = jnp.take_along_axis(x, jnp.clip(lab, 0, None)[:, None],
@@ -997,23 +946,34 @@ def interleaved_matmul_encdec_valatt(keys_values, attention, heads):
 _KV_SCALE_BYTES = 4
 
 
+# The scale's four bytes (little-endian, the f32's own bit pattern) are
+# taken apart and put together with 32-bit integer ops, not with a
+# 4 x int8 <-> f32 bitcast: Mosaic has no such bitcast, and these two
+# functions are the ONE definition of the layout — the Pallas kernels
+# (ops/pallas/paged_attention.py, fused_decode.py) call them inside the
+# kernel body.
 def kv_cache_quantize(t):
-    """(..., D) float -> (..., D+4) int8 [values | bitcast f32 scale]."""
-    amax = jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1, keepdims=True)
+    """(..., D) float -> (..., D+4) int8 [values | f32 scale bytes]."""
+    t = t.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(t), axis=-1, keepdims=True)
     scale = jnp.maximum(amax, 1e-6) / 127.0
-    q = jnp.clip(jnp.round(t.astype(jnp.float32) / scale), -127, 127)
-    sb = jax.lax.bitcast_convert_type(scale, jnp.int8)  # (..., 1, 4)
-    sb = sb.reshape(*t.shape[:-1], _KV_SCALE_BYTES)
-    return jnp.concatenate([q.astype(jnp.int8), sb], axis=-1)
+    parts = [jnp.clip(jnp.round(t / scale), -127, 127).astype(jnp.int32)]
+    bits = jax.lax.bitcast_convert_type(scale, jnp.int32)     # (..., 1)
+    for i in range(_KV_SCALE_BYTES):
+        byte = (bits >> (8 * i)) & 0xFF
+        parts.append(byte - ((byte & 0x80) << 1))             # as signed
+    return jnp.concatenate(parts, axis=-1).astype(jnp.int8)
 
 
 def kv_cache_dequantize(c, dtype):
     """(..., D+4) int8 -> (..., D) ``dtype``."""
     d = c.shape[-1] - _KV_SCALE_BYTES
-    vals = c[..., :d].astype(jnp.float32)
-    sb = c[..., d:].reshape(*c.shape[:-1], 1, _KV_SCALE_BYTES)
-    scale = jax.lax.bitcast_convert_type(sb, jnp.float32)  # (..., 1)
-    return (vals * scale.reshape(*c.shape[:-1], 1)).astype(dtype)
+    w = c.astype(jnp.int32)
+    b = w[..., d:] & 0xFF                                     # (..., 4)
+    bits = (b[..., 0:1] | (b[..., 1:2] << 8) | (b[..., 2:3] << 16)
+            | (b[..., 3:4] << 24))
+    scale = jax.lax.bitcast_convert_type(bits, jnp.float32)   # (..., 1)
+    return (w[..., :d].astype(jnp.float32) * scale).astype(dtype)
 
 
 def paged_attention(q, k_pool, v_pool, block_table, lengths,
@@ -1053,8 +1013,7 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths,
     mb = block_table.shape[1]
     quantized = k_pool.dtype == jnp.int8
     if use_kernel is None:
-        use_kernel = (not _pallas_disabled.depth
-                      and jax.default_backend() == "tpu")
+        use_kernel = _tpu_kernels_selected()
     if use_kernel:
         from .pallas.paged_attention import paged_attention_kernel
 
@@ -1113,8 +1072,7 @@ def paged_attention_multi(q, k_pool, v_pool, block_table, positions,
     abs_pos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
     quantized = k_pool.dtype == jnp.int8
     if use_kernel is None:
-        use_kernel = (not _pallas_disabled.depth
-                      and jax.default_backend() == "tpu")
+        use_kernel = _tpu_kernels_selected()
     if use_kernel:
         from .pallas.paged_attention import paged_attention_kernel
 
@@ -1155,8 +1113,11 @@ def attend(q, k, v, heads, causal=False, mask=None, dropout=0.0, key=None,
     qh = q.reshape(b, lq, heads, d).transpose(0, 2, 1, 3)
     kh = k.reshape(b, k.shape[1], heads, d).transpose(0, 2, 1, 3)
     vh = v.reshape(b, v.shape[1], heads, d).transpose(0, 2, 1, 3)
+    # the flash kernel: compiled on the TPU (not inside a mesh scope, see
+    # _tpu_kernels_selected), interpreted — plain XLA ops — elsewhere
     if mask is None and not (dropout and training) \
-            and not _pallas_disabled.depth:
+            and not _pallas_disabled.depth \
+            and not (jax.default_backend() == "tpu" and _in_mesh_scope()):
         from .pallas.flash_attention import flash_attention
 
         out = flash_attention(qh, kh, vh, causal=causal)

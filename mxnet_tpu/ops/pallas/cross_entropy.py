@@ -64,13 +64,21 @@ def _lse_kernel(x_ref, o_ref, m_ref, l_ref, *, n_v, v_total, block_v):
 def fused_lse(x, block_n: int = 256, block_v: int = 2048,
               interpret: Optional[bool] = None):
     """Row-wise logsumexp of a 2-D array in one HBM pass. Returns (N,) f32."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     if x.ndim != 2:
         raise ValueError(f"expected (N, V), got {x.shape}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    return _fused_lse(x, block_n, block_v, interpret)
+
+
+# jitted: a bare ``pallas_call`` builds a new jit wrapper on every call,
+# so an eager caller (the loss outside a hybridized block) would compile
+# the kernel again at every step
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _fused_lse(x, block_n, block_v, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
     n, v = x.shape
     # round blocks to Mosaic fp32 tile multiples (8 sublanes × 128 lanes):
     # an unaligned bn/bv (e.g. N=100 or V=1000) is a hard Mosaic reject on

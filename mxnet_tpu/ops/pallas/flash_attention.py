@@ -25,17 +25,6 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
-def _tpu_compiler_params(**kw):
-    """``pltpu.CompilerParams`` across the jax rename — older jaxlibs
-    (including the pinned one) expose it as ``TPUCompilerParams``; the
-    compiled (non-interpret) arm must not crash on either."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
-
-
 def _matmul_precision(dtype):
     """One policy for every kernel matmul, fwd and bwd: bf16 runs at
     native MXU precision (HIGHEST on bf16 is a Mosaic reject; f32
@@ -261,6 +250,10 @@ def _resident_fits(lk, d, itemsize):
     return 4 * lk * d * itemsize <= _RESIDENT_KV_VMEM_BYTES
 
 
+# the kernel entry points are jitted: a bare ``pallas_call`` builds a new
+# jit wrapper on every call, so an eager caller would compile the kernel
+# again each time
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                    save_residuals=False):
     import jax.experimental.pallas as pl
@@ -331,7 +324,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     # sequential — the scratch accumulators carry across k programs
     compiler_params = None
     if not interpret:
-        compiler_params = _tpu_compiler_params(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel") if resident
             else ("parallel", "parallel", "arbitrary"))
     res = pl.pallas_call(
@@ -487,11 +480,12 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, g_ref, qT_ref, gT_ref, oT_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
 def _flash_bwd_pallas(q, k, v, out, lse, g, causal, sm_scale, block_q,
                       block_k, interpret):
     """Pallas flash backward: dq/dk/dv with all score-sized transients in
-    VMEM. The scan fallback below keeps correctness everywhere; this
-    path removes its dominant cost — every (Lq, bk) s/p/dp/ds tensor
+    VMEM. Against the scan backward (what interpret mode runs) this
+    path removes the dominant cost — every (Lq, bk) s/p/dp/ds tensor
     round-tripping HBM between XLA matmuls."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -589,71 +583,6 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, causal, sm_scale, block_q,
     return dq, dk, dv
 
 
-_BWD_PALLAS_STATE: dict = {}
-_BWD_PALLAS_FALLBACKS = {"count": 0}
-
-
-def bwd_pallas_report():
-    """JSON-ready provenance for benchmarks: per-signature probe
-    outcomes (True = compiled Pallas backward enabled, False = scan
-    fallback), plus how many real backward traces fell back DESPITE a
-    green probe (trace-time surprises) — a green probe alone does not
-    prove the compiled path ran."""
-    rep = {str(k): v for k, v in _BWD_PALLAS_STATE.items()}
-    if _BWD_PALLAS_FALLBACKS["count"]:
-        rep["trace_time_fallbacks"] = _BWD_PALLAS_FALLBACKS["count"]
-    return rep
-
-
-def bwd_pallas_enabled_for(b, h, d, dtype, causal, lq, lk) -> bool:
-    """Structured query for bench provenance: True iff the per-signature
-    probe admitted the compiled Pallas backward for this exact geometry
-    (any probed block size) AND no trace-time fallback has occurred in
-    this process — a green probe plus a recorded fallback means at least
-    one trace ran the scan path instead, so the honest answer is False.
-    Callers must NOT parse bwd_pallas_report()'s stringified keys (they
-    change shape when the probe signature grows)."""
-    if _BWD_PALLAS_FALLBACKS["count"]:
-        return False
-    want = (int(b), int(h), int(d), jnp.dtype(dtype).name, bool(causal),
-            int(lq), int(lk))
-    return any(k[:7] == want and v for k, v in _BWD_PALLAS_STATE.items())
-
-
-def _bwd_pallas_ok(b, h, d, dtype, causal, lq, lk, bq, bk):
-    """Probe once PER SIGNATURE — with the REAL grid geometry, batch and
-    heads included, so the probe compiles exactly the block shapes,
-    padding and (b*h, n_q, n_k) grid the real call will (ADVICE r4: a
-    b=h=1 probe green-lights grids Mosaic could still reject at size,
-    and when the backward is traced under the enclosing train-step jit,
-    that reject would surface at outer-jit compile time where no handler
-    catches it — failing the whole step instead of falling back). Any
-    reject falls back to the XLA-scan backward for that signature.
-    Training shapes are static, so this is one compile per distinct
-    shape; the probe's zeros are freed as soon as it returns."""
-    key = (int(b), int(h), int(d), jnp.dtype(dtype).name, bool(causal),
-           int(lq), int(lk), int(bq), int(bk),
-           # the RESOLVED kernel precision participates in what the
-           # kernel compiles to, so it is part of the probe's identity;
-           # keying on the raw ambient string would recompile the probe
-           # for ambients that lower identically (f32 high==highest,
-           # bf16 always DEFAULT)
-           str(_matmul_precision(dtype)))
-    if key not in _BWD_PALLAS_STATE:
-        try:
-            q = jnp.zeros((b, h, lq, d), dtype)
-            kv = jnp.zeros((b, h, lk, d), dtype)
-            lse = jnp.zeros((b, h, lq), jnp.float32)
-            jax.block_until_ready(jax.jit(
-                lambda q_, kv_, s: _flash_bwd_pallas(
-                    q_, kv_, kv_, q_, s, q_, causal, 0.125, bq, bk, False)
-            )(q, kv, lse))
-            _BWD_PALLAS_STATE[key] = True
-        except Exception:  # noqa: BLE001 — Mosaic reject / old pallas
-            _BWD_PALLAS_STATE[key] = False
-    return _BWD_PALLAS_STATE[key]
-
-
 def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
     """Flash backward: ONE blockwise pass over K computing dQ/dK/dV, never
     materializing more than one (Lq, block_k) score block.
@@ -667,43 +596,20 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
     b, h, lq, d = q.shape
     lk = k.shape[2]
-    # compiled Pallas backward on TPU (probe-gated: scan fallback keeps
-    # every backend correct). Interpret mode stays on the scan path —
-    # the Pallas interpreter's python grid loop is for the dedicated
-    # kernel unit tests, not every CPU-test backward.
+    # The compiled Pallas backward on the TPU, by fixed rule: blocks
+    # capped at 256 (and by the caller's block args), which the chip's
+    # compiler accepts (tests/test_chip_compile.py). Interpret mode
+    # stays on the scan path below — the Pallas interpreter's python
+    # grid loop is for the dedicated kernel unit tests, not every
+    # CPU-test backward.
     if not interpret and jax.default_backend() == "tpu":
-        # prefer fatter blocks (fewer grid programs, more arithmetic per
-        # MXU visit), capped by the caller's block args so explicit
-        # block_q/block_k still bound the backward kernel too; the
-        # per-signature probe decides what Mosaic takes
-        cands = []
-        for cap in (256, 128):
-            c = (min(block_q, cap, lq), min(block_k, cap, lk))
-            if c not in cands:
-                cands.append(c)
-        raised = False
-        for pbq, pbk in cands:
-            if not _bwd_pallas_ok(b, h, d, q.dtype, causal, lq, lk,
-                                  pbq, pbk):
-                continue
-            try:
-                dq, dk, dv = _flash_bwd_pallas(
-                    q, k, v, out, lse, g, causal, sm_scale, pbq, pbk,
-                    False)
-                return (dq.astype(q.dtype), dk.astype(k.dtype),
-                        dv.astype(v.dtype))
-            except Exception:  # noqa: BLE001 — trace-time surprise:
-                # try the next (smaller) candidate before surrendering
-                raised = True
-        if raised:
-            # count TRACES that reached the scan path despite a green
-            # probe — not per-candidate misses (provenance contract of
-            # bwd_pallas_report)
-            _BWD_PALLAS_FALLBACKS["count"] += 1
+        return _flash_bwd_pallas(
+            q, k, v, out, lse, g, causal, sm_scale,
+            min(block_q, 256), min(block_k, 256), False)
     # the XLA-scan backward gets no launch-overhead win from big K blocks
     # (that argument is the Pallas forward grid's); it only pays their
     # memory — s/p/dp/ds transients scale with bk. Cap at 128 regardless
-    # of the probed forward default.
+    # of the forward default.
     bk = min(block_k, 128, lk)
     n_k = -(-lk // bk)
     pad = n_k * bk - lk
@@ -821,35 +727,12 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-# Default block sizes, probed once per process. 128x128 blocks make each
-# grid program a tiny (128,64)x(64,128) matmul — launch-bound at scale
-# (8192 programs for B8/H16/L1024). 256x512 blocks lift arithmetic
-# intensity ~8x per program and use ~1.5 MB of the ~16 MB VMEM; if
-# Mosaic rejects them on some backend the probe falls back to the
-# always-valid 128x128.
-_BLOCK_CANDIDATES = ((256, 512), (128, 128))
-_BLOCKS_STATE = {"val": None}
-
-
-def _default_blocks():
-    st = _BLOCKS_STATE
-    if st["val"] is None:
-        if jax.default_backend() != "tpu":
-            st["val"] = _BLOCK_CANDIDATES[0]  # interpreter: size-agnostic
-        else:
-            for bq, bk in _BLOCK_CANDIDATES:
-                try:
-                    probe = jnp.zeros((1, 1, 1024, 64), jnp.bfloat16)
-                    jax.jit(lambda x: _flash(
-                        x, x, x, True, 0.125, bq, bk, False))(
-                            probe).block_until_ready()
-                    st["val"] = (bq, bk)
-                    break
-                except Exception:  # noqa: BLE001 — Mosaic reject: next
-                    continue
-            else:
-                st["val"] = (128, 128)
-    return st["val"]
+# Default block sizes, by fixed rule. 128x128 blocks make each grid
+# program a tiny (128,64)x(64,128) matmul (8192 programs for
+# B8/H16/L1024); 256x512 blocks lift arithmetic intensity ~8x per program
+# and use ~1.5 MB of the ~16 MB VMEM. The chip's compiler accepts both
+# (tests/test_chip_compile.py); which is faster is not measured.
+_DEFAULT_BLOCKS = (256, 512)
 
 
 def flash_attention(
@@ -864,8 +747,7 @@ def flash_attention(
 
     ``interpret=None`` auto-selects: the compiled Mosaic kernel on TPU, the
     Pallas interpreter elsewhere (so CPU tests exercise the same kernel
-    logic the TPU runs). Block sizes default to the probed
-    ``_default_blocks()`` (256x512 where Mosaic accepts them).
+    logic the TPU runs). Block sizes default to ``_DEFAULT_BLOCKS``.
     """
     if q.ndim != 4:
         raise ValueError(f"expected (b, h, l, d), got {q.shape}")
@@ -873,8 +755,6 @@ def flash_attention(
         sm_scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if block_q is None or block_k is None:
-        dbq, dbk = _default_blocks()
-        block_q = block_q or dbq
-        block_k = block_k or dbk
+    block_q = block_q or _DEFAULT_BLOCKS[0]
+    block_k = block_k or _DEFAULT_BLOCKS[1]
     return _flash(q, k, v, causal, float(sm_scale), block_q, block_k, interpret)

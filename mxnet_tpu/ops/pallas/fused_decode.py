@@ -18,14 +18,12 @@ collapses the per-layer decode hot path into three Pallas launches:
   before it.
 - :func:`fused_out_project` — out projection + bias in one kernel.
 
-Gate: :func:`fused_decode_armed` — an env knob
-(``MXNET_TPU_LLM_FUSED_DECODE``: ``auto``/``1``/``0``) whose ``auto``
-arm requires the TPU backend AND the :mod:`mxnet_tpu.analysis.opt` cost
-model scoring the decode projection memory-bound (it always is; the
-gate records *why* fusion pays — the "A Learned Performance Model for
-TPUs" discipline of never rewriting on vibes). Oracle: the unfused jnp
-path in ``MultiHeadAttention.forward_step_paged``, checked in interpret
-mode on CPU (``tests/test_llm_serving.py``).
+Gate: :func:`fused_decode_armed` — the env knob
+``MXNET_TPU_LLM_FUSED_DECODE`` (``1`` arms; unarmed by default, see the
+function for why). Oracle: the unfused jnp path in
+``MultiHeadAttention.forward_step_paged``, checked in interpret mode on
+CPU (``tests/test_llm_serving.py``); the chip's compiler is asked in
+``tests/test_chip_compile.py``.
 """
 from __future__ import annotations
 
@@ -35,86 +33,58 @@ import jax
 import jax.numpy as jnp
 
 from ...base import env_str
+from ..nn import _KV_SCALE_BYTES
 
 __all__ = ["fused_decode_armed", "fused_decode_step",
            "fused_qkv_project", "fused_out_project"]
 
 
 # --- gating ----------------------------------------------------------------
-@functools.lru_cache(maxsize=8)
-def _cost_model_gate(kv_dtype: str, backend: str) -> bool:
-    """Arm fusion only when the cost model scores the per-token decode
-    projection memory-bound (weights re-read every token dwarf the
-    rank-1 matmul's flops)."""
-    try:
-        from ...analysis.opt.cost_model import CostModel, OpFeatures
-
-        model = CostModel.for_backend(backend=backend)
-        u = 1024.0            # representative decode width; the verdict
-        w_bytes = 1.0 if kv_dtype == "int8" else 2.0   # is scale-free
-        f = OpFeatures(
-            prim="dot_general", flops_raw=2 * u * 3 * u,
-            flops_padded=2 * 8 * u * 3 * u,
-            bytes=(3 * u * u + 6 * u) * w_bytes, major=True,
-            dtype="bfloat16", detail="fused_decode_gate")
-        return model.op_cost(f).bound == "memory"
-    except Exception:  # noqa: BLE001 — cost model down: fuse on TPU
-        return True
-
-
-def fused_decode_armed(kv_dtype: str = "float32",
-                       backend=None) -> bool:
+def fused_decode_armed() -> bool:
     """Should the paged decode step run the fused Pallas kernels?
 
-    ``MXNET_TPU_LLM_FUSED_DECODE``: ``0``/``off`` never, ``1``/``on``
-    always (tests force it on CPU — the kernels run interpreted there),
-    ``auto`` (default) = TPU backend + cost-model memory-bound verdict.
-    Always off inside :func:`~mxnet_tpu.ops.nn.no_pallas` scopes."""
+    Only when ``MXNET_TPU_LLM_FUSED_DECODE`` asks for it (``1``/``on``;
+    the kernels run interpreted off the TPU). Unarmed by default, on
+    every backend and for every pool dtype: the one argument for
+    arming it on the TPU was a per-launch cost fitted to numbers that
+    predate PR 1, :func:`fused_out_project` holds the whole ``(U, U)``
+    weight in VMEM and so does not scale with width, and no chip run has
+    compared the trio with XLA's own fusion of the same ops (ROADMAP
+    S3). Always off inside :func:`~mxnet_tpu.ops.nn.no_pallas` scopes."""
     from ..nn import _pallas_disabled
 
     if _pallas_disabled.depth:
         return False
-    mode = env_str("MXNET_TPU_LLM_FUSED_DECODE", "auto").strip().lower()
-    if mode in ("0", "off", "false", "no", ""):
-        return False
-    if mode in ("1", "on", "true", "yes", "force"):
-        return True
-    if backend is None:
-        from ...base import failsoft_call
-
-        backend = failsoft_call(jax.default_backend)
-    if backend != "tpu":
-        return False
-    return _cost_model_gate(str(kv_dtype), str(backend))
+    mode = env_str("MXNET_TPU_LLM_FUSED_DECODE", "0").strip().lower()
+    return mode in ("1", "on", "true", "yes", "force")
 
 
 # --- kernel bodies ---------------------------------------------------------
 def _qkv_kernel(x_ref, wq_ref, wk_ref, wv_ref, bq_ref, bk_ref, bv_ref,
                 q_ref, k_ref, v_ref, *, quantized, precision):
-    # the ONE definition of the int8 [values | bitcast f32 scale]
-    # layout — fusing the oracle's own quantizer into the kernel keeps
-    # the interpret-mode parity promise by construction
+    # the ONE definition of the int8 [values | f32 scale bytes] layout:
+    # fusing the oracle's own quantizer into the kernel keeps the
+    # interpret-mode parity promise by construction
     from ..nn import kv_cache_quantize
 
     x = x_ref[...].astype(jnp.float32)            # (N, U)
 
     def proj(w_ref, b_ref):                       # -> (N, D) f32
-        w = w_ref[:, 0, :].astype(jnp.float32)    # (U, D)
+        w = w_ref[0].astype(jnp.float32)          # (U, D)
         y = jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
                                 precision=precision,
                                 preferred_element_type=jnp.float32)
-        return y + b_ref[...].astype(jnp.float32)
+        return y + b_ref[0].astype(jnp.float32)   # (1, D) broadcast
 
-    q = proj(wq_ref, bq_ref)
-    q_ref[...] = q[:, None, :].astype(q_ref.dtype)
+    q_ref[0] = proj(wq_ref, bq_ref).astype(q_ref.dtype)
     k = proj(wk_ref, bk_ref)
     v = proj(wv_ref, bv_ref)
     if quantized:
-        k_ref[...] = kv_cache_quantize(k)[:, None, :]
-        v_ref[...] = kv_cache_quantize(v)[:, None, :]
+        k_ref[0] = kv_cache_quantize(k)
+        v_ref[0] = kv_cache_quantize(v)
     else:
-        k_ref[...] = k[:, None, :].astype(k_ref.dtype)
-        v_ref[...] = v[:, None, :].astype(v_ref.dtype)
+        k_ref[0] = k.astype(k_ref.dtype)
+        v_ref[0] = v.astype(v_ref.dtype)
 
 
 def _out_kernel(a_ref, w_ref, b_ref, o_ref, *, precision):
@@ -135,7 +105,11 @@ def fused_qkv_project(x, w_qkv, b_qkv, *, heads, store_dtype,
     (out, in); ``b_qkv``: (3U,) or None. Returns ``(q, k_store,
     v_store)``: q (N, H, D) in ``x``'s dtype; k/v (N, H, D') already in
     the pool layout — int8 + bitcast scale when ``store_dtype`` is
-    int8, a plain cast otherwise. Grid: one program per head."""
+    int8, a plain cast otherwise. Grid: one program per head, over
+    head-major operands — weights ``(H, U, D)``, biases ``(H, 1, D)``,
+    outputs ``(H, N, D')`` — so that every block's last two dims are
+    the array's own, which is what the TPU lowering accepts for 64-wide
+    heads; the outputs are transposed to ``(N, H, D')`` outside."""
     import jax.experimental.pallas as pl
 
     from .flash_attention import _matmul_precision
@@ -145,39 +119,47 @@ def fused_qkv_project(x, w_qkv, b_qkv, *, heads, store_dtype,
     n, u = x.shape
     d = u // heads
     quantized = jnp.dtype(store_dtype) == jnp.int8
-    from ..nn import _KV_SCALE_BYTES
-
     dp = d + _KV_SCALE_BYTES if quantized else d
     if b_qkv is None:
         b_qkv = jnp.zeros((3 * u,), x.dtype)
 
-    def slab(w):                                  # (U, U) -> (U, H, D)
-        return w.T.reshape(u, heads, d)
+    def slab(w):                                  # (U_out, U_in) -> (H, U_in, D)
+        return w.reshape(heads, d, u).transpose(0, 2, 1)
 
     wq, wk, wv = (slab(w_qkv[:u]), slab(w_qkv[u:2 * u]),
                   slab(w_qkv[2 * u:]))
-    bq, bk, bv = (b_qkv[:u].reshape(heads, d),
-                  b_qkv[u:2 * u].reshape(heads, d),
-                  b_qkv[2 * u:].reshape(heads, d))
+    bq, bk, bv = (b_qkv[:u].reshape(heads, 1, d),
+                  b_qkv[u:2 * u].reshape(heads, 1, d),
+                  b_qkv[2 * u:].reshape(heads, 1, d))
     kernel = functools.partial(
         _qkv_kernel, quantized=quantized,
         precision=_matmul_precision(x.dtype))
-    w_spec = pl.BlockSpec((u, 1, d), lambda h: (0, h, 0))
-    b_spec = pl.BlockSpec((1, d), lambda h: (h, 0))
-    kv_spec = pl.BlockSpec((n, 1, dp), lambda h: (0, h, 0))
+
+    # index maps are traced under jax_enable_x64 (base.py), where a
+    # Python 0 becomes an i64 that the lowering refuses: jnp.int32(0)
+    def head_map(h):
+        z = jnp.int32(0)
+        return h, z, z
+
+    def whole_map(h):
+        z = jnp.int32(0)
+        return z, z
+
+    w_spec = pl.BlockSpec((1, u, d), head_map)
+    b_spec = pl.BlockSpec((1, 1, d), head_map)
+    kv_spec = pl.BlockSpec((1, n, dp), head_map)
     q, ks, vs = pl.pallas_call(
         kernel,
         grid=(heads,),
-        in_specs=[pl.BlockSpec((n, u), lambda h: (0, 0)),
+        in_specs=[pl.BlockSpec((n, u), whole_map),
                   w_spec, w_spec, w_spec, b_spec, b_spec, b_spec],
-        out_specs=[pl.BlockSpec((n, 1, d), lambda h: (0, h, 0)),
-                   kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct((n, heads, d), x.dtype),
-                   jax.ShapeDtypeStruct((n, heads, dp), store_dtype),
-                   jax.ShapeDtypeStruct((n, heads, dp), store_dtype)],
+        out_specs=[pl.BlockSpec((1, n, d), head_map), kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((heads, n, d), x.dtype),
+                   jax.ShapeDtypeStruct((heads, n, dp), store_dtype),
+                   jax.ShapeDtypeStruct((heads, n, dp), store_dtype)],
         interpret=interpret,
     )(x, wq, wk, wv, bq, bk, bv)
-    return q, ks, vs
+    return tuple(t.transpose(1, 0, 2) for t in (q, ks, vs))
 
 
 def fused_out_project(attn, w_out, b_out, *, interpret=None):

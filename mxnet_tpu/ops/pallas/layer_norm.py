@@ -72,6 +72,9 @@ def _pad_cols(x, dp):
     return jnp.pad(x, ((0, 0), (0, pad))) if pad else x
 
 
+# jitted: a bare ``pallas_call`` builds a new jit wrapper on every call,
+# so an eager caller would compile the kernel again each time
+@functools.partial(jax.jit, static_argnums=(0, 3, 4, 5, 6))
 def _run_norm(kernel, x, scales, n_extra_outs, eps, block_n, interpret):
     """Shared pallas_call plumbing for the two norm kernels. The output
     dtype follows jnp promotion over (x, *scales) so the kernel path is
@@ -118,7 +121,7 @@ def fused_layer_norm(x, gamma, beta, eps: float = 1e-5,
 
 def _ln_fwd(x, gamma, beta, eps, interpret):
     out, (mean, rstd) = _run_norm(
-        functools.partial(_ln_kernel), x, [gamma, beta], 2, eps,
+        _ln_kernel, x, [gamma, beta], 2, eps,
         128, _auto_interpret(interpret))
     return out, (x, gamma, beta, mean, rstd)
 
@@ -151,7 +154,7 @@ def fused_rms_norm(x, gamma, eps: float = 1e-6,
 
 def _rms_fwd(x, gamma, eps, interpret):
     out, (rstd,) = _run_norm(
-        functools.partial(_rms_kernel), x, [gamma], 1, eps,
+        _rms_kernel, x, [gamma], 1, eps,
         128, _auto_interpret(interpret))
     return out, (x, gamma, rstd)
 

@@ -9,12 +9,13 @@ block into VMEM — the gather never materializes a dense per-lane cache
 in HBM, which is the point: decode reads ``length`` real positions,
 not ``max_context``.
 
-Grid: ``(lanes * heads, max_blocks)`` — one (lane, head) pair per
-program row, online-softmax accumulation over the block axis (the
-flash-attention recurrence with block_q == 1). Correctness-first: the
-(1, D) query row underfills the MXU; the throughput win this kernel
-banks is the *bytes* win (paged gather + no dense cache), which is what
-the bandwidth-bound decode path is limited by.
+Grid: ``(lanes, max_blocks)`` — one lane per program row covering all
+heads, online-softmax accumulation over the block axis (the
+flash-attention recurrence with block_q == 1). Every block's last two
+dims are the array's own (q ``(1, H, D)``, pool ``(1, H, bs, D')``),
+which is what the TPU lowering accepts for 64-wide heads. The single
+query row per head would underfill the MXU, so scores and the weighted
+sum run on the VPU.
 
 int8 pools (the engine default) take the same kernel: a pool row is
 ``[D int8 values | 4 bitcast f32-scale bytes]``
@@ -39,19 +40,14 @@ __all__ = ["paged_attention_kernel"]
 _NEG_BIG = -1e30  # finite mask (−inf breaks the online-softmax carry)
 
 
-def _dequant_block(c, d):
-    """(bs, D+4) int8 [values | bitcast f32 scale] -> (bs, D) f32."""
-    vals = c[:, :d].astype(jnp.float32)
-    scale = jax.lax.bitcast_convert_type(c[:, d:], jnp.float32)  # (bs,)
-    return vals * scale[:, None]
-
-
 def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, bs, mb, heads, d, quantized,
-                  sm_scale, precision):
+                  m_ref, l_ref, acc_ref, *, bs, mb, quantized,
+                  sm_scale):
     import jax.experimental.pallas as pl
 
-    rh = pl.program_id(0)
+    from ..nn import kv_cache_dequantize
+
+    r = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -60,28 +56,26 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)            # (1, D)
+    q = q_ref[0].astype(jnp.float32)              # (H, D)
     if quantized:
-        k = _dequant_block(k_ref[0, 0], d)        # (bs, D)
-        v = _dequant_block(v_ref[0, 0], d)
+        k = kv_cache_dequantize(k_ref[0], jnp.float32)    # (H, bs, D)
+        v = kv_cache_dequantize(v_ref[0], jnp.float32)
     else:
-        k = k_ref[0, 0].astype(jnp.float32)       # (bs, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            precision=precision,
-                            preferred_element_type=jnp.float32)  # (1, bs)
-    s = s * sm_scale
-    length = len_ref[rh // heads]
-    pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    s = jnp.where(pos < length, s, _NEG_BIG)
-    m_prev = m_ref[:, :1]                         # (1, 1)
+        k = k_ref[0].astype(jnp.float32)          # (H, bs, D)
+        v = v_ref[0].astype(jnp.float32)
+    # one query row per head: the products run on the VPU in f32 (a
+    # (1, D) x (D, bs) matmul would fill one MXU row in 128)
+    s = jnp.sum(q[:, None, :] * k, axis=-1) * sm_scale       # (H, bs)
+    length = len_ref[r]
+    pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    # (a bare Python float here is an f64 operand under jax_enable_x64)
+    s = jnp.where(pos < length, s, jnp.float32(_NEG_BIG))
+    m_prev = m_ref[:, :1]                         # (H, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                        # (1, bs)
+    p = jnp.exp(s - m_new)                        # (H, bs)
     l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             precision=precision,
-                             preferred_element_type=jnp.float32)  # (1, D)
+    pv = jnp.sum(p[:, :, None] * v, axis=1)       # (H, D)
     acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -89,7 +83,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j == mb - 1)
     def _finish():
         denom = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths,
@@ -108,54 +102,51 @@ def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from .flash_attention import _matmul_precision, _tpu_compiler_params
-
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     r, h, d = q.shape
     _, _, bs, dp = k_pool.shape
     quantized = k_pool.dtype == jnp.int8
     mb = block_table.shape[1]
-    sm_scale = float(d) ** -0.5
-    precision = _matmul_precision(q.dtype)
     out_dtype = q.dtype if quantized else v_pool.dtype
-    qf = q.reshape(r * h, d)
     bt = block_table.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
-
     kernel = functools.partial(
-        _paged_kernel, bs=bs, mb=mb, heads=h, d=d, quantized=quantized,
-        sm_scale=sm_scale, precision=precision)
+        _paged_kernel, bs=bs, mb=mb, quantized=quantized,
+        sm_scale=float(d) ** -0.5)
+    # every block's last two dims are the array's own,
+    # which is what the TPU lowering takes for D=64 rows and 12 heads.
+    # Index maps are traced under jax_enable_x64 (base.py), where a
+    # Python 0 becomes an i64 that the lowering refuses: jnp.int32(0)
+    def lane_map(i, j, bt_, ln_):
+        z = jnp.int32(0)
+        return i, z, z
+
+    def block_map(i, j, bt_, ln_):
+        z = jnp.int32(0)
+        return bt_[i, j], z, z, z
+
+    q_spec = pl.BlockSpec((1, h, d), lane_map)
+    pool_spec = pl.BlockSpec((1, h, bs, dp), block_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # block_table, lengths
-        grid=(r * h, mb),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda rh, j, bt_, ln_: (rh, 0)),
-            pl.BlockSpec(
-                (1, 1, bs, dp),
-                lambda rh, j, bt_, ln_: (bt_[rh // h, j], rh % h, 0, 0)),
-            pl.BlockSpec(
-                (1, 1, bs, dp),
-                lambda rh, j, bt_, ln_: (bt_[rh // h, j], rh % h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda rh, j, bt_, ln_: (rh, 0)),
+        grid=(r, mb),
+        in_specs=[q_spec, pool_spec, pool_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),   # running max
-            pltpu.VMEM((1, 128), jnp.float32),   # running denom
-            pltpu.VMEM((1, d), jnp.float32),     # output accumulator
+            pltpu.VMEM((h, 128), jnp.float32),   # running max
+            pltpu.VMEM((h, 128), jnp.float32),   # running denom
+            pltpu.VMEM((h, d), jnp.float32),     # output accumulator
         ],
     )
-    compiler_params = None
-    if not interpret:
-        # the block axis is a sequential reduction (the scratch
-        # accumulators carry across j); lane-head programs are free
-        compiler_params = _tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"))
-    out = pl.pallas_call(
+    # the block axis is a sequential reduction (the scratch accumulators
+    # carry across j); lanes are independent
+    compiler_params = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r * h, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((r, h, d), out_dtype),
         compiler_params=compiler_params,
         interpret=interpret,
-    )(bt, lens, qf, k_pool, v_pool)
-    return out.reshape(r, h, d)
+    )(bt, lens, q, k_pool, v_pool)

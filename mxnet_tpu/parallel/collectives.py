@@ -35,26 +35,11 @@ __all__ = [
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable ``shard_map``: jax moved it from
-    ``jax.experimental.shard_map`` to ``jax.shard_map`` and renamed the
-    replication-check knob (``check_rep`` -> ``check_vma``) across
-    releases — every in-tree caller (ring attention, GPipe, syncbn
-    tests) goes through this one shim instead of chasing the API."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # noqa: N813
-    import inspect
-
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # builtins without signatures
-        params = {}
-    if "check_vma" in params:
-        kwargs["check_vma"] = check
-    elif "check_rep" in params:
-        kwargs["check_rep"] = check
-    return sm(f, **kwargs)
+    """``jax.shard_map`` with the replication check off by default —
+    the one spelling every in-tree caller (ring attention, GPipe, syncbn
+    tests) uses."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def allreduce(x, axis_name: str, op: str = "sum"):

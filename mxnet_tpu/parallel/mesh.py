@@ -23,7 +23,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 
-from ..base import FatalError, safe_devices
+from ..base import FatalError
 import numpy as onp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -62,7 +62,7 @@ def make_mesh(
     (SURVEY.md §2.3).
     """
     if devices is None:
-        devices = safe_devices()
+        devices = jax.devices()
     devices = list(devices)
     if axes is None:
         axes = {"dp": -1}

@@ -28,7 +28,6 @@ from typing import Dict, List
 
 import jax
 
-from .base import safe_devices
 from .telemetry import registry as _registry
 from .telemetry import tracing as _tracing
 
@@ -122,7 +121,7 @@ def device_memory(device=None) -> dict:
     Returns {} on backends that expose no stats (virtual CPU devices)."""
     import jax
 
-    d = device or safe_devices()[0]
+    d = device or jax.devices()[0]
     try:
         return dict(d.memory_stats() or {})
     except Exception:
@@ -139,7 +138,7 @@ def _mem_in_use() -> int:
         import jax
 
         try:
-            dev = safe_devices()[0]
+            dev = jax.devices()[0]
             if not (dev.memory_stats() or {}):
                 _mem_probe = False
                 return 0

@@ -6,8 +6,6 @@ from typing import Dict
 
 import jax
 
-from .base import safe_devices
-
 
 class Feature:
     def __init__(self, name: str, enabled: bool):
@@ -22,7 +20,7 @@ class Features(dict):
     """dict of name -> Feature (parity with mx.runtime.Features)."""
 
     def __init__(self):
-        platforms = {d.platform for d in safe_devices()}
+        platforms = {d.platform for d in jax.devices()}
         feats = {
             "TPU": any(p not in ("cpu",) for p in platforms),
             "CPU": True,

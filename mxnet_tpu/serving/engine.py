@@ -15,11 +15,7 @@ measured (``pad_waste`` histogram, :mod:`.metrics`).
 Backend hygiene:
 - the padded device batch is **donated** to the executable on
   accelerator backends (input buffer reused for outputs — no double
-  allocation at the serving hot loop's rate),
-- engine startup runs :func:`mxnet_tpu.base.preflight_backend` and every
-  batch executes under :func:`~mxnet_tpu.base.failsoft_call`, so a dead
-  accelerator degrades the engine to CPU instead of wedging the queue
-  with requests that time out one deadline at a time.
+  allocation at the serving hot loop's rate).
 """
 from __future__ import annotations
 
@@ -32,7 +28,7 @@ import jax.numpy as jnp
 import numpy as onp
 
 from .. import aot
-from ..base import env_float, env_int, failsoft_call, preflight_backend
+from ..base import env_float, env_int
 from ..ndarray.ndarray import ndarray, _wrap
 from ..resilience import chaos
 from .admission import (AdmissionQueue, DeadlineExceeded, Request,
@@ -176,11 +172,8 @@ class InferenceEngine:
         self._closed = False
         self._close_lock = threading.Lock()
 
-        # a hung accelerator must be discovered NOW (killable probe, CPU
-        # flip), not after the queue is full of deadlined requests
-        preflight_backend()
         if donate is None:
-            donate = failsoft_call(jax.default_backend) not in ("cpu",)
+            donate = jax.default_backend() != "cpu"
         self._donate = bool(donate)
 
         self._model = model
@@ -253,12 +246,7 @@ class InferenceEngine:
             with self._build_lock:
                 ex = self._execs.get(key)
                 if ex is None:
-                    # donation re-decided per executable from the backend
-                    # already in the cache key: after a fail-soft flip to
-                    # CPU, fresh executables must drop donate_argnums or
-                    # XLA:CPU warns on every served batch
-                    donate = ((1,) if self._donate
-                              and key[1] not in ("cpu", "?") else ())
+                    donate = (1,) if self._donate else ()
                     # the AOT seam: consult the persistent compile cache
                     # before compiling, publish after — a plain jax.jit
                     # when no store is armed (aot.get_cache() is None)
@@ -405,12 +393,7 @@ class InferenceEngine:
         snap["max_delay_ms"] = self.max_delay_ms
         snap["aot"] = aot.stats()  # process-wide hit/miss/bytes counters
         snap["tuned"] = self.tuned.provenance() if self.tuned else None
-        try:
-            # pure observability must never raise (or be the process's
-            # unguarded first backend touch) — mirror stem_s2d_cache_key
-            snap["backend"] = failsoft_call(jax.default_backend)
-        except Exception:  # noqa: BLE001
-            snap["backend"] = "?"
+        snap["backend"] = jax.default_backend()
         return snap
 
     @property
@@ -504,18 +487,9 @@ class InferenceEngine:
         # through the canonical DynamicBatcher fail path
         chaos.site("serving.infer", bucket=bucket)
 
-        def run():
-            # everything that can be the process's first backend touch
-            # lives INSIDE the failsoft retry: lazy _build (functionalize
-            # traces through the backend), host->device transfer, and the
-            # compiled call itself. A backend-init failure anywhere here
-            # flips to CPU and retries once instead of wedging the queue.
-            if self._fn is None:
-                self._build(staged)
-            x = jnp.asarray(staged)
-            return self._get_exec()(self._params, x)
-
-        out = failsoft_call(run)
+        if self._fn is None:
+            self._build(staged)
+        out = self._get_exec()(self._params, jnp.asarray(staged))
         out = jax.tree_util.tree_map(
             lambda a: a.block_until_ready()
             if hasattr(a, "block_until_ready") else a, out)

@@ -47,8 +47,7 @@ import jax
 import numpy as onp
 
 from .. import telemetry
-from ..base import (FatalError, MXNetError, TransientError, env_float,
-                    failsoft_call, preflight_backend)
+from ..base import FatalError, MXNetError, TransientError, env_float
 from ..resilience import chaos
 from ..resilience.retry import classify, TRANSIENT
 from ..telemetry import get_registry
@@ -539,9 +538,8 @@ class LLMEngine:
                        else spill_peers_from_env()),
                 serve=bool(kv_spill_serve))
 
-        preflight_backend()
         if donate is None:
-            donate = failsoft_call(jax.default_backend) not in ("cpu",)
+            donate = jax.default_backend() != "cpu"
         self._donate = bool(donate)
 
         self.metrics = metrics or LLMMetrics(str(next(_engine_seq)))

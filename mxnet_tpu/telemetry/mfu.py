@@ -133,7 +133,7 @@ class RooflineBank:
 
     def anchor(self, metric: str) -> Optional[Dict]:
         """The banked row for ``metric`` (e.g.
-        ``resnet50_v1_infer_bs32_bf16``), or None."""
+        ``resnet50_v1_infer_bs256_bf16``), or None."""
         self._ensure()
         return self._anchors.get(metric)
 

@@ -1,0 +1,224 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed in the sandbox and compiles for a chip that
+is described, not attached. Interpret-mode tests cannot see what it
+refuses (a block shape off the (8, 128) tiling, an i64 index under
+``jax_enable_x64``, an unsupported bitcast), so the kernels of the main
+path are compiled here at GPT-2-small shapes — units 768, 12 heads of 64,
+vocabulary 50,257, sequence 1024 — and so are one whole train step and
+one whole decode step of a 2-layer model at that width.
+
+Nothing runs, so nothing here says a word about results or speed. The
+topology is described inside a fixture, never at import: only one process
+may load the TPU's library, and every xdist worker imports every file.
+Keep these tests in this one file.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+R, H, D, U, V, L = 32, 12, 64, 768, 50257, 1024   # lanes, heads, ...
+NB, BS, MB = 2049, 16, 64                          # pool blocks (+trash)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: skip, loudly
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Code that asks ``jax.default_backend()`` still sees the CPU here;
+    steer it onto its TPU branch, from the test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, args, one_chip):
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _s(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+
+# --- one case per kernel of the main path ----------------------------------
+def _paged(pool_dtype):
+    from mxnet_tpu.ops.pallas.paged_attention import paged_attention_kernel
+
+    dp = D + 4 if pool_dtype == "int8" else D
+    pool = _s((NB, H, BS, dp), pool_dtype)
+    return (lambda q, k, v, bt, ln: paged_attention_kernel(
+                q, k, v, bt, ln, interpret=False),
+            (_s((R, H, D), "bfloat16"), pool, pool,
+             _s((R, MB), "int32"), _s((R,), "int32")))
+
+
+def _fused_qkv(store_dtype):
+    from mxnet_tpu.ops.pallas.fused_decode import fused_qkv_project
+
+    return (lambda x, w, b: fused_qkv_project(
+                x, w, b, heads=H, store_dtype=jnp.dtype(store_dtype),
+                interpret=False),
+            (_s((R, U), "bfloat16"), _s((3 * U, U), "bfloat16"),
+             _s((3 * U,), "bfloat16")))
+
+
+def _fused_out():
+    from mxnet_tpu.ops.pallas.fused_decode import fused_out_project
+
+    return (lambda a, w, b: fused_out_project(a, w, b, interpret=False),
+            (_s((R, U), "bfloat16"), _s((U, U), "bfloat16"),
+             _s((U,), "bfloat16")))
+
+
+def _flash(blocks, backward):
+    # the module, not the function of the same name the package exports
+    fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+    qkv = _s((8, H, L, D), "bfloat16")
+
+    def fwd(q, k, v):
+        return fa._flash(q, k, v, True, D ** -0.5, *blocks, False)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    return (fwd_bwd if backward else fwd), (qkv, qkv, qkv)
+
+
+def _layer_norm(dtype):
+    from mxnet_tpu.ops.pallas.layer_norm import fused_layer_norm
+
+    def fwd_bwd(x, g, b):
+        return jax.value_and_grad(
+            lambda *a: fused_layer_norm(*a, 1e-5, False)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(x, g, b)
+
+    return fwd_bwd, (_s((8 * L, U), dtype), _s((U,), dtype), _s((U,), dtype))
+
+
+def _cross_entropy(dtype):
+    from mxnet_tpu.ops.nn import softmax_cross_entropy
+
+    def fwd_bwd(logits, labels):
+        return jax.value_and_grad(
+            lambda x: softmax_cross_entropy(x, labels)[0]
+            .astype(jnp.float32))(logits)
+
+    return fwd_bwd, (_s((8 * L, V), dtype), _s((8 * L,), "int32"))
+
+
+KERNELS = {
+    "paged-float-pools": lambda: _paged("bfloat16"),
+    "paged-int8-pools": lambda: _paged("int8"),
+    "fused-qkv-float-store": lambda: _fused_qkv("bfloat16"),
+    "fused-qkv-int8-store": lambda: _fused_qkv("int8"),
+    "fused-out": _fused_out,
+    "flash-fwd-256x512": lambda: _flash((256, 512), False),
+    "flash-fwd-128x128": lambda: _flash((128, 128), False),
+    "flash-fwd-bwd-256x512": lambda: _flash((256, 512), True),
+    "flash-fwd-bwd-128x128": lambda: _flash((128, 128), True),
+    "layer-norm-f32": lambda: _layer_norm("float32"),
+    "layer-norm-bf16": lambda: _layer_norm("bfloat16"),
+    "cross-entropy-f32": lambda: _cross_entropy("float32"),
+    "cross-entropy-bf16": lambda: _cross_entropy("bfloat16"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache, on_tpu):
+    assert jax.config.jax_enable_x64      # base.py's setting is in force
+    fn, args = KERNELS[case]()
+    compiled = _compile(fn, args, one_chip)
+    assert "tpu_custom_call" in compiled.as_text(), case
+    if "fwd-bwd" in case:                 # the Pallas backward, not the scan
+        assert compiled.as_text().count("tpu_custom_call") >= 3, case
+
+
+# --- whole programs, 2 layers at full width --------------------------------
+@pytest.fixture(scope="module")
+def lm():
+    from mxnet_tpu.gluon.model_zoo import bert
+
+    net = bert.gpt_like(vocab_size=V, max_length=L, num_layers=2,
+                        dtype="bfloat16")
+    net.initialize()
+    return net
+
+
+def test_train_step_program_compiles_for_v5e(lm, one_chip,
+                                             no_compile_cache, on_tpu):
+    """Forward, loss and backward of the causal LM on a batch of
+    8 x 1024: the flash, layer-norm and cross-entropy kernels inside one
+    program, as the hybridized trainer step holds them."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops.nn import softmax_cross_entropy
+
+    fn, params = lm.functionalize(
+        mx.np.array(onp.zeros((8, L), onp.int32)), training=True)
+
+    def loss(p, x, labels, key):
+        logits, _ = fn(p, x, key=key)
+        return softmax_cross_entropy(logits.reshape(-1, V), labels)[0]
+
+    compiled = _compile(
+        jax.value_and_grad(loss),
+        (params, _s((8, L), "int32"), _s((8 * L,), "int32"),
+         _s((2,), "uint32")), one_chip)
+    # per layer: flash fwd + dq + dkv, two norms; then final norm and CE
+    assert compiled.as_text().count("tpu_custom_call") >= 2 * 5 + 2
+
+
+@pytest.mark.parametrize("kv_cache_dtype", ["int8", None])
+def test_decode_step_program_compiles_for_v5e(lm, kv_cache_dtype, one_chip,
+                                              no_compile_cache, on_tpu):
+    """The engine's one decode program at its default geometry (32 lanes,
+    2,048 blocks of 16, context 1024), paged kernel inside."""
+    from mxnet_tpu.gluon.model_zoo.generation import paged_decode_program
+
+    run, params = paged_decode_program(
+        lm, max_running=R, num_blocks=NB, block_size=BS,
+        max_blocks_per_seq=MB, kv_cache_dtype=kv_cache_dtype)
+    pool = (_s((2, NB, H, BS, D + 4), "int8") if kv_cache_dtype == "int8"
+            else _s((2, NB, H, BS, D), "bfloat16"))
+    compiled = _compile(
+        run._fn,
+        (params, _s((R, 1), "int32"), pool, pool, _s((R, MB), "int32"),
+         _s((R,), "int32"), _s((2,), "uint32")), one_chip)
+    # per layer: two norms and the paged kernel, whatever the pool dtype
+    assert compiled.as_text().count("tpu_custom_call") >= 2 * 3

@@ -763,7 +763,7 @@ def test_fused_decode_engine_token_identical(monkeypatch):
     with _engine(net) as eng:
         from mxnet_tpu.ops.pallas.fused_decode import fused_decode_armed
 
-        assert fused_decode_armed(kv_dtype="float32")
+        assert fused_decode_armed()
         for p_len, n_new in ((4, 5), (3, 6)):
             prompt = onp.arange(1, p_len + 1, dtype=onp.int32) % 37
             ref = generate(net, prompt[None], max_new_tokens=n_new,
@@ -805,18 +805,43 @@ def test_fused_decode_int8_pool_close_to_unfused(monkeypatch):
     onp.testing.assert_allclose(got_vals, ref_vals, rtol=0.1, atol=0.05)
 
 
-def test_fused_gate_cost_model_and_env(monkeypatch):
-    """The auto gate: off on CPU backends, on for TPU (memory-bound
-    verdict from the analysis.opt cost model); env overrides win."""
-    from mxnet_tpu.ops.pallas.fused_decode import (_cost_model_gate,
-                                                   fused_decode_armed)
+def test_fused_gate_is_the_env_knob_alone(monkeypatch):
+    """The fused trio is unarmed unless the knob asks for it — no
+    backend probe, no cost model — and never inside ``no_pallas``."""
+    from mxnet_tpu.ops.nn import no_pallas
+    from mxnet_tpu.ops.pallas.fused_decode import fused_decode_armed
 
-    monkeypatch.setenv("MXNET_TPU_LLM_FUSED_DECODE", "auto")
-    assert fused_decode_armed(kv_dtype="int8", backend="cpu") is False
-    assert _cost_model_gate("int8", "tpu") is True
-    assert fused_decode_armed(kv_dtype="int8", backend="tpu") is True
+    monkeypatch.delenv("MXNET_TPU_LLM_FUSED_DECODE", raising=False)
+    assert fused_decode_armed() is False
     monkeypatch.setenv("MXNET_TPU_LLM_FUSED_DECODE", "0")
-    assert fused_decode_armed(kv_dtype="int8", backend="tpu") is False
+    assert fused_decode_armed() is False
+    monkeypatch.setenv("MXNET_TPU_LLM_FUSED_DECODE", "1")
+    assert fused_decode_armed() is True
+    with no_pallas():
+        assert fused_decode_armed() is False
+
+
+def test_kv_quantizer_layout_is_values_then_the_scale_bytes():
+    """``kv_cache_quantize`` builds the scale's four bytes with integer
+    ops (what Mosaic accepts inside the kernels): they must be the f32's
+    own little-endian bytes, zero rows and wide scales included, and
+    ``kv_cache_dequantize`` must read them back."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.nn import kv_cache_dequantize, kv_cache_quantize
+
+    rng = onp.random.RandomState(3)
+    t = (rng.randn(37, 64) * rng.lognormal(0, 3, (37, 1))).astype("float32")
+    t[3] = 0.0
+    scale = onp.maximum(onp.abs(t).max(-1, keepdims=True),
+                        onp.float32(1e-6)) / onp.float32(127.0)
+    want = onp.concatenate(
+        [onp.clip(onp.round(t / scale), -127, 127).astype(onp.int8),
+         scale.astype("<f4").view(onp.int8)], axis=-1)
+    got = onp.asarray(kv_cache_quantize(jnp.asarray(t)))
+    onp.testing.assert_array_equal(got, want)
+    back = onp.asarray(kv_cache_dequantize(jnp.asarray(got), jnp.float32))
+    onp.testing.assert_array_equal(back, want[:, :64] * scale)
 
 
 @pytest.mark.seed(60)

@@ -318,59 +318,6 @@ def test_serve_bench_cli_smoke():
     assert row["value"] > 0 and row["mean_batch_occupancy"] > 1.0
 
 
-# ---------------------------------------------------------------------------
-# satellites: preflight fast path + bad-value warning
-# ---------------------------------------------------------------------------
-def test_preflight_bad_value_warns_and_uses_default(monkeypatch):
-    import subprocess as sp
-    import warnings
-
-    from mxnet_tpu import base
-
-    seen = {}
-
-    def fake_run(cmd, timeout=None, capture_output=None):
-        seen["timeout"] = timeout
-
-        class R:
-            returncode = 0
-        return R()
-
-    monkeypatch.setattr(sp, "run", fake_run)
-    monkeypatch.setenv("MXNET_TPU_PREFLIGHT", "5s")  # unparseable
-    monkeypatch.setitem(base._preflight, "done", False)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        base.preflight_backend()
-    msgs = [str(x.message) for x in w if "MXNET_TPU_PREFLIGHT" in str(x.message)]
-    assert len(msgs) == 1, f"expected ONE bad-value warning, got {msgs}"
-    assert "'5s'" in msgs[0]  # names the bad value
-    # the guard stays ARMED with the default deadline, not disabled
-    assert seen["timeout"] == base._PREFLIGHT_DEFAULT_S
-
-
-def test_preflight_done_fast_path_skips_lock(monkeypatch):
-    from mxnet_tpu import base
-
-    class CountingLock:
-        def __init__(self):
-            self.acquisitions = 0
-
-        def __enter__(self):
-            self.acquisitions += 1
-
-        def __exit__(self, *exc):
-            return False
-
-    lock = CountingLock()
-    monkeypatch.setenv("MXNET_TPU_PREFLIGHT", "30")
-    monkeypatch.setitem(base._preflight, "done", True)
-    monkeypatch.setitem(base._preflight, "lock", lock)
-    for _ in range(100):
-        base.preflight_backend()
-    assert lock.acquisitions == 0  # double-checked: no lock once done
-
-
 def test_serving_symbolblock_from_export(tmp_path):
     """The engine also serves a SymbolBlock loaded from a durable
     StableHLO export (the 'Symbol executor' case). Exports are

@@ -528,11 +528,11 @@ def test_roofline_bank_reads_banked_corpus():
     bank = tmfu.RooflineBank(os.path.join(REPO, "benchmark"))
     # the measured HBM row (results_hbm_tpu.json) beats the spec table
     assert bank.hbm_gbps("TPU v5 lite") == pytest.approx(542.8)
-    anchor = bank.anchor("resnet50_v1_infer_bs32_bf16")
+    anchor = bank.anchor("resnet50_v1_infer_bs256_bf16")
     assert anchor and anchor["value"] > 0
     out = tmfu.observe_step(
         "unit_vs_banked", examples=anchor["value"], dt_s=1.0,
-        banked_metric="resnet50_v1_infer_bs32_bf16")
+        banked_metric="resnet50_v1_infer_bs256_bf16")
     assert out["vs_banked"] == pytest.approx(1.0, rel=1e-6)
 
 

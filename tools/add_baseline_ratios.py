@@ -70,7 +70,6 @@ def patch_table(key_fn):
 
 def main():
     patch("results_bench_tpu_bs256.json", patch_headline_like)
-    patch("results_bench_tpu.json", patch_headline_like)
     patch("results_infer_tpu.json", patch_table(attach_infer_ratios))
     patch("results_train_tpu.json", patch_table(attach_train_ratios))
 
