@@ -27,6 +27,7 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 import jax
 
 from ..base import MXNetError
+from ..telemetry import tracing as _tracing
 
 __all__ = ["apply_op", "Tape", "autograd_state", "is_recording", "is_training"]
 
@@ -236,14 +237,23 @@ def backward(
     Accumulates into each leaf's ``.grad`` honoring its ``grad_req``
     (write/add/null — reference OpReqType, include/mxnet/op_attr_types.h).
     """
+    tape = autograd_state.tape
+    if tape is None:
+        raise MXNetError("backward called outside autograd.record scope with no tape")
+    with _tracing.span("autograd.backward", cpu=True,
+                       args={"nodes": len(tape.nodes)}) as sp:
+        _backward(tape, heads, head_grads, retain_graph, sp)
+
+
+def _backward(tape: Tape, heads, head_grads, retain_graph: bool, sp) -> None:
+    """The sweep of :func:`backward` inside its ``autograd.backward``
+    span ``sp``. Each pullback runs under an ``autograd.node:<op>``
+    annotation (no ring row: an un-hybridized net has thousands a step);
+    the ring gets their sums as ``sp.args["by_op"]``."""
     import jax.numpy as jnp
 
     from .. import engine as _engine
     from ..ndarray.ndarray import ndarray, _unwrap
-
-    tape = autograd_state.tape
-    if tape is None:
-        raise MXNetError("backward called outside autograd.record scope with no tape")
 
     # cotangent storage per (node_idx, slot)
     cots: dict = {}
@@ -281,6 +291,7 @@ def backward(
             pending_nodes.add(tape.producer[id(h)][0])
 
     # reverse topological sweep — tape order is already topological
+    by_op: dict = {}    # op name -> [wall s, pullbacks called]
     for idx in range(len(tape.nodes) - 1, -1, -1):
         node = tape.nodes[idx]
         slots = [cots.get((idx, s)) for s in range(node.n_out)]
@@ -300,12 +311,24 @@ def backward(
             return s
 
         full = tuple(_slot_ct(i, s) for i, s in enumerate(slots))
-        in_cts = node.vjp_fn(full[0] if node.n_out == 1 else full)
+        with _tracing.span("autograd.node:" + node.name, ring=False) as nsp:
+            in_cts = node.vjp_fn(full[0] if node.n_out == 1 else full)
+        acc = by_op.get(node.name)
+        if acc is None:
+            acc = by_op[node.name] = [0.0, 0]
+        acc[0] += nsp.dur_s
+        acc[1] += 1
         for arr, ct in zip(node.inputs, in_cts):
             _route(arr, ct)
         if not retain_graph:
             node.vjp_fn = None  # free residuals eagerly
             node.replay_fn = None
+
+    sp.args["ran"] = sum(acc[1] for acc in by_op.values())
+    sp.args["by_op"] = {
+        name: [round(acc[0] * 1e3, 3), acc[1]]
+        for name, acc in sorted(by_op.items(),
+                                key=lambda kv: -kv[1][0])[:8]}
 
     # write leaf grads honoring grad_req (and grad storage type)
     for key, ct in list(leaf_grads.items()):

@@ -68,7 +68,8 @@ class GenRequest(Request):
     """
 
     __slots__ = ("prompt", "max_new_tokens", "eos_token", "on_token",
-                 "tokens", "prefill_s", "first_token_s", "trace_id")
+                 "tokens", "admitted_s", "prefill_s", "first_token_s",
+                 "trace_id")
 
     def __init__(self, prompt, max_new_tokens: int, eos_token: int,
                  deadline: Optional[float],
@@ -80,6 +81,10 @@ class GenRequest(Request):
         self.eos_token = int(eos_token)
         self.on_token = on_token
         self.tokens: List[int] = []
+        # when the scheduler took the request out of the queue into a
+        # lane, on enqueue_t's clock (time.monotonic): the queue wait is
+        # admitted_s - enqueue_t, and prefill is not in it
+        self.admitted_s: Optional[float] = None
         self.prefill_s: Optional[float] = None
         self.first_token_s: Optional[float] = None
         # distributed-trace identity: minted at the cluster's front
@@ -225,6 +230,10 @@ class LLMMetrics:
             ("engine",)).labels(**eng)
         self.queue_depth = reg.histogram(
             "llm_queue_depth", "Queue depth at admission",
+            ("engine",)).labels(**eng)
+        self.queue_wait_ms = reg.histogram(
+            "llm_queue_wait_ms",
+            "Submission to admission into a lane (ms), prefill not in it",
             ("engine",)).labels(**eng)
 
     def observe_spec(self, proposed: int, accepted: int) -> None:
@@ -997,47 +1006,59 @@ class LLMEngine:
             return self._tick_locked()
 
     def _tick_locked(self):
-        if self._step_hook is not None:
-            # inside the containment: a hook fault (e.g. an armed
-            # serving.fleet.replica chaos rule) routes through _fault
-            self._step_hook()
-        self._sweep_lanes()
-        active = [i for i in range(self.max_running)
-                  if self._lanes[i] is not None]
-        free = [i for i in range(self.max_running)
-                if self._lanes[i] is None]
-        if free and (len(self._queue) or not active):
-            got = self._queue.take(
-                max_items=len(free), max_wait_s=0.0,
-                poll_s=0.02 if not active else 1e-4)
-            try:
-                while got:
-                    self._admit(got.pop(0), free.pop(0))
-            except Exception as e:
-                # an admission escalation (donated-buffer reset) aborts
-                # the tick: _admit already failed ITS request, but
-                # siblings popped from the queue in the same take() are
-                # in neither a lane nor the queue — fail them typed
-                # (transient: the client retry loop resubmits) instead
-                # of orphaning their wait() forever
-                for req in got:
-                    req.fail(ServerOverload(
-                        f"engine resetting mid-admission: {e!r}"))
-                    self.metrics.count("failed")
-                raise
+        occupied = sum(1 for ln in self._lanes if ln is not None)
+        with telemetry.span("llm.tick", args={
+                "active": occupied, "queue_len": len(self._queue),
+                "blocks_in_use": self.num_blocks - len(self._free)}) as tick:
+            if self._step_hook is not None:
+                # inside the containment: a hook fault (e.g. an armed
+                # serving.fleet.replica chaos rule) routes through _fault
+                self._step_hook()
+            if occupied:
+                self._sweep_lanes()
             active = [i for i in range(self.max_running)
                       if self._lanes[i] is not None]
             free = [i for i in range(self.max_running)
                     if self._lanes[i] is None]
-        if not active:
-            if self._closed and not len(self._queue):
-                return None
-            return True
-        if self._spec:
-            self._spec_step(active)
-        else:
-            self._decode_step(active)
-        return False
+            took = 0
+            if free and (len(self._queue) or not active):
+                got = self._queue.take(
+                    max_items=len(free), max_wait_s=0.0,
+                    poll_s=0.02 if not active else 1e-4)
+                took = len(got)
+                try:
+                    while got:
+                        self._admit(got.pop(0), free.pop(0))
+                except Exception as e:
+                    # an admission escalation (donated-buffer reset) aborts
+                    # the tick: _admit already failed ITS request, but
+                    # siblings popped from the queue in the same take() are
+                    # in neither a lane nor the queue — fail them typed
+                    # (transient: the client retry loop resubmits) instead
+                    # of orphaning their wait() forever
+                    for req in got:
+                        req.fail(ServerOverload(
+                            f"engine resetting mid-admission: {e!r}"))
+                        self.metrics.count("failed")
+                    raise
+                active = [i for i in range(self.max_running)
+                          if self._lanes[i] is not None]
+                free = [i for i in range(self.max_running)
+                        if self._lanes[i] is None]
+            if not active:
+                if not occupied and not took:
+                    # no lane held a request and none came: an idle engine
+                    # spins here a thousand times a second, and those ticks
+                    # would push everything else out of the ring
+                    tick.ring = False
+                if self._closed and not len(self._queue):
+                    return None
+                return True
+            if self._spec:
+                self._spec_step(active)
+            else:
+                self._decode_step(active)
+            return False
 
     def _sweep_lanes(self) -> None:
         """Retire lanes whose request no longer wants to run: cancelled
@@ -1046,36 +1067,38 @@ class LLMEngine:
         mid-decode (the work would stream to a client that already gave
         up; retire it and free the blocks instead). Runs at the top of
         every tick, so a freed lane is admittable the same tick."""
-        now = time.monotonic()
-        retired = False
-        for i in range(self.max_running):
-            lane = self._lanes[i]
-            if lane is None:
-                continue
-            req = lane.req
-            if req.cancelled:
-                retired = True
-                self._release(lane, i)
-                if req.fail(RequestCancelled(
-                        "request cancelled mid-generation — lane "
-                        f"retired after {len(req.tokens)} token(s)")):
-                    self.metrics.count("cancelled")
-                continue
-            if req.deadline is not None and now > req.deadline:
-                elapsed = now - req.enqueue_t
-                budget = req.deadline - req.enqueue_t
-                retired = True
-                self._release(lane, i)
-                if req.fail(DeadlineExceeded(
-                        f"deadline passed mid-decode ({elapsed * 1e3:.1f} "
-                        f"ms elapsed vs a {budget * 1e3:.1f} ms budget, "
-                        f"{len(req.tokens)} token(s) generated) — lane "
-                        "retired, remaining work not spent",
-                        elapsed_s=elapsed, budget_s=budget)):
-                    self.metrics.count("retired_deadline")
-        if retired:
-            self.metrics.lanes_active.set(
-                sum(1 for ln in self._lanes if ln is not None))
+        with telemetry.span("llm.sweep") as sp:
+            now = time.monotonic()
+            retired = 0
+            for i in range(self.max_running):
+                lane = self._lanes[i]
+                if lane is None:
+                    continue
+                req = lane.req
+                if req.cancelled:
+                    retired += 1
+                    self._release(lane, i)
+                    if req.fail(RequestCancelled(
+                            "request cancelled mid-generation — lane "
+                            f"retired after {len(req.tokens)} token(s)")):
+                        self.metrics.count("cancelled")
+                    continue
+                if req.deadline is not None and now > req.deadline:
+                    elapsed = now - req.enqueue_t
+                    budget = req.deadline - req.enqueue_t
+                    retired += 1
+                    self._release(lane, i)
+                    if req.fail(DeadlineExceeded(
+                            f"deadline passed mid-decode ({elapsed * 1e3:.1f} "
+                            f"ms elapsed vs a {budget * 1e3:.1f} ms budget, "
+                            f"{len(req.tokens)} token(s) generated) — lane "
+                            "retired, remaining work not spent",
+                            elapsed_s=elapsed, budget_s=budget)):
+                        self.metrics.count("retired_deadline")
+            sp.args["retired"] = retired
+            if retired:
+                self.metrics.lanes_active.set(
+                    sum(1 for ln in self._lanes if ln is not None))
 
     def _admit(self, req: GenRequest, lane_idx: int) -> None:
         """Prefill ``req`` into ``lane_idx`` (or shed it typed: expired
@@ -1094,7 +1117,10 @@ class LLMEngine:
         here first-wins, then propagates to :meth:`_fault` so pool /
         cache / refcount state rebuilds consistently."""
         try:
-            self._admit_locked(req, lane_idx)
+            with telemetry.span(
+                    "llm.admit", args={"trace_id": req.trace_id}
+                    if req.trace_id is not None else None) as sp:
+                self._admit_locked(req, lane_idx, sp)
         except Exception as e:  # noqa: BLE001 — typed + escalated
             if isinstance(e, (TransientError, FatalError)):
                 typed = e
@@ -1107,8 +1133,9 @@ class LLMEngine:
                 self.metrics.count("failed")
             raise
 
-    def _admit_locked(self, req: GenRequest, lane_idx: int) -> None:
+    def _admit_locked(self, req: GenRequest, lane_idx: int, sp) -> None:
         now = time.monotonic()
+        sp.args["queue_wait_ms"] = round((now - req.enqueue_t) * 1e3, 3)
         if req.expired(now):
             self.metrics.count("shed_deadline")
             req.fail(DeadlineExceeded(
@@ -1118,6 +1145,7 @@ class LLMEngine:
         p = int(req.prompt.shape[0])
         bs = self.block_size
         need = -(-(p + req.max_new_tokens + self._slack) // bs)
+        sp.args["prompt_tokens"] = p
         # prefix-cache lookup: the longest run of resident chain hashes
         # (consecutive dict hits == the radix descent, since hash j
         # commits to the whole prefix through block j)
@@ -1200,7 +1228,12 @@ class LLMEngine:
             self.metrics.observe_prefix(n_hit * bs, p - n_hit * bs)
             if n_hit:
                 self._prefix_hits += 1
-        t0 = time.perf_counter()
+        # the request has its blocks: it is admitted, and has waited
+        # from submission until the top of this call
+        req.admitted_s = now
+        self.metrics.queue_wait_ms.observe(sp.args["queue_wait_ms"])
+        sp.args.update(bucket=self._prefill_bucket(p - n_hit * bs),
+                       blocks=len(blocks), prefix_hit_blocks=n_hit)
         ran = False
         try:
             # the chaos injection point for the splice path: an injected
@@ -1209,9 +1242,11 @@ class LLMEngine:
             chaos.site("serving.llm", phase="prefill_splice",
                        prefix_hit_blocks=n_hit)
             with telemetry.step("llm_prefill") as st:
+                tid = None
                 if req.trace_id is not None:
+                    tid = {"trace_id": req.trace_id}
                     st.annotate("trace_id", req.trace_id)
-                with st.phase("device", "llm.prefill"):
+                with st.phase("device", "llm.prefill", tid) as prefill:
                     ran = True
                     if n_hit:
                         first = self._suffix_prefill(req, blocks, n_hit)
@@ -1239,9 +1274,8 @@ class LLMEngine:
                 # request is already failed; lanes/pool rebuild there)
                 raise
             return
-        dt = time.perf_counter() - t0
         self.metrics.count("prefills")
-        self.metrics.prefill_ms.observe(dt * 1e3)
+        self.metrics.prefill_ms.observe(prefill.dur_s * 1e3)
         self.metrics.tokens_prefill.inc()
         # admit this prompt's freshly-computed full blocks into the
         # cache (+1 cache ref each; they are never written again —
@@ -1267,7 +1301,7 @@ class LLMEngine:
                 # contained miss and the decode side re-prefills.)
                 self._spill_save(fresh_cached)
                 self.metrics.handoff_exported.inc(len(fresh_cached))
-        req.prefill_s = dt
+        req.prefill_s = prefill.dur_s
         req.first_token_s = req.latency_s
         lane = _Lane(req, blocks, pos=p, last_token=first)
         if not self._push_token(lane, first):
@@ -1366,41 +1400,46 @@ class LLMEngine:
         return out
 
     def _decode_step(self, active: List[int]) -> None:
-        t0 = time.perf_counter()
         self._step_seq += 1
         with telemetry.step("llm_decode", self._step_seq) as st:
             tids = self._lane_trace_ids(active)
+            at = {"step": self._step_seq}
             if tids:
                 st.annotate("trace_ids", tids)
-            with st.phase("device", "llm.decode"):
+            # launch: the host hands the decode program its arguments and
+            # gets futures back; fetch: the host waits for the chip
+            with st.phase("device", "llm.decode.launch",
+                          dict(at, trace_ids=tids) if tids else at) as launch:
                 nxt, self._pool_k, self._pool_v = self._decode_run(
                     self._params, self._toks, self._pool_k, self._pool_v,
                     self._bt, self._pos, self._next_key())
+            with st.phase("device", "llm.decode.fetch", at) as fetch:
                 nxt = onp.asarray(nxt)
-        dt = time.perf_counter() - t0
-        self.metrics.count("decode_steps")
-        self.metrics.decode_ms.observe(dt * 1e3)
-        self.metrics.token_latency_ms.observe(dt * 1e3 / len(active))
-        self.metrics.tokens_decode.inc(len(active))
-        self._record_manifest(
-            "llm.decode", self.max_running, self._decode_run,
-            (self._params, self._toks, self._pool_k, self._pool_v,
-             self._bt, self._pos, self._key))
-        self._observe_tok_s(len(active))
-        for i in active:
-            lane = self._lanes[i]
-            tok = int(nxt[i])
-            lane.pos += 1
-            lane.last_token = tok
-            if not self._push_token(lane, tok):
-                self._release(lane, i)
-                continue
-            if self._retire_if_done(lane, lane_idx=i):
-                continue
-            self._pos[i] = lane.pos
-            self._toks[i, 0] = tok
-        self.metrics.lanes_active.set(
-            sum(1 for ln in self._lanes if ln is not None))
+        with telemetry.span("llm.emit", args={"tokens": len(active)}):
+            step_ms = (launch.dur_s + fetch.dur_s) * 1e3
+            self.metrics.count("decode_steps")
+            self.metrics.decode_ms.observe(step_ms)
+            self.metrics.token_latency_ms.observe(step_ms / len(active))
+            self.metrics.tokens_decode.inc(len(active))
+            self._record_manifest(
+                "llm.decode", self.max_running, self._decode_run,
+                (self._params, self._toks, self._pool_k, self._pool_v,
+                 self._bt, self._pos, self._key))
+            self._observe_tok_s(len(active))
+            for i in active:
+                lane = self._lanes[i]
+                tok = int(nxt[i])
+                lane.pos += 1
+                lane.last_token = tok
+                if not self._push_token(lane, tok):
+                    self._release(lane, i)
+                    continue
+                if self._retire_if_done(lane, lane_idx=i):
+                    continue
+                self._pos[i] = lane.pos
+                self._toks[i, 0] = tok
+            self.metrics.lanes_active.set(
+                sum(1 for ln in self._lanes if ln is not None))
 
     def _spec_step(self, active: List[int]) -> None:
         """One speculative round over the whole lane set: the draft
@@ -1410,13 +1449,14 @@ class LLMEngine:
         advances by ``n_acc + 1`` tokens per round instead of 1.
         Inactive lanes ride along pointed at the trash block (their
         outputs are garbage the loop below never reads)."""
-        t0 = time.perf_counter()
         self._step_seq += 1
         with telemetry.step("llm_spec", self._step_seq) as st:
             tids = self._lane_trace_ids(active)
+            at = {"step": self._step_seq}
             if tids:
                 st.annotate("trace_ids", tids)
-            with st.phase("device", "llm.spec"):
+            with st.phase("device", "llm.decode.launch",
+                          dict(at, trace_ids=tids) if tids else at) as launch:
                 # the draft-verify splice chaos site: an injected fault
                 # propagates to _fault(), which fails the in-flight
                 # requests typed-transient and keeps the engine serving
@@ -1431,61 +1471,65 @@ class LLMEngine:
                         self._params, self._toks, d_toks, d_lgs,
                         self._pool_k, self._pool_v, self._bt, self._pos,
                         self._next_key())
+            with st.phase("device", "llm.decode.fetch", at) as fetch:
                 out = onp.asarray(out)
                 n_acc = onp.asarray(n_acc)
-        dt = time.perf_counter() - t0
-        self.metrics.count("spec_steps")
-        self.metrics.count("decode_steps")
-        self.metrics.decode_ms.observe(dt * 1e3)
-        self.metrics.spec_ms.observe(dt * 1e3)
-        self._record_manifest(
-            "llm.draft", self._draft_k, self._draft_run,
-            (self._draft_params, self._prev, self._toks, self._dpool_k,
-             self._dpool_v, self._bt, self._pos, self._key))
-        self._record_manifest(
-            "llm.verify", self._draft_k, self._verify_run,
-            (self._params, self._toks, d_toks, d_lgs, self._pool_k,
-             self._pool_v, self._bt, self._pos, self._key))
-        emitted_total = 0
-        accepted_total = 0
-        for i in active:
-            lane = self._lanes[i]
-            n_take = int(n_acc[i]) + 1
-            accepted_total += int(n_acc[i])
-            prev_last = lane.last_token
-            gone = False
-            emitted = 0
-            for j in range(n_take):
-                tok = int(out[i, j])
-                emitted += 1
-                lane.last_token = tok
-                if not self._push_token(lane, tok):
-                    self._release(lane, i)
-                    gone = True
-                    break
-                if self._retire_if_done(lane, lane_idx=i):
-                    gone = True
-                    break
-            emitted_total += emitted
-            if gone:
-                continue
-            # full window emitted: KV for [last, d_0..d_{n_acc-1}] is
-            # resident at pos..pos+n_acc; the corrected/bonus token is
-            # the new last (written next round); the token at the new
-            # pos-1 (the draft catch-up input) is the last ACCEPTED one
-            lane.pos += n_take
-            self._pos[i] = lane.pos
-            self._toks[i, 0] = lane.last_token
-            self._prev[i, 0] = (int(out[i, n_take - 2]) if n_take >= 2
-                                else prev_last)
-        self.metrics.observe_spec(self._draft_k * len(active),
-                                  accepted_total)
-        if emitted_total:
-            self.metrics.token_latency_ms.observe(dt * 1e3 / emitted_total)
-            self.metrics.tokens_decode.inc(emitted_total)
-            self._observe_tok_s(emitted_total)
-        self.metrics.lanes_active.set(
-            sum(1 for ln in self._lanes if ln is not None))
+        with telemetry.span("llm.emit") as emit:
+            step_ms = (launch.dur_s + fetch.dur_s) * 1e3
+            self.metrics.count("spec_steps")
+            self.metrics.count("decode_steps")
+            self.metrics.decode_ms.observe(step_ms)
+            self.metrics.spec_ms.observe(step_ms)
+            self._record_manifest(
+                "llm.draft", self._draft_k, self._draft_run,
+                (self._draft_params, self._prev, self._toks, self._dpool_k,
+                 self._dpool_v, self._bt, self._pos, self._key))
+            self._record_manifest(
+                "llm.verify", self._draft_k, self._verify_run,
+                (self._params, self._toks, d_toks, d_lgs, self._pool_k,
+                 self._pool_v, self._bt, self._pos, self._key))
+            emitted_total = 0
+            accepted_total = 0
+            for i in active:
+                lane = self._lanes[i]
+                n_take = int(n_acc[i]) + 1
+                accepted_total += int(n_acc[i])
+                prev_last = lane.last_token
+                gone = False
+                emitted = 0
+                for j in range(n_take):
+                    tok = int(out[i, j])
+                    emitted += 1
+                    lane.last_token = tok
+                    if not self._push_token(lane, tok):
+                        self._release(lane, i)
+                        gone = True
+                        break
+                    if self._retire_if_done(lane, lane_idx=i):
+                        gone = True
+                        break
+                emitted_total += emitted
+                if gone:
+                    continue
+                # full window emitted: KV for [last, d_0..d_{n_acc-1}] is
+                # resident at pos..pos+n_acc; the corrected/bonus token is
+                # the new last (written next round); the token at the new
+                # pos-1 (the draft catch-up input) is the last ACCEPTED one
+                lane.pos += n_take
+                self._pos[i] = lane.pos
+                self._toks[i, 0] = lane.last_token
+                self._prev[i, 0] = (int(out[i, n_take - 2]) if n_take >= 2
+                                    else prev_last)
+            self.metrics.observe_spec(self._draft_k * len(active),
+                                      accepted_total)
+            emit.args["tokens"] = emitted_total
+            if emitted_total:
+                self.metrics.token_latency_ms.observe(
+                    step_ms / emitted_total)
+                self.metrics.tokens_decode.inc(emitted_total)
+                self._observe_tok_s(emitted_total)
+            self.metrics.lanes_active.set(
+                sum(1 for ln in self._lanes if ln is not None))
 
     def _push_token(self, lane: _Lane, tok: int) -> bool:
         """Record + stream one token. Returns False when the request's
@@ -1744,6 +1788,7 @@ class LLMEngine:
             "tok_s": round(float(self.metrics.tok_s.get()), 2),
             "decode_step_ms": self.metrics.decode_ms.summary(),
             "prefill_ms": self.metrics.prefill_ms.summary(),
+            "queue_wait_ms": self.metrics.queue_wait_ms.summary(),
             "token_latency_ms": self.metrics.token_latency_ms.summary(),
             "queue_len": len(self._queue),
             "aot": aot.stats(),

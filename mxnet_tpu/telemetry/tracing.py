@@ -25,18 +25,34 @@ wall time into four buckets:
   Python glue. Computed as ``wall - (compile + device + input_starved)``
   so the buckets sum to the measured wall time by construction.
 
+**Spans** (:class:`span`) are the one timing primitive of the package:
+a span records its name, start and end on ``time.perf_counter``, a
+process-wide ``id``, the ``id`` of its ``parent`` (the span open on the
+same thread when it started) and the ambient ``trace_id``; where the
+site asks for it (``cpu=True``) also the thread's CPU time inside it
+(``cpu_us``; ``dur - cpu_us`` is time the thread was off the CPU). For
+the same interval it holds a
+``jax.profiler.TraceAnnotation("mxnet_tpu.<name>")``, so that the span is
+also an event on its thread's line of ``/host:CPU`` in a profiler trace,
+on the clock of the device's operations (a no-op of well under a
+microsecond while no profiler session runs). A step's phases and the
+step itself are spans. :func:`rows` reads the ring's spans back.
+
 All recording is host arithmetic + one bounded-deque append — no device
 syncs (tpulint A001) and cheap enough to leave on permanently at step
 granularity.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from .registry import get_registry
 
@@ -45,11 +61,15 @@ __all__ = [
     "span", "step", "current_step", "attribute", "phase_if_active",
     "chrome_trace", "dump_chrome", "now_us", "emit_complete",
     "emit_counter", "emit_instant", "new_trace_id", "current_trace",
-    "trace_scope", "bind_trace", "clock_anchor",
+    "trace_scope", "bind_trace", "clock_anchor", "rows",
 ]
 
 #: Step attribution buckets (``host`` is the computed remainder).
 BUCKETS = ("compile", "device", "input_starved", "host")
+
+#: per-thread state: the bound trace context (``trace``), the innermost
+#: open step (``step``) and the ids of the open spans (``spans``)
+_tls = threading.local()
 
 
 def _env_int(name: str, default: int) -> int:
@@ -230,12 +250,26 @@ def buffer() -> TraceBuffer:
     return _buffer
 
 
+#: this process's id for the ring's rows: ``os.getpid()`` is a system call
+#: on every row (5.7 us on the chip's host, PR 26); a forked child gets
+#: its own
+_pid = os.getpid()
+
+
+def _pid_after_fork() -> None:
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_pid_after_fork)
+
+
 def emit_complete(name: str, ts_us: float, dur_us: float,
                   cat: str = "telemetry",
                   args: Optional[dict] = None,
                   tid: Optional[int] = None) -> None:
     ev = {"name": name, "cat": cat, "ph": "X", "ts": ts_us,
-          "dur": dur_us, "pid": os.getpid(),
+          "dur": dur_us, "pid": _pid,
           "tid": tid if tid is not None
           else threading.get_ident() % 10000}
     if args:
@@ -247,44 +281,128 @@ def emit_counter(name: str, value: float,
                  ts_us: Optional[float] = None) -> None:
     _buffer.append({"name": name, "ph": "C",
                     "ts": now_us() if ts_us is None else ts_us,
-                    "pid": os.getpid(), "args": {"value": value}})
+                    "pid": _pid, "args": {"value": value}})
 
 
 def emit_instant(name: str, cat: str = "telemetry",
                  args: Optional[dict] = None) -> None:
     ev = {"name": name, "cat": cat, "ph": "i", "ts": now_us(),
-          "pid": os.getpid(), "tid": threading.get_ident() % 10000,
+          "pid": _pid, "tid": threading.get_ident() % 10000,
           "s": "p"}
     if args:
         ev["args"] = args
     _buffer.append(ev)
 
 
-class span:
-    """Context manager adding one named complete span to the ring."""
+#: span ids: one process-wide sequence (``next`` is atomic under the GIL)
+_span_ids = itertools.count(1)
 
-    __slots__ = ("name", "cat", "args", "_t0")
+#: every annotation the program writes into a profiler trace starts so
+ANNOTATION_PREFIX = "mxnet_tpu."
+
+
+class span:
+    """Context manager timing one named interval on this thread.
+
+    On exit one complete event goes to the ring: ``ts``/``dur`` from
+    ``time.perf_counter`` (read once at enter, once at exit) and, in
+    ``args``, what the caller gave plus ``id``, ``parent`` (absent for a
+    root) and the ambient ``trace_id`` (unless ``args`` already has one —
+    the LLM scheduler passes its request's). The same interval is a
+    ``TraceAnnotation`` named ``mxnet_tpu.<name>``. ``sp.dur_s`` is set at
+    exit, for a caller that feeds a histogram from it.
+
+    ``args`` may be filled while the span is open (``sp.args["n"] = 3``).
+    ``cpu=True`` also records ``cpu_us``, the thread's CPU time inside the
+    span (``dur - cpu_us`` is time the thread was off the CPU): two
+    ``time.thread_time()`` system calls, 6 us each on the chip's host, so
+    only where a reader wants it (``autograd.backward``).
+    ``ring=False`` keeps the annotation and ``dur_s`` but writes no ring
+    row, and the span is nobody's parent: for sites that run thousands of
+    times a step (``autograd.node:<op>``). Set before the exit
+    (``sp.ring = False``) it drops the row of a span that turned out to
+    hold nothing (an idle scheduler tick).
+    """
+
+    __slots__ = ("name", "cat", "args", "ring", "id", "parent", "start_s",
+                 "dur_s", "_cpu0", "_ann")
 
     def __init__(self, name: str, cat: str = "telemetry",
-                 args: Optional[dict] = None):
-        self.name, self.cat, self.args = name, cat, args
+                 args: Optional[dict] = None, ring: bool = True,
+                 cpu: bool = False):
+        self.name, self.cat, self.ring = name, cat, ring
+        self.args = dict(args) if args else {}
+        self.id = self.parent = None
+        self._cpu0 = 0.0 if cpu else None
 
     def __enter__(self) -> "span":
-        self._t0 = time.perf_counter()
+        if self.ring:
+            try:
+                stack = _tls.spans
+            except AttributeError:
+                stack = _tls.spans = []
+            self.id = next(_span_ids)
+            self.parent = stack[-1] if stack else None
+            stack.append(self.id)
+        self._ann = _Annotation(ANNOTATION_PREFIX + self.name)
+        self._ann.__enter__()
+        # the wall clock is read outside the CPU clock, so cpu <= dur
+        self.start_s = time.perf_counter()
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time()
         return self
 
     def __exit__(self, *exc) -> bool:
-        dur = time.perf_counter() - self._t0
-        emit_complete(self.name, now_us() - dur * 1e6, dur * 1e6,
-                      self.cat, self.args)
+        if self._cpu0 is not None:
+            cpu = time.thread_time() - self._cpu0
+        self.dur_s = time.perf_counter() - self.start_s
+        self._ann.__exit__(None, None, None)
+        if self.id is None:
+            return False
+        # () where this thread never opened a span: one entered on
+        # another thread is on that thread's stack, not on ours
+        stack = getattr(_tls, "spans", ())
+        if stack and stack[-1] == self.id:
+            stack.pop()
+        elif self.id in stack:      # left out of order: drop the orphans
+            del stack[stack.index(self.id):]
+        if not self.ring:
+            return False
+        args = self.args
+        args["id"] = self.id
+        if self.parent is not None:
+            args["parent"] = self.parent
+        if self._cpu0 is not None:
+            args["cpu_us"] = round(
+                max(0.0, min(cpu, self.dur_s)) * 1e6, 1)
+        ctx = getattr(_tls, "trace", None)
+        if ctx is not None and "trace_id" not in args:
+            args["trace_id"] = ctx.trace_id
+        emit_complete(self.name, self.start_s * 1e6, self.dur_s * 1e6,
+                      self.cat, args)
         return False
+
+
+def rows(lo_s: float, hi_s: float,
+         name: Optional[str] = None) -> List[Tuple[str, float, float, dict]]:
+    """The ring's complete spans that lie wholly inside the
+    ``perf_counter`` interval ``[lo_s, hi_s]`` (all of them, or those
+    called ``name``), as ``(name, start_s, end_s, args)`` — how a reader
+    or a test gets at the spans. A span still open, or cut by an end of
+    the interval, is not returned."""
+    out = []
+    for ev in _buffer.snapshot():
+        if ev.get("ph") != "X" or (name is not None and ev["name"] != name):
+            continue
+        start, end = ev["ts"] / 1e6, (ev["ts"] + ev["dur"]) / 1e6
+        if start >= lo_s and end <= hi_s:
+            out.append((ev["name"], start, end, ev.get("args") or {}))
+    return out
 
 
 # ---------------------------------------------------------------------------
 # step timelines
 # ---------------------------------------------------------------------------
-_tls = threading.local()
-
 # registry families (registered once at import; children created lazily)
 _reg = get_registry()
 _steps_total = _reg.counter(
@@ -337,33 +455,33 @@ def _ensure_compile_listener() -> None:
             _compile_listener_installed = True  # degrade to hook-less
 
 
-class _Phase:
-    __slots__ = ("_st", "_bucket", "_label", "_t0", "_noop")
+class _Phase(span):
+    """A span that also adds its time to a bucket of its step."""
 
-    def __init__(self, st: "StepTimeline", bucket: str, label: str):
+    __slots__ = ("_st", "_bucket", "_nested")
+
+    def __init__(self, st: "StepTimeline", bucket: str, label: str,
+                 args: Optional[dict] = None):
+        super().__init__(label, f"step.{bucket}", args)
         self._st = st
         self._bucket = bucket
-        self._label = label
 
     def __enter__(self) -> "_Phase":
-        # a phase nested inside an open phase records nothing — the
-        # outer phase already owns this wall time (e.g. a bench wrapping
-        # trainer.step + barrier in phase('device') around the Trainer's
-        # own internal device phase must not double-count)
-        self._noop = self._st._open_phase is not None
-        if not self._noop:
+        # a phase nested inside an open phase adds nothing to a bucket —
+        # the outer phase already owns this wall time (e.g. a bench
+        # wrapping trainer.step + barrier in phase('device') around the
+        # Trainer's own internal device phase must not double-count); it
+        # is still a span, a child of the outer one
+        self._nested = self._st._open_phase is not None
+        if not self._nested:
             self._st._open_phase = self._bucket
-        self._t0 = time.perf_counter()
-        return self
+        return super().__enter__()
 
     def __exit__(self, *exc) -> bool:
-        if self._noop:
-            return False
-        dur = time.perf_counter() - self._t0
-        self._st._open_phase = None
-        self._st.add(self._bucket, dur)
-        emit_complete(self._label, now_us() - dur * 1e6, dur * 1e6,
-                      cat=f"step.{self._bucket}")
+        super().__exit__(*exc)
+        if not self._nested:
+            self._st._open_phase = None
+            self._st.add(self._bucket, self.dur_s)
         return False
 
 
@@ -377,7 +495,7 @@ class StepTimeline:
     or attribute manually with :meth:`phase` / :meth:`add`.
     """
 
-    __slots__ = ("name", "index", "_t0", "_wall", "_buckets",
+    __slots__ = ("name", "index", "_span", "_wall", "_buckets",
                  "_open_phase", "_compile_in_device", "_prev",
                  "_cancelled", "_annotations")
 
@@ -393,14 +511,18 @@ class StepTimeline:
         self._prev = None
         self._cancelled = False
         self._annotations: Optional[Dict] = None
+        # the step is itself a span: the parent of its phases and of
+        # whatever else opens inside it
+        self._span = span(f"step[{name}]", cat="step")
 
     # -- recording --------------------------------------------------------
-    def phase(self, bucket: str, label: Optional[str] = None) -> _Phase:
+    def phase(self, bucket: str, label: Optional[str] = None,
+              args: Optional[dict] = None) -> _Phase:
         if bucket not in self._buckets:
             raise ValueError(
                 f"unknown bucket {bucket!r} (one of "
                 f"{tuple(self._buckets)}; 'host' is the remainder)")
-        return _Phase(self, bucket, label or f"{self.name}.{bucket}")
+        return _Phase(self, bucket, label or f"{self.name}.{bucket}", args)
 
     def add(self, bucket: str, dur_s: float) -> None:
         """Attribute ``dur_s`` seconds to ``bucket`` (hook entry point:
@@ -438,35 +560,32 @@ class StepTimeline:
     def __enter__(self) -> "StepTimeline":
         self._prev = getattr(_tls, "step", None)
         _tls.step = self
-        self._t0 = time.perf_counter()
+        self._span.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._wall = time.perf_counter() - self._t0
+        self._wall = time.perf_counter() - self._span.start_s
         _tls.step = self._prev
-        if not self._cancelled:
-            self._finish()
-        return False
-
-    def _finish(self) -> None:
+        if self._cancelled:
+            self._span.ring = False
+            self._span.__exit__(*exc)
+            return False
         att = self.attribution()
-        args = {k: round(v * 1e3, 3) for k, v in att.items()}
+        args = self._span.args
+        args.update((k, round(v * 1e3, 3)) for k, v in att.items())
         args["wall_ms"] = round(self._wall * 1e3, 3)
         if self.index is not None:
             args["step"] = self.index
         if self._annotations:
             args.update(self._annotations)
-        ctx = getattr(_tls, "trace", None)
-        if ctx is not None and "trace_id" not in args:
-            args["trace_id"] = ctx.trace_id
-        emit_complete(f"step[{self.name}]",
-                      now_us() - self._wall * 1e6, self._wall * 1e6,
-                      cat="step", args=args)
+        # the row and the annotation end here; the registry is not in them
+        self._span.__exit__(*exc)
         _steps_total.labels(name=self.name).inc()
         _step_ms.labels(name=self.name).observe(self._wall * 1e3)
         for bucket, dur in att.items():
             _bucket_ms.labels(name=self.name,
                               bucket=bucket).observe(dur * 1e3)
+        return False
 
     # -- reading ----------------------------------------------------------
     @property
