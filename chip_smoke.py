@@ -369,8 +369,8 @@ def sharded_phase(sz, seed, compiles, devices):
         for pool in (eng._pool_k, eng._pool_v):
             shards = pool.addressable_shards
             assert {s.device for s in shards} == set(devices)
-            assert all(s.data.shape[2] * 4 == pool.shape[2]
-                       for s in shards)          # the head axis
+            assert all(s.data.shape[-1] * 4 == pool.shape[-1]
+                       for s in shards)          # a row's heads, in groups
         sharded = [k for k, v in eng._params.items()
                    if not v.sharding.is_fully_replicated]
         for k in sharded:

@@ -181,14 +181,23 @@ class _CausalLM(HybridBlock):
         return seq @ w.T, pk, pv
 
     def init_block_pool(self, num_blocks, block_size, dtype="float32"):
-        """Zeroed (L, NB, H, block_size, D) paged K/V block pools.
+        """Zeroed ``(L, NB, block_size, H*D')`` paged K/V block pools.
+
+        The one pool layout (:func:`~mxnet_tpu.ops.nn.kv_pool_rows`): a
+        row per token with every head side by side. At GPT-2 widths a
+        row (``H*D`` = 768, 1,280) is a whole multiple of the chip's 128
+        lanes, so the engine's buffer, the decode step's row write and
+        the kernel's block all use plain row-major and the donated pool
+        is updated in place; with the head size (64) innermost the three
+        disagreed and every layer converted a whole pool twice.
 
         The paged analogue of :meth:`init_cache`: pool capacity — not
         ``max_length x max_batch`` — bounds KV memory; a sequence owns
         ``ceil(context / block_size)`` blocks via its block table and
         returns them the moment it finishes. ``dtype="int8"`` stores
-        quantized blocks (+4 bitcast scale bytes on the feature axis,
-        see :func:`~mxnet_tpu.ops.nn.kv_cache_quantize`)."""
+        quantized blocks (``D' = D + 4``: each head's values, then its
+        4 bitcast scale bytes, see
+        :func:`~mxnet_tpu.ops.nn.kv_cache_quantize`)."""
         from ... import numpy as mxnp
 
         enc = self.encoder
@@ -198,7 +207,7 @@ class _CausalLM(HybridBlock):
             from ..nn.transformer import _KV_SCALE_BYTES
 
             d += _KV_SCALE_BYTES
-        shape = (enc._num_layers, num_blocks, heads, block_size, d)
+        shape = (enc._num_layers, num_blocks, block_size, heads * d)
         return mxnp.zeros(shape, dtype=dtype), mxnp.zeros(shape, dtype=dtype)
 
     def init_cache(self, batch_size, max_length, dtype="float32"):

@@ -20,6 +20,7 @@ import numpy as onp
 
 from ...base import MXNetError
 from ...ndarray.ndarray import ndarray, _unwrap, _wrap
+from ...ops.nn import kv_pool_rows
 from ..block import HybridBlock
 
 __all__ = ["generate", "beam_search", "paged_decode_program",
@@ -413,7 +414,11 @@ def paged_decode_program(model, *, max_running, num_blocks, block_size,
     through the pool, and sampled (greedy argmax by default). Inactive
     lanes must point at a trash block — their outputs are garbage the
     scheduler ignores. With ``donate=True`` the pool buffers are donated
-    (decode reuses them in place — no double pool allocation per step).
+    and decode reuses them in place: every layer stores its rows into
+    the whole ``(L, NB, bs, H*D')`` pool and the kernel reads blocks of
+    that same buffer, one row-major layout for all three, so the
+    compiled step holds no temporary and no copy shaped like a pool
+    (``tests/test_chip_compile.py`` asks the chip's compiler).
     """
     cache_dtype = _resolve_cache_dtype(model, kv_cache_dtype)
     r, mb = int(max_running), int(max_blocks_per_seq)
@@ -463,7 +468,9 @@ def paged_prefill_program(model, *, prefill_len, num_blocks, block_size,
     (first_token () i32, new_pool_k, new_pool_v)``. The prompt (padded
     to the ``Pb`` bucket) prefills a dense per-request cache allocated
     INSIDE the program, the cache is resliced into ``Pb // block_size``
-    blocks and spliced into the running pool at ``block_ids``, and the
+    blocks of pool rows (``(Lyr, nb, bs, H*D')``, the pool's own layout)
+    and spliced into the running pool at ``block_ids`` (in place when
+    the pools are donated: the same rule as decode), and the
     first generated token is sampled from the logits at ``last_idx``
     (the last REAL prompt position — pad garbage beyond it never
     matters: causal attention keeps it out of positions <= last_idx and
@@ -503,9 +510,10 @@ def paged_prefill_program(model, *, prefill_len, num_blocks, block_size,
         (logits, ck_f, cv_f), _ = step_fn(
             params, prompt, ck0, cv0, jnp.zeros((), jnp.int32))
 
-        def blocks(c):                  # (Lyr,1,H,Pb,D') -> (Lyr,nb,H,bs,D')
-            return c[:, 0].reshape(lyr, heads, nb, bs, dp) \
-                .transpose(0, 2, 1, 3, 4)
+        def blocks(c):          # (Lyr,1,H,Pb,D') -> (Lyr,nb,bs,H*D') rows
+            return kv_pool_rows(
+                c[:, 0].transpose(0, 2, 1, 3)).reshape(lyr, nb, bs,
+                                                       heads * dp)
 
         pool_k = pool_k.at[:, block_ids].set(blocks(ck_f))
         pool_v = pool_v.at[:, block_ids].set(blocks(cv_f))

@@ -38,7 +38,8 @@ from ...ops.nn import attend as _attend
 # block-pool decode path shares them); re-exported here unchanged for
 # the historical import path.
 from ...ops.nn import (_KV_SCALE_BYTES, kv_cache_dequantize,
-                       kv_cache_quantize, paged_attention as _paged_attend,
+                       kv_cache_quantize, kv_pool_rows,
+                       paged_attention as _paged_attend,
                        paged_attention_multi as _paged_attend_multi)
 
 
@@ -169,7 +170,8 @@ class MultiHeadAttention(HybridBlock):
                                     name="MultiHeadAttentionStep", n_out=3)
         return self.out_proj(out), new_ck, new_cv
 
-    def forward_step_paged(self, x, pool_k, pool_v, block_table, positions):
+    def forward_step_paged(self, x, pool_k, pool_v, block_table, positions,
+                           layer):
         """Paged-KV decode attention: ``x`` is (R, T, units) — lane
         ``r``'s token ``t`` sits at absolute position
         ``positions[r] + t`` — whose K/V are written into the shared
@@ -179,9 +181,18 @@ class MultiHeadAttention(HybridBlock):
         lanes with per-position lengths (the length mask IS the causal
         mask). ``T == 1`` is the continuous-batching decode step;
         ``T > 1`` serves speculative verify (K+1 draft tokens per lane
-        in ONE forward) and shared-prefix suffix prefill. Pools are
-        (NB, H, bs, D') for THIS layer; static shapes throughout, so one
-        XLA program serves every step at every mix of sequence lengths.
+        in ONE forward) and shared-prefix suffix prefill.
+
+        ``pool_k``/``pool_v`` are the WHOLE ``(L, NB, bs, H*D')`` pools
+        (the one layout, :func:`~mxnet_tpu.ops.nn.kv_pool_rows`) and
+        ``layer`` this layer's index in them: the rows are stored with
+        ``pool.at[layer, blk, slot].set(rows)`` and the pools returned,
+        so nothing is sliced out of a pool or stacked back, and with the
+        pools donated the write is in place. Rows of ``H*D`` lanes (a
+        multiple of 128 at GPT-2 widths) are what lets this scatter, the
+        kernel's block and the donated buffer share one row-major layout.
+        Static shapes throughout, so one XLA program serves every step
+        at every mix of sequence lengths.
 
         When the fused Pallas decode path is armed
         (:func:`~mxnet_tpu.ops.pallas.fused_decode.fused_decode_armed`),
@@ -193,7 +204,7 @@ class MultiHeadAttention(HybridBlock):
 
         if self._fused_eligible() and _fused.fused_decode_armed():
             return self._forward_step_paged_fused(
-                x, pool_k, pool_v, block_table, positions)
+                x, pool_k, pool_v, block_table, positions, layer)
         proj = self.qkv(x)
 
         def fn(p, pk, pv, bt, pos):
@@ -216,15 +227,15 @@ class MultiHeadAttention(HybridBlock):
             abs_pos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
             blk = jnp.take_along_axis(bt, abs_pos // bs, axis=1).reshape(-1)
             slot = (abs_pos % bs).reshape(-1)
-            # two advanced indices around a slice: the (R*T,) token axis
-            # broadcasts to the front -> (R*T, H, D') matches k_store
-            pk = pk.at[blk, :, slot, :].set(k_store)
-            pv = pv.at[blk, :, slot, :].set(v_store)
+            # one (R*T, H*D') row per written token, straight into the
+            # whole pool
+            pk = pk.at[layer, blk, slot].set(kv_pool_rows(k_store))
+            pv = pv.at[layer, blk, slot].set(kv_pool_rows(v_store))
             if t == 1:
                 # the ONE continuous-batching decode step (unchanged op
                 # stream: greedy token-identity with the dense cache)
                 out = _paged_attend(q, pk, pv, bt,
-                                    (abs_pos + 1).reshape(-1))
+                                    (abs_pos + 1).reshape(-1), layer)
                 return out.reshape(r, 1, units), pk, pv
             # T > 1 (speculative verify / suffix prefill): gather each
             # lane's blocks ONCE and attend all T queries against the
@@ -232,7 +243,7 @@ class MultiHeadAttention(HybridBlock):
             # which is the whole roofline win; the per-(lane, t) length
             # mask IS the causal mask
             out = _paged_attend_multi(q.reshape(r, t, heads, d),
-                                      pk, pv, bt, pos)     # (R, T, H, D)
+                                      pk, pv, bt, pos, layer)  # (R, T, H, D)
             return out.reshape(r, t, units), pk, pv
 
         out, new_pk, new_pv = _call(
@@ -248,7 +259,7 @@ class MultiHeadAttention(HybridBlock):
             self.out_proj, Dense)
 
     def _forward_step_paged_fused(self, x, pool_k, pool_v, block_table,
-                                  positions):
+                                  positions, layer):
         """Fused-kernel variant of :meth:`forward_step_paged`: one
         Pallas kernel per (QKV projection + int8 quantize), the
         scalar-prefetch paged-attend kernel, and one fused out-proj
@@ -267,7 +278,7 @@ class MultiHeadAttention(HybridBlock):
             bq = biases[0] if b_qkv is not None else None
             bo = biases[-1] if b_out is not None else None
             return fused_decode_step(
-                xv, wq, bq, wo, bo, pk, pv, bt, pos, heads=heads,
+                xv, wq, bq, wo, bo, pk, pv, bt, pos, layer, heads=heads,
                 units=units)
 
         args = [x, w_qkv, pool_k, pool_v, block_table, positions, w_out]
@@ -358,16 +369,17 @@ class TransformerEncoderLayer(HybridBlock):
         x = self.ln1(x + h)
         return self.ln2(x + self.ffn(x)), ck, cv
 
-    def forward_step_paged(self, x, pool_k, pool_v, block_table, positions):
+    def forward_step_paged(self, x, pool_k, pool_v, block_table, positions,
+                           layer):
         """Paged-pool variant of :meth:`forward_step` (no dropout:
-        decode is inference)."""
+        decode is inference): the whole pools and this layer's index."""
         if self._pre_norm:
             h, pk, pv = self.attn.forward_step_paged(
-                self.ln1(x), pool_k, pool_v, block_table, positions)
+                self.ln1(x), pool_k, pool_v, block_table, positions, layer)
             x = x + h
             return x + self.ffn(self.ln2(x)), pk, pv
         h, pk, pv = self.attn.forward_step_paged(
-            x, pool_k, pool_v, block_table, positions)
+            x, pool_k, pool_v, block_table, positions, layer)
         x = self.ln1(x + h)
         return self.ln2(x + self.ffn(x)), pk, pv
 
@@ -410,18 +422,18 @@ class TransformerEncoder(HybridBlock):
 
     def forward_step_paged(self, x, pool_k, pool_v, block_table, positions):
         """Paged-pool decode through the stack. ``pool_k``/``pool_v``
-        are (num_layers, NB, H, bs, D') stacked block pools sharing ONE
+        are the ``(num_layers, NB, bs, H*D')`` block pools sharing ONE
         block table (a block holds one layer's slice; the same block id
         addresses every layer's pool, so splice/free work per sequence,
-        not per layer)."""
-        from ... import numpy as mxnp
-
-        new_ks, new_vs = [], []
+        not per layer). Every layer is handed the whole pools and its
+        index, writes its rows in place and hands the pools on: no
+        ``pool[i]``, no ``stack`` — a slice of a pool is a copy of a pool
+        (70 MB a layer at GPT-2-large); slices, the re-stack and the
+        layout conversions around them were 72% of the decode step
+        (PERF.md, PR 27)."""
         for i in range(self._num_layers):
-            x, pk, pv = getattr(self, f"layer{i}").forward_step_paged(
-                x, pool_k[i], pool_v[i], block_table, positions)
-            new_ks.append(pk)
-            new_vs.append(pv)
+            x, pool_k, pool_v = getattr(self, f"layer{i}").forward_step_paged(
+                x, pool_k, pool_v, block_table, positions, i)
         if self.final_ln is not None:
             x = self.final_ln(x)
-        return x, mxnp.stack(new_ks), mxnp.stack(new_vs)
+        return x, pool_k, pool_v

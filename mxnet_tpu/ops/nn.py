@@ -976,7 +976,44 @@ def kv_cache_dequantize(c, dtype):
     return (w[..., :d].astype(jnp.float32) * scale).astype(dtype)
 
 
-def paged_attention(q, k_pool, v_pool, block_table, lengths,
+# --- the ONE KV-pool layout -------------------------------------------------
+# A pool is ``(L, NB, bs, H*D')``: layer, block, slot, then one ROW per
+# token with all heads side by side (head ``h`` in columns
+# ``[h*D', (h+1)*D')``; ``D' = D`` for float pools, ``D + 4`` for int8).
+# Why: three parties touch a pool inside the decode program — the XLA
+# scatter that stores the step's rows, the Pallas kernel that reads
+# blocks, the compiler's layout for the donated parameter and result. On
+# ``(L, NB, H, bs, D)`` each chose its own (a 64-wide minor dimension
+# wastes half of the 128 lanes) and every layer converted a whole pool
+# twice: 72% of the decode step (PERF.md, PR 27). Rows of ``H*D`` = 768
+# or 1,280 are whole multiples of 128 lanes: all three agree on plain
+# row-major and the donated pool is updated in place. These two functions
+# alone turn per-head tensors into rows and back; everyone else indexes
+# blocks (axis 1) and slots (axis 2).
+def kv_pool_rows(t):
+    """``(..., H, D')`` per-head tensors -> ``(..., H*D')`` pool rows."""
+    return t.reshape(*t.shape[:-2], t.shape[-2] * t.shape[-1])
+
+
+def kv_pool_heads(rows, heads):
+    """``(..., H*D')`` pool rows -> ``(..., H, D')`` per-head tensors."""
+    return rows.reshape(*rows.shape[:-1], heads, rows.shape[-1] // heads)
+
+
+def _gather_lanes(pool, layer, block_table, heads, dtype):
+    """Each lane's blocks of one layer as a dense view:
+    ``(L, NB, bs, H*D')`` -> ``(R, H, MB*bs, D)`` in ``dtype`` (int8 rows
+    dequantized after the gather)."""
+    r, mb = block_table.shape
+    rows = pool[layer, block_table]             # (R, MB, bs, H*D')
+    c = kv_pool_heads(rows.reshape(r, mb * rows.shape[2], -1), heads)
+    c = c.transpose(0, 2, 1, 3)                 # (R, H, MB*bs, D')
+    if pool.dtype == jnp.int8:                  # int8 rides HBM; math in q's
+        c = kv_cache_dequantize(c, dtype)
+    return c
+
+
+def paged_attention(q, k_pool, v_pool, block_table, lengths, layer=0,
                     use_kernel=None):
     """Single-token decode attention through a paged KV block pool.
 
@@ -989,15 +1026,21 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths,
     Parameters
     ----------
     q : (R, H, D) — one query token per decode lane.
-    k_pool, v_pool : (NB, H, bs, D') — the shared block pools for ONE
-        layer; ``D' = D`` for float pools, ``D + 4`` for int8 pools
-        (:func:`kv_cache_quantize` layout, dequantized per gather).
+    k_pool, v_pool : (L, NB, bs, H*D') — the WHOLE block pools of every
+        layer, in the one pool layout (:func:`kv_pool_rows`: a row per
+        token, heads side by side, so that a row is a whole multiple of
+        128 lanes at GPT-2 widths and the scatter, the kernel and the
+        donated buffer agree on row-major); ``D' = D`` for float pools,
+        ``D + 4`` for int8 pools (:func:`kv_cache_quantize` layout,
+        dequantized per gather). No per-layer slice is ever taken: a
+        slice of a pool is a copy of a pool.
     block_table : (R, MB) int32 — lane -> pool-block indices, logical
         position ``p`` lives in ``block_table[r, p // bs]`` slot
         ``p % bs``. Entries past a lane's context may point anywhere
         live (a trash block): they are masked by ``lengths``.
     lengths : (R,) int32 — valid positions per lane (current token
         included, written by the caller before attending).
+    layer : int or () int32 — which layer's blocks to read.
     use_kernel : None | bool — None auto-selects the Pallas TPU kernel
         on the TPU backend for float AND int8 pools (int8 — the engine
         default — dequantizes the bitcast-scale layout inside the
@@ -1008,28 +1051,18 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths,
     Returns (R, H, D) in the pool's value dtype (float pools) or ``q``'s
     dtype (int8 pools).
     """
-    r, h, d = q.shape
-    nb, _, bs, _ = k_pool.shape
+    _, h, d = q.shape
+    bs = k_pool.shape[2]
     mb = block_table.shape[1]
-    quantized = k_pool.dtype == jnp.int8
     if use_kernel is None:
         use_kernel = _tpu_kernels_selected()
     if use_kernel:
         from .pallas.paged_attention import paged_attention_kernel
 
         return paged_attention_kernel(q, k_pool, v_pool, block_table,
-                                      lengths)
-    keys = k_pool[block_table]          # (R, MB, H, bs, D')
-    vals = v_pool[block_table]
-
-    def flat(c):                        # -> (R, H, MB*bs, D')
-        return c.transpose(0, 2, 1, 3, 4).reshape(r, h, mb * bs,
-                                                  c.shape[-1])
-
-    keys, vals = flat(keys), flat(vals)
-    if quantized:                       # int8 rides HBM; math in q's dtype
-        keys = kv_cache_dequantize(keys, q.dtype)
-        vals = kv_cache_dequantize(vals, q.dtype)
+                                      lengths, layer)
+    keys = _gather_lanes(k_pool, layer, block_table, h, q.dtype)
+    vals = _gather_lanes(v_pool, layer, block_table, h, q.dtype)
     # the dense MultiHeadAttention.forward_step arithmetic with T=1 and
     # the causal row-mask replaced by the per-lane length mask — kept
     # operation-for-operation identical so paged greedy decode emits the
@@ -1044,9 +1077,11 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths,
 
 
 def paged_attention_multi(q, k_pool, v_pool, block_table, positions,
-                          use_kernel=None):
+                          layer=0, use_kernel=None):
     """Multi-token paged decode attention: ``q`` is (R, T, H, D), lane
-    ``r``'s query ``t`` at absolute position ``positions[r] + t``.
+    ``r``'s query ``t`` at absolute position ``positions[r] + t``; pools
+    and ``layer`` as in :func:`paged_attention` (the whole
+    ``(L, NB, bs, H*D')`` pools, never a layer's slice).
 
     The speculative-verify / suffix-prefill hot path. The point over
     calling :func:`paged_attention` on R*T virtual lanes is the READ
@@ -1066,11 +1101,10 @@ def paged_attention_multi(q, k_pool, v_pool, block_table, positions,
     ``q``'s dtype (int8 pools).
     """
     r, t, h, d = q.shape
-    nb, _, bs, _ = k_pool.shape
+    bs = k_pool.shape[2]
     mb = block_table.shape[1]
     pos = positions.astype(jnp.int32)
     abs_pos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
-    quantized = k_pool.dtype == jnp.int8
     if use_kernel is None:
         use_kernel = _tpu_kernels_selected()
     if use_kernel:
@@ -1079,19 +1113,10 @@ def paged_attention_multi(q, k_pool, v_pool, block_table, positions,
         out = paged_attention_kernel(
             q.reshape(r * t, h, d), k_pool, v_pool,
             jnp.repeat(block_table, t, axis=0),
-            (abs_pos + 1).reshape(-1))
+            (abs_pos + 1).reshape(-1), layer)
         return out.reshape(r, t, h, d)
-    keys = k_pool[block_table]          # (R, MB, H, bs, D') — ONCE
-    vals = v_pool[block_table]
-
-    def flat(c):                        # -> (R, H, MB*bs, D')
-        return c.transpose(0, 2, 1, 3, 4).reshape(r, h, mb * bs,
-                                                  c.shape[-1])
-
-    keys, vals = flat(keys), flat(vals)
-    if quantized:
-        keys = kv_cache_dequantize(keys, q.dtype)
-        vals = kv_cache_dequantize(vals, q.dtype)
+    keys = _gather_lanes(k_pool, layer, block_table, h, q.dtype)   # ONCE
+    vals = _gather_lanes(v_pool, layer, block_table, h, q.dtype)
     scores = jnp.einsum("rthd,rhld->rthl", q, keys).astype(jnp.float32)
     scores = scores / onp.sqrt(d).astype(onp.float32)
     live = jnp.arange(mb * bs)[None, None, :] < (abs_pos + 1)[:, :, None]

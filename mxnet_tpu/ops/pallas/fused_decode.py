@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ...base import env_str
-from ..nn import _KV_SCALE_BYTES
+from ..nn import _KV_SCALE_BYTES, kv_pool_rows
 
 __all__ = ["fused_decode_armed", "fused_decode_step",
            "fused_qkv_project", "fused_out_project"]
@@ -104,9 +104,11 @@ def fused_qkv_project(x, w_qkv, b_qkv, *, heads, store_dtype,
     ``x``: (N, U) decode activations; ``w_qkv``: (3U, U) Dense weight
     (out, in); ``b_qkv``: (3U,) or None. Returns ``(q, k_store,
     v_store)``: q (N, H, D) in ``x``'s dtype; k/v (N, H, D') already in
-    the pool layout — int8 + bitcast scale when ``store_dtype`` is
-    int8, a plain cast otherwise. Grid: one program per head, over
-    head-major operands — weights ``(H, U, D)``, biases ``(H, 1, D)``,
+    the pool's row encoding — int8 + bitcast scale when ``store_dtype``
+    is int8, a plain cast otherwise
+    (:func:`~mxnet_tpu.ops.nn.kv_pool_rows` makes pool rows of them).
+    Grid: one program per head, over head-major operands — weights
+    ``(H, U, D)``, biases ``(H, 1, D)``,
     outputs ``(H, N, D')`` — so that every block's last two dims are
     the array's own, which is what the TPU lowering accepts for 64-wide
     heads; the outputs are transposed to ``(N, H, D')`` outside."""
@@ -189,14 +191,16 @@ def fused_out_project(attn, w_out, b_out, *, interpret=None):
 
 
 def fused_decode_step(x, w_qkv, b_qkv, w_out, b_out, pool_k, pool_v,
-                      block_table, positions, *, heads, units,
+                      block_table, positions, layer, *, heads, units,
                       interpret=None):
     """One attention sublayer's paged decode step through the fused
     kernels: QKV+quantize kernel -> in-place pool scatter (donated
     buffers) -> scalar-prefetch paged-attend kernel -> out-proj kernel.
 
     ``x``: (R, T, U) at per-lane absolute positions ``positions[r]+t``;
-    pools (NB, H, bs, D'); ``block_table`` (R, MB). Returns
+    the whole pools ``(L, NB, bs, H*D')`` and this layer's index, as in
+    ``MultiHeadAttention.forward_step_paged``; ``block_table`` (R, MB).
+    Returns
     ``(out (R, T, U), new_pool_k, new_pool_v)`` — arithmetic matches
     the unfused jnp path (the interpret-mode oracle)."""
     r, t, u = x.shape
@@ -212,13 +216,13 @@ def fused_decode_step(x, w_qkv, b_qkv, w_out, b_out, pool_k, pool_v,
     abs_pos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
     blk = jnp.take_along_axis(bt, abs_pos // bs, axis=1).reshape(-1)
     slot = (abs_pos % bs).reshape(-1)
-    pool_k = pool_k.at[blk, :, slot, :].set(ks)
-    pool_v = pool_v.at[blk, :, slot, :].set(vs)
+    pool_k = pool_k.at[layer, blk, slot].set(kv_pool_rows(ks))
+    pool_v = pool_v.at[layer, blk, slot].set(kv_pool_rows(vs))
     from .paged_attention import paged_attention_kernel
 
     out = paged_attention_kernel(
         q, pool_k, pool_v, jnp.repeat(bt, t, axis=0),
-        (abs_pos + 1).reshape(-1), interpret=interpret)   # (N, H, D)
+        (abs_pos + 1).reshape(-1), layer, interpret=interpret)  # (N, H, D)
     o = fused_out_project(out.reshape(n, u).astype(x.dtype), w_out,
                           b_out, interpret=interpret)
     return o.reshape(r, t, u), pool_k, pool_v

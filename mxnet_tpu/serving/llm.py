@@ -381,8 +381,8 @@ class LLMEngine:
         ``rules`` (default the
         :data:`~mxnet_tpu.parallel.sharding.TRANSFORMER_RULES`
         megatron tp column/row catalog), the KV block pools become
-        global arrays sharded on the head axis
-        (``P(None, None, "tp")`` — heads must divide the ``tp`` axis),
+        global arrays sharded on the heads of every row
+        (``P(None, None, None, "tp")`` — ``tp`` must divide the heads),
         and every paged program runs as a global-array program over the
         mesh by input-sharding propagation. Token-identical to the
         unsharded engine; donation and the ``_decode_cache``/AOT
@@ -691,12 +691,16 @@ class LLMEngine:
 
     def _shard_pool(self, arr):
         """Commit one KV block pool to the mesh as a global array,
-        sharded on the HEAD axis (pool layout ``(L, NB+1, H, bs, D)`` —
-        heads are embarrassingly parallel under paged attention, while
-        D carries the int8 bitcast-scale tail and must stay whole, and
-        the block axis must stay whole so block ids keep addressing the
-        global pool). On a mesh without a ``tp`` axis the spec
-        collapses to replication (the ``named_sharding`` contract)."""
+        sharded on its LAST axis (the one pool layout,
+        ``(L, NB+1, bs, H*D')``: a row holds the heads side by side, so
+        ``tp`` equal parts of a row are contiguous groups of whole
+        heads — heads are embarrassingly parallel under paged
+        attention, each head's ``D'`` values with their int8
+        bitcast-scale tail stay together as long as ``tp`` divides the
+        heads, and the block axis stays whole so block ids keep
+        addressing the global pool). On a mesh without a ``tp`` axis
+        the spec collapses to replication (the ``named_sharding``
+        contract)."""
         if self._mesh is None:
             return arr
         from jax.sharding import PartitionSpec as P
@@ -704,7 +708,7 @@ class LLMEngine:
         from ..parallel.mesh import named_sharding
 
         return jax.device_put(
-            arr, named_sharding(P(None, None, "tp"), self._mesh))
+            arr, named_sharding(P(None, None, None, "tp"), self._mesh))
 
     def _shard_params(self, params):
         """Partition the flat param dict by the rule catalog
@@ -723,8 +727,8 @@ class LLMEngine:
     def _pool_bytes_per_device(self) -> int:
         """Bytes of KV pool resident PER DEVICE — the number that
         decides whether a model fits a chip. Sharded pools divide the
-        head axis across the mesh, so this is the largest-servable
-        -model lever: per-device share = total / tp."""
+        heads of every row across the mesh, so this is the
+        largest-servable-model lever: per-device share = total / tp."""
         pools = [self._pool_k, self._pool_v]
         if self._spec:
             pools += [self._dpool_k, self._dpool_v]

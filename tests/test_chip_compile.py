@@ -65,27 +65,33 @@ def on_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-def _compile(fn, args, one_chip):
+def _compile(fn, args, one_chip, donate=()):
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         args)
-    return jax.jit(fn).lower(*args).compile()
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
 
 
 def _s(shape, dtype):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
 
 
+def _pool(dtype):
+    """One KV pool of the 2-layer ``lm`` in the one pool layout,
+    ``(L, NB, bs, H*D')``; ``None`` is the model's own dtype."""
+    dp = D + 4 if dtype == "int8" else D
+    return _s((2, NB, BS, H * dp), dtype or "bfloat16")
+
+
 # --- one case per kernel of the main path ----------------------------------
 def _paged(pool_dtype):
     from mxnet_tpu.ops.pallas.paged_attention import paged_attention_kernel
 
-    dp = D + 4 if pool_dtype == "int8" else D
-    pool = _s((NB, H, BS, dp), pool_dtype)
-    return (lambda q, k, v, bt, ln: paged_attention_kernel(
-                q, k, v, bt, ln, interpret=False),
+    pool = _pool(pool_dtype)
+    return (lambda q, k, v, bt, ln, layer: paged_attention_kernel(
+                q, k, v, bt, ln, layer, interpret=False),
             (_s((R, H, D), "bfloat16"), pool, pool,
-             _s((R, MB), "int32"), _s((R,), "int32")))
+             _s((R, MB), "int32"), _s((R,), "int32"), _s((), "int32")))
 
 
 def _fused_qkv(store_dtype):
@@ -204,21 +210,76 @@ def test_train_step_program_compiles_for_v5e(lm, one_chip,
     assert compiled.as_text().count("tpu_custom_call") >= 2 * 5 + 2
 
 
+def _pool_report(compiled, pool, label):
+    """What the chip's compiler says of a program that is handed both
+    pools donated: its temporaries and the two pools, in bytes, and the
+    ``copy`` operations whose result is shaped like a pool."""
+    pool_bytes = 2 * int(onp.prod(pool.shape)) * pool.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    dims = ",".join(map(str, pool.shape))
+    short = {"bfloat16": "bf16", "int8": "s8"}[pool.dtype.name]
+    copies = [line.strip()[:160] for line in compiled.as_text().splitlines()
+              if f" = {short}[{dims}]" in line and " copy(" in line]
+    print(f"{label}: pools {pool_bytes / 2**20:.1f} MiB, temporaries "
+          f"{temp / 2**20:.1f} MiB, pool-shaped copies {len(copies)}")
+    return temp, pool_bytes, copies
+
+
 @pytest.mark.parametrize("kv_cache_dtype", ["int8", None])
 def test_decode_step_program_compiles_for_v5e(lm, kv_cache_dtype, one_chip,
                                               no_compile_cache, on_tpu):
     """The engine's one decode program at its default geometry (32 lanes,
-    2,048 blocks of 16, context 1024), paged kernel inside."""
+    2,048 blocks of 16, context 1024), paged kernel inside, **with the
+    pools donated** as the engine hands them over off the CPU. For float
+    pools this is the guard of the one pool layout (PR 27): rows of
+    ``H*D`` lanes let the row scatter, the kernel's block and the donated
+    buffer share row-major, so the program needs no temporary and no
+    copy shaped like a pool (on ``(L, NB, H, bs, D)`` it needed 1.7 x
+    the pools and copied one twice a layer). int8 rows (816 bytes) are no
+    multiple of 128 lanes: the case keeps compiling and prints its
+    numbers, without a limit."""
     from mxnet_tpu.gluon.model_zoo.generation import paged_decode_program
 
     run, params = paged_decode_program(
         lm, max_running=R, num_blocks=NB, block_size=BS,
-        max_blocks_per_seq=MB, kv_cache_dtype=kv_cache_dtype)
-    pool = (_s((2, NB, H, BS, D + 4), "int8") if kv_cache_dtype == "int8"
-            else _s((2, NB, H, BS, D), "bfloat16"))
+        max_blocks_per_seq=MB, kv_cache_dtype=kv_cache_dtype, donate=True)
+    pool = _pool(kv_cache_dtype)
     compiled = _compile(
         run._fn,
         (params, _s((R, 1), "int32"), pool, pool, _s((R, MB), "int32"),
-         _s((R,), "int32"), _s((2,), "uint32")), one_chip)
+         _s((R,), "int32"), _s((2,), "uint32")), one_chip, donate=(2, 3))
     # per layer: two norms and the paged kernel, whatever the pool dtype
     assert compiled.as_text().count("tpu_custom_call") >= 2 * 3
+    temp, pools, copies = _pool_report(
+        compiled, pool, f"decode, {kv_cache_dtype or 'bf16'} pools")
+    if kv_cache_dtype is None:
+        assert temp < 0.05 * pools, (temp, pools)
+        assert not copies, copies
+
+
+@pytest.mark.parametrize("kv_cache_dtype", ["int8", None])
+def test_prefill_program_compiles_for_v5e(lm, kv_cache_dtype, one_chip,
+                                          no_compile_cache, on_tpu):
+    """One prefill-and-splice bucket (512 tokens) under the same rule: a
+    donated float pool takes the prompt's blocks in place. The program
+    computes the float32 logits of the whole bucket (``Pb x V`` x 4
+    bytes, 98 MiB here, of which one row is sampled: they do not grow
+    with the pools); what it holds beside them stays under 5% of the
+    pools."""
+    from mxnet_tpu.gluon.model_zoo.generation import paged_prefill_program
+
+    pb = 512
+    run, params = paged_prefill_program(
+        lm, prefill_len=pb, num_blocks=NB, block_size=BS,
+        kv_cache_dtype=kv_cache_dtype, donate=True)
+    pool = _pool(kv_cache_dtype)
+    compiled = _compile(
+        run._fn,
+        (params, _s((1, pb), "int32"), _s((), "int32"), pool, pool,
+         _s((pb // BS,), "int32"), _s((2,), "uint32")), one_chip,
+        donate=(3, 4))
+    temp, pools, copies = _pool_report(
+        compiled, pool, f"prefill {pb}, {kv_cache_dtype or 'bf16'} pools")
+    if kv_cache_dtype is None:
+        assert temp - pb * V * 4 < 0.05 * pools, (temp, pools)
+        assert not copies, copies

@@ -57,78 +57,96 @@ def _tiny_draft(seed=99, vocab=37, units=16, heads=4, max_length=64):
 # ---------------------------------------------------------------------------
 # op level
 # ---------------------------------------------------------------------------
-def test_paged_attention_matches_manual():
-    """The jnp gather path against a dense numpy oracle."""
+def _pools(rng, layers, nb, bs, h, d, dtype):
+    """Random K and V pools in the one pool layout,
+    ``(L, NB, bs, H*D')``, and the per-head float values they hold."""
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops.nn import paged_attention
+    from mxnet_tpu.ops.nn import (kv_cache_dequantize, kv_cache_quantize,
+                                  kv_pool_rows)
+
+    out = []
+    for _ in range(2):
+        t = jnp.asarray(rng.randn(layers, nb, bs, h, d), jnp.float32)
+        if dtype == "int8":
+            c = kv_cache_quantize(t)
+            assert c.dtype == jnp.int8 and c.shape[-1] == d + 4
+            vals = kv_cache_dequantize(c, jnp.float32)
+        else:
+            c = t.astype(dtype)
+            vals = c.astype(jnp.float32)
+        pool = kv_pool_rows(c)
+        assert pool.shape == (layers, nb, bs, h * c.shape[-1])
+        out += [pool, onp.asarray(vals)]
+    return out
+
+
+def test_paged_attention_matches_manual():
+    """The jnp gather path against a dense numpy oracle, on a layer
+    other than 0 of the ``(L, NB, bs, H*D)`` pools."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.nn import kv_pool_heads, paged_attention
 
     rng = onp.random.RandomState(0)
-    r, h, d, bs, nb, mb = 3, 2, 8, 4, 7, 3
+    r, h, d, bs, nb, mb, layer = 3, 2, 8, 4, 7, 3, 1
     q = rng.randn(r, h, d).astype(onp.float32)
-    kp = rng.randn(nb, h, bs, d).astype(onp.float32)
-    vp = rng.randn(nb, h, bs, d).astype(onp.float32)
+    kp, kv, vp, vv = _pools(rng, 2, nb, bs, h, d, "float32")
+    onp.testing.assert_array_equal(                # rows <-> heads round trip
+        onp.asarray(kv_pool_heads(kp, h)), kv)
     bt = rng.randint(0, nb, (r, mb)).astype(onp.int32)
     lens = onp.array([3, 7, 12], onp.int32)
     out = onp.asarray(paged_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(bt), jnp.asarray(lens), use_kernel=False))
+        jnp.asarray(q), kp, vp, jnp.asarray(bt), jnp.asarray(lens),
+        layer=layer, use_kernel=False))
     for i in range(r):
-        keys = kp[bt[i]].transpose(1, 0, 2, 3).reshape(h, mb * bs, d)
-        vals = vp[bt[i]].transpose(1, 0, 2, 3).reshape(h, mb * bs, d)
+        keys = kv[layer][bt[i]].reshape(mb * bs, h, d)
+        vals = vv[layer][bt[i]].reshape(mb * bs, h, d)
         for hh in range(h):
-            s = keys[hh, :lens[i]] @ q[i, hh] / onp.sqrt(d)
+            s = keys[:lens[i], hh] @ q[i, hh] / onp.sqrt(d)
             p = onp.exp(s - s.max())
             p /= p.sum()
-            want = p @ vals[hh, :lens[i]]
+            want = p @ vals[:lens[i], hh]
             onp.testing.assert_allclose(out[i, hh], want, rtol=2e-5,
                                         atol=2e-5)
 
 
-def test_paged_kernel_matches_jnp_int8():
-    """ISSUE 11 satellite: the kernel arms for int8 pools (the engine
-    DEFAULT) — the bitcast-scale layout dequantizes inside the kernel
-    and must match the jnp dequant-gather oracle."""
-    import jax.numpy as jnp
-
-    from mxnet_tpu.ops.nn import kv_cache_quantize, paged_attention
-    from mxnet_tpu.ops.pallas.paged_attention import paged_attention_kernel
-
-    rng = onp.random.RandomState(2)
-    r, h, d, bs, nb, mb = 3, 4, 16, 8, 10, 4
-    q = jnp.asarray(rng.randn(r, h, d), jnp.float32)
-    kp = kv_cache_quantize(jnp.asarray(rng.randn(nb, h, bs, d),
-                                       jnp.float32))
-    vp = kv_cache_quantize(jnp.asarray(rng.randn(nb, h, bs, d),
-                                       jnp.float32))
-    assert kp.dtype == jnp.int8 and kp.shape[-1] == d + 4
-    bt = jnp.asarray(rng.randint(0, nb, (r, mb)).astype(onp.int32))
-    lens = jnp.asarray(onp.array([5, 17, 32], onp.int32))
-    ref = paged_attention(q, kp, vp, bt, lens, use_kernel=False)
-    got = paged_attention_kernel(q, kp, vp, bt, lens, interpret=True)
-    assert got.dtype == q.dtype
-    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(ref),
-                                rtol=2e-5, atol=2e-5)
-
-
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_paged_kernel_matches_jnp(dtype):
+@pytest.mark.parametrize("t", [1, 3])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "bfloat16-f32q",
+                                   "int8"])
+@pytest.mark.parametrize("heads,d", [(12, 64), (20, 64), (4, 16)])
+def test_paged_kernel_matches_jnp(heads, d, dtype, t):
     """The Pallas kernel (interpret mode on CPU — the compiled Mosaic
-    path on TPU) against the jnp gather oracle."""
+    path on TPU) against the jnp gather oracle on the one pool layout:
+    GPT-2's heads x head size (rows of 768 and 1,280 lanes) and a toy,
+    float and int8 pools (the engine DEFAULT: the bitcast-scale rows
+    dequantize inside the kernel), bf16 pools under a float32 query (a
+    bf16 model's norms hand float32 on: what the chip serves), T = 1
+    (decode) and T > 1 (suffix prefill, speculative verify: the same
+    kernel on R*T virtual lanes), a layer other than 0, lengths that
+    end inside a block."""
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops.nn import paged_attention
-    from mxnet_tpu.ops.pallas.paged_attention import paged_attention_kernel
+    from mxnet_tpu.ops.nn import paged_attention, paged_attention_multi
 
-    rng = onp.random.RandomState(1)
-    r, h, d, bs, nb, mb = 3, 4, 16, 8, 10, 4
-    q = jnp.asarray(rng.randn(r, h, d), dtype)
-    kp = jnp.asarray(rng.randn(nb, h, bs, d), dtype)
-    vp = jnp.asarray(rng.randn(nb, h, bs, d), dtype)
+    rng = onp.random.RandomState(1 + heads + t)
+    r, bs, nb, mb, layer = 3, 8, 10, 4, 2
+    dtype, _, f32q = dtype.partition("-")
+    qdt = "float32" if dtype == "int8" or f32q else dtype
+    kp, _, vp, _ = _pools(rng, 3, nb, bs, heads, d, dtype)
     bt = jnp.asarray(rng.randint(0, nb, (r, mb)).astype(onp.int32))
-    lens = jnp.asarray(onp.array([5, 17, 32], onp.int32))
-    ref = paged_attention(q, kp, vp, bt, lens, use_kernel=False)
-    got = paged_attention_kernel(q, kp, vp, bt, lens, interpret=True)
+    if t == 1:
+        q = jnp.asarray(rng.randn(r, heads, d), qdt)
+        lens = jnp.asarray(onp.array([5, 17, 32], onp.int32))
+        ref, got = (paged_attention(q, kp, vp, bt, lens, layer=layer,
+                                    use_kernel=uk) for uk in (False, True))
+    else:
+        q = jnp.asarray(rng.randn(r, t, heads, d), qdt)
+        pos = jnp.asarray(onp.array([2, 14, 32 - t], onp.int32))
+        ref, got = (paged_attention_multi(q, kp, vp, bt, pos, layer=layer,
+                                          use_kernel=uk)
+                    for uk in (False, True))
+    assert got.shape == q.shape and got.dtype == ref.dtype
     tol = 3e-2 if dtype == "bfloat16" else 2e-5
     onp.testing.assert_allclose(onp.asarray(got, dtype=onp.float32),
                                 onp.asarray(ref, dtype=onp.float32),
@@ -786,7 +804,7 @@ def test_fused_decode_int8_pool_close_to_unfused(monkeypatch):
     toks = mxnp.array(onp.array([[7], [11]], onp.int32))
     bt = mxnp.array(onp.array([[0, 1, 8, 8], [2, 3, 8, 8]], onp.int32))
     pos = mxnp.array(onp.array([2, 5], onp.int32))
-    from mxnet_tpu.ops.nn import kv_cache_dequantize
+    from mxnet_tpu.ops.nn import kv_cache_dequantize, kv_pool_heads
 
     monkeypatch.setenv("MXNET_TPU_LLM_FUSED_DECODE", "0")
     ref_lg, ref_pk, _ = net.decode_step_paged(toks, pk, pv, bt, pos)
@@ -799,9 +817,9 @@ def test_fused_decode_int8_pool_close_to_unfused(monkeypatch):
     # guaranteed — the fused projection's fp association can flip
     # near-tie roundings)
     ref_vals = onp.asarray(kv_cache_dequantize(
-        jnp.asarray(ref_pk.asnumpy()), jnp.float32))
+        kv_pool_heads(jnp.asarray(ref_pk.asnumpy()), 4), jnp.float32))
     got_vals = onp.asarray(kv_cache_dequantize(
-        jnp.asarray(got_pk.asnumpy()), jnp.float32))
+        kv_pool_heads(jnp.asarray(got_pk.asnumpy()), 4), jnp.float32))
     onp.testing.assert_allclose(got_vals, ref_vals, rtol=0.1, atol=0.05)
 
 
