@@ -180,6 +180,17 @@ class _CausalLM(HybridBlock):
         w = self.word_embed.weight.data()
         return seq @ w.T, pk, pv
 
+    def cache_geometry(self, block_size):
+        """Blocks of ``block_size`` K/V rows: a request of ``n`` tokens
+        holds ``ceil(n / block_size)`` of them, and the position table
+        bounds the context (what ``serving.LLMEngine``'s cache manager
+        asks, :class:`~.generation.CacheGeometry`)."""
+        from .generation import CacheGeometry
+
+        return CacheGeometry(
+            "kv_blocks", lambda tokens: -(-tokens // block_size),
+            int(self.pos_embed.shape[0]))
+
     def init_block_pool(self, num_blocks, block_size, dtype="float32"):
         """Zeroed ``(L, NB, block_size, H*D')`` paged K/V block pools.
 

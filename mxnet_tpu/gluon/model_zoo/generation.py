@@ -10,9 +10,10 @@ happen on device inside the scan.
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 import weakref
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,34 @@ from ..block import HybridBlock
 
 __all__ = ["generate", "beam_search", "paged_decode_program",
            "paged_prefill_program", "paged_suffix_prefill_program",
-           "paged_spec_draft_program", "paged_spec_verify_program"]
+           "paged_spec_draft_program", "paged_spec_verify_program",
+           "CacheGeometry", "state_prefill_program"]
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheGeometry:
+    """What a model's ``cache_geometry(block_size)`` tells the serving
+    engine's cache manager about the pools of ``init_block_pool``: the
+    engine allocates, the model says what there is to allocate.
+
+    ``kind``: ``"kv_blocks"`` — blocks of ``block_size`` K/V rows, a
+    request holds as many as its tokens fill — or ``"state_slots"`` — a
+    block is one request's whole recurrent state. ``blocks_for(tokens)``:
+    the pool blocks a request of that many tokens reserves (which is also
+    the width of a lane's block table, at ``max_context``).
+    ``max_positions``: the model's context window, or None.
+    ``prefill_chunk``: None for whole-prompt prefill in length buckets
+    (``paged_prefill_program``), else the one chunk size of
+    ``state_prefill_program``. ``cache_dtypes``: the pool dtypes the model
+    can hold (None: those of ``_resolve_cache_dtype``); the first is its
+    default. ``unsupported``: engine feature -> why this cache cannot
+    carry it yet."""
+    kind: str
+    blocks_for: Callable[[int], int]
+    max_positions: Optional[int] = None
+    prefill_chunk: Optional[int] = None
+    cache_dtypes: Optional[tuple] = None
+    unsupported: dict = dataclasses.field(default_factory=dict)
 
 
 class _StepAdapter(HybridBlock):
@@ -522,6 +550,62 @@ def paged_prefill_program(model, *, prefill_len, num_blocks, block_size,
         return first, pool_k, pool_v
 
     jrun = _paged_jit(run, "llm.prefill", (3, 4) if donate else (), store)
+    return jrun, params
+
+
+class _ChunkStepAdapter(HybridBlock):
+    """Same, for model.prefill_chunk_step (one chunk of one lane)."""
+
+    def __init__(self, model):
+        super().__init__()
+        self.model = model
+
+    def forward(self, tokens, pool_s, pool_z, slot, start, n_real):
+        return self.model.prefill_chunk_step(tokens, pool_s, pool_z, slot,
+                                             start, n_real)
+
+
+def state_prefill_program(model, *, chunk, num_blocks, greedy=True,
+                          temperature=1.0, top_k=0, donate=False):
+    """Build (or fetch memoized) the ONE prefill program of a model whose
+    cache is a state (``CacheGeometry.kind == "state_slots"``): a chunk
+    of ``chunk`` tokens of one lane that carries the lane's state, so a
+    prompt of any length is a loop over it — no length buckets, one
+    warm-up shape.
+
+    Returns ``(run, params)``: ``run(params, tokens (1, chunk) i32, start
+    () i32, n_real () i32, pool_s, pool_z, slot () i32, key) ->
+    (next_token () i32, new_pool_s, new_pool_z)``. The chunk's tokens sit
+    at positions ``start + arange(chunk)``; the first ``n_real`` are
+    tokens, the rest padding that neither decays nor adds to the state.
+    The slot counts as zero where ``start == 0`` — a slot handed to a new
+    request needs no clearing. ``next_token`` is sampled from the logits
+    of the last real row alone (it is the request's first token after its
+    last chunk, and means nothing before)."""
+    from ... import numpy as mxnp
+
+    cc = int(chunk)
+    ps, pz = model.init_block_pool(min(int(num_blocks), 2), 0)
+    tokens0 = mxnp.array(onp.zeros((1, cc), onp.int32))
+    zero = mxnp.array(onp.zeros((), onp.int32))
+    adapter = _ChunkStepAdapter(model)
+    step_fn, params = adapter.functionalize(tokens0, ps, pz, zero, zero,
+                                            zero)
+    tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
+    ckey = ("state_prefill", cc, int(num_blocks), bool(greedy), *tkey,
+            bool(donate))
+    store, cached = _decode_cache(model, ckey)
+    if cached is not None:
+        return cached, params
+
+    def run(params, tokens, start, n_real, pool_s, pool_z, slot, key):
+        (logits, pool_s, pool_z), _ = step_fn(
+            params, tokens, pool_s, pool_z, slot, start, n_real)
+        nxt = _sample(logits, key, greedy, temperature, top_k)[0]
+        return nxt, pool_s, pool_z
+
+    jrun = _paged_jit(run, "llm.prefill_chunk", (4, 5) if donate else (),
+                      store)
     return jrun, params
 
 

@@ -115,7 +115,7 @@ class LLMMetrics:
 
     _EVENTS = ("submitted", "admitted", "completed", "failed",
                "shed_overload", "shed_deadline", "retired_deadline",
-               "cancelled", "prefills",
+               "cancelled", "prefills", "prefill_chunks",
                "decode_steps", "spec_steps", "resets", "compiles")
 
     def __init__(self, engine_id: str):
@@ -291,10 +291,14 @@ class LLMEngine:
     Parameters
     ----------
     model : causal LM with the paged decode contract
-        ``decode_step_paged`` / ``init_block_pool`` (+ the dense
-        ``decode_step`` / ``init_cache`` used by prefill) —
-        :class:`~mxnet_tpu.gluon.model_zoo.bert._CausalLM` provides all
-        four.
+        ``decode_step_paged`` / ``init_block_pool`` / ``cache_geometry``
+        (+ the dense ``decode_step`` / ``init_cache`` used by whole-prompt
+        prefill, or ``prefill_chunk_step`` where the geometry names a
+        chunk) — :class:`~mxnet_tpu.gluon.model_zoo.bert._CausalLM`
+        (blocks of K/V rows) and
+        :class:`~mxnet_tpu.gluon.model_zoo.brumby._RetentionLM` (one
+        state per request) provide them; ``docs/llm_serving.md``, "Two
+        cache geometries", says which features each carries.
     max_running : int
         Decode lanes (the fixed batch axis of the ONE decode program).
         Default ``MXNET_TPU_LLM_MAX_RUNNING`` (8).
@@ -479,24 +483,36 @@ class LLMEngine:
             raise ValueError("max_running and block_size must be >= 1")
         self.max_running = int(max_running)
         self.block_size = int(block_size)
-        model_ctx = None
-        pos_table = getattr(model, "pos_embed", None)
-        if pos_table is not None:
-            model_ctx = int(pos_table.shape[0])
+        # the model states the cache's geometry, the engine allocates:
+        # what a pool block is (block_size K/V rows, or one request's
+        # whole state), what a request of n tokens reserves, and which
+        # features the cache cannot carry (generation.CacheGeometry). This
+        # is the one place it is asked for; everything below is one path.
+        geom = self._geom = model.cache_geometry(self.block_size)
+        model_ctx = geom.max_positions
         if max_context is None:
             max_context = min(model_ctx or 2048, 2048)
         if model_ctx is not None and max_context > model_ctx:
             raise MXNetError(
                 f"max_context {max_context} exceeds the model's context "
-                f"window (pos_embed rows = {model_ctx})")
+                f"window ({model_ctx} positions)")
         self.max_context = int(max_context)
-        self.max_blocks_per_seq = -(-self.max_context // self.block_size)
+        # the width of a lane's block table: 1 where a block is a state,
+        # so there max_context bounds positions and nothing else
+        self.max_blocks_per_seq = geom.blocks_for(self.max_context)
         if num_blocks is None:
             num_blocks = int(env_float("MXNET_TPU_LLM_POOL_BLOCKS", 0)) \
                 or self.max_running * self.max_blocks_per_seq
         if num_blocks < 1:
             raise ValueError("num_blocks must be >= 1")
         self.num_blocks = int(num_blocks)
+        if geom.cache_dtypes is not None:
+            if kv_cache_dtype not in (None, *geom.cache_dtypes):
+                raise ValueError(
+                    f"kv_cache_dtype {kv_cache_dtype!r} is not supported "
+                    f"with a {geom.kind} cache: it is held as "
+                    f"{'/'.join(geom.cache_dtypes)} (pass that, or None)")
+            kv_cache_dtype = kv_cache_dtype or geom.cache_dtypes[0]
         self._kv_dtype = _resolve_cache_dtype(model, kv_cache_dtype)
         self._weight_dtype = weight_dtype
         self._greedy = bool(greedy)
@@ -527,6 +543,15 @@ class LLMEngine:
         # indexed by the SAME chain hashes as the prefix cache
         if kv_spill is None:
             kv_spill = bool(env_float("MXNET_TPU_LLM_KV_SPILL", 0))
+        armed = {"role": role is not None, "mesh": mesh is not None,
+                 "draft_model": draft_model is not None,
+                 "kv_spill": bool(kv_spill),
+                 "prefix_cache": self._prefix_on}
+        for feature, on in armed.items():
+            if on and feature in geom.unsupported:
+                raise ValueError(
+                    f"{feature} is not supported with a {geom.kind} "
+                    f"cache: {geom.unsupported[feature]}")
         self._spill = None
         if kv_spill:
             if not self._prefix_on:
@@ -603,8 +628,16 @@ class LLMEngine:
         from ..gluon.model_zoo.generation import (
             paged_decode_program, paged_prefill_program,
             paged_spec_draft_program, paged_spec_verify_program,
-            paged_suffix_prefill_program)
+            paged_suffix_prefill_program, state_prefill_program)
 
+        # prefill: whole prompts in length buckets, or (a state) chunks
+        # of one fixed size that carry it — one program, one warm-up shape
+        self._chunk = geom.prefill_chunk
+        if self._chunk:
+            self._chunk_run, _ = state_prefill_program(
+                model, chunk=self._chunk, num_blocks=self.num_blocks + 1,
+                greedy=greedy, temperature=temperature, top_k=top_k,
+                donate=self._donate)
         self._paged_prefill_program = paged_prefill_program
         self._paged_suffix_program = paged_suffix_prefill_program
         self._decode_run, self._params = paged_decode_program(
@@ -962,7 +995,7 @@ class LLMEngine:
             raise ValueError(
                 f"prompt {p} + max_new_tokens {max_new_tokens}"
                 f"{slack_note} exceeds max_context {self.max_context}")
-        if -(-(p + max_new_tokens + self._slack) // self.block_size) \
+        if self._geom.blocks_for(p + max_new_tokens + self._slack) \
                 > self.num_blocks:
             raise ValueError(
                 f"request needs more KV blocks than the whole pool holds "
@@ -985,6 +1018,23 @@ class LLMEngine:
     def generate(self, prompt_ids, max_new_tokens: int, **kw):
         """Blocking convenience: submit + wait."""
         return self.submit(prompt_ids, max_new_tokens, **kw).wait()
+
+    def snapshot_cache(self, req: GenRequest):
+        """What the cache holds for an in-flight request, taken between
+        two ticks: ``(positions, tokens, k, v)`` — how many positions the
+        cache has absorbed (the prompt and every emitted token but the
+        last, which the next step feeds), the tokens emitted so far, and
+        the request's blocks of the two pools (``pool[:, blocks]``: its
+        rows of keys and values, or a state cache's one slot) as new
+        device arrays. ``None`` when no lane carries the request."""
+        with self._state_lock, self._mesh_ctx():
+            for lane in self._lanes:
+                if lane is not None and lane.req is req:
+                    ids = onp.asarray(lane.blocks, onp.int32)
+                    return (lane.pos, list(req.tokens),
+                            _pool_gather(self._pool_k, ids),
+                            _pool_gather(self._pool_v, ids))
+        return None
 
     # -- scheduler ---------------------------------------------------------
     def _loop(self) -> None:
@@ -1148,7 +1198,7 @@ class LLMEngine:
             return
         p = int(req.prompt.shape[0])
         bs = self.block_size
-        need = -(-(p + req.max_new_tokens + self._slack) // bs)
+        need = self._geom.blocks_for(p + req.max_new_tokens + self._slack)
         sp.args["prompt_tokens"] = p
         # prefix-cache lookup: the longest run of resident chain hashes
         # (consecutive dict hits == the radix descent, since hash j
@@ -1236,8 +1286,9 @@ class LLMEngine:
         # from submission until the top of this call
         req.admitted_s = now
         self.metrics.queue_wait_ms.observe(sp.args["queue_wait_ms"])
-        sp.args.update(bucket=self._prefill_bucket(p - n_hit * bs),
-                       blocks=len(blocks), prefix_hit_blocks=n_hit)
+        sp.args.update(
+            bucket=self._chunk or self._prefill_bucket(p - n_hit * bs),
+            blocks=len(blocks), prefix_hit_blocks=n_hit)
         ran = False
         try:
             # the chaos injection point for the splice path: an injected
@@ -1254,6 +1305,8 @@ class LLMEngine:
                     ran = True
                     if n_hit:
                         first = self._suffix_prefill(req, blocks, n_hit)
+                    elif self._chunk:
+                        first = self._chunk_prefill(req, blocks)
                     else:
                         first = self._full_prefill(req, blocks)
         except Exception as e:
@@ -1352,6 +1405,34 @@ class LLMEngine:
                 (self._draft_params, padded, onp.int32(p - 1),
                  self._dpool_k, self._dpool_v, ids, self._key))
         return int(first)
+
+    def _chunk_prefill(self, req: GenRequest, blocks: List[int]) -> int:
+        """Prefill a prompt of any length as a loop over the one chunk
+        program, the lane's state carried from chunk to chunk in its
+        slot (``blocks[0]``; the first chunk starts it from zero inside
+        the program). All of a prompt's chunks run in the tick that
+        admits it, as a whole-prompt prefill does; each is waited for,
+        so that ``llm.prefill.chunk`` is the chunk's time on the chip and
+        not its launch."""
+        p, c = int(req.prompt.shape[0]), self._chunk
+        slot = onp.int32(blocks[0])
+        for start in range(0, p, c):
+            n = min(c, p - start)
+            padded = onp.zeros((1, c), onp.int32)
+            padded[0, :n] = req.prompt[start:start + n]
+            with telemetry.span("llm.prefill.chunk",
+                                args={"tokens": n, "pad": c - n,
+                                      "start": start}):
+                first, self._pool_k, self._pool_v = self._chunk_run(
+                    self._params, padded, onp.int32(start), onp.int32(n),
+                    self._pool_k, self._pool_v, slot, self._next_key())
+                first = int(first)
+            self.metrics.count("prefill_chunks")
+        self._record_manifest(
+            "llm.prefill_chunk", c, self._chunk_run,
+            (self._params, padded, onp.int32(start), onp.int32(n),
+             self._pool_k, self._pool_v, slot, self._key))
+        return first
 
     def _suffix_prefill(self, req: GenRequest, blocks: List[int],
                         n_hit: int) -> int:
@@ -1696,7 +1777,9 @@ class LLMEngine:
         Returns the warmed prefill buckets."""
         from .. import aot
 
-        if manifest is not None:
+        if self._chunk:
+            buckets = []        # one chunk program, whatever the lengths
+        elif manifest is not None:
             if not isinstance(manifest, aot.WarmupManifest):
                 manifest = aot.WarmupManifest.load(manifest)
             buckets = sorted({int(e["bucket"])
@@ -1716,6 +1799,17 @@ class LLMEngine:
             self._warmup_buckets_locked(buckets)
 
     def _warmup_buckets_locked(self, buckets) -> None:
+        if self._chunk and "prefill_chunk" not in self._warm:
+            args = (self._params, onp.zeros((1, self._chunk), onp.int32),
+                    onp.int32(0), onp.int32(1))
+            _, self._pool_k, self._pool_v = self._chunk_run(
+                *args, self._pool_k, self._pool_v,
+                onp.int32(self._trash), self._next_key())
+            self._warm.add("prefill_chunk")
+            self._record_manifest(
+                "llm.prefill_chunk", self._chunk, self._chunk_run,
+                (*args, self._pool_k, self._pool_v,
+                 onp.int32(self._trash), self._key))
         for b in buckets:
             if ("llm.prefill", b) in self._warm:
                 continue
@@ -1807,7 +1901,8 @@ class LLMEngine:
             out["sharding"] = {
                 "devices": int(self._mesh.devices.size),
                 "topology": mesh_topology(self._mesh),
-                "pool_bytes_per_device": self._pool_bytes_per_device(),
+                "pool_bytes_per_device": int(
+                    self.metrics.shard_pool_bytes.get()),
             }
         if self._spec:
             out["speculative"] = {

@@ -283,3 +283,124 @@ def test_prefill_program_compiles_for_v5e(lm, kv_cache_dtype, one_chip,
     if kv_cache_dtype is None:
         assert temp - pb * V * 4 < 0.05 * pools, (temp, pools)
         assert not copies, copies
+
+
+# --- a model whose cache is a state: the retention programs ----------------
+# Brumby-14B-Base's published widths, 2 of its layers, the cell's geometry
+B_UNITS, B_FFN, B_HQ, B_HK, B_D, B_V = 5120, 17408, 40, 8, 128, 151936
+B_LANES, B_SLOTS, B_CHUNK, B_DP = 16, 17, 1024, 8320
+
+
+def _retention_kernel(which):
+    from mxnet_tpu.ops.pallas import power_retention as pr
+
+    pools = _state_pools()
+    if which == "step":
+        n, rest = B_LANES, (_s((B_LANES,), "int32"), _s((), "int32"))
+        fn = pr.power_retention_step
+    else:
+        n, rest = B_CHUNK, (_s((), "int32"), _s((), "int32"),
+                            _s((), "bool"), _s((), "int32"))
+        fn = pr.power_retention_chunk
+    return (lambda *a: fn(*a, interpret=False),
+            (_s((n, B_HQ, B_D), "float32"), _s((n, B_HK, B_D), "float32"),
+             _s((n, B_HK, B_D), "float32"), _s((n, B_HK), "float32"),
+             *pools, *rest))
+
+
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_retention_kernel_compiles_for_v5e(which, one_chip,
+                                           no_compile_cache):
+    """The recurrent step (16 lanes) and the chunked form (1,024 tokens)
+    at heads of 128, 40 query over 8 K/V heads, with ``jax_enable_x64``
+    as the package sets it; each under its own name, so that the trace
+    prints it and the roofline readers find it."""
+    assert jax.config.jax_enable_x64
+    fn, args = _retention_kernel(which)
+    text = _compile(fn, args, one_chip, donate=(4, 5)).as_text()
+    assert f"%power_retention_{which}" in text and "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def retention_lm():
+    from mxnet_tpu import initializer as mx_init
+    from mxnet_tpu.gluon.model_zoo import brumby
+
+    net = brumby.brumby_like(
+        vocab_size=B_V, units=B_UNITS, hidden_size=B_FFN, num_layers=2,
+        num_heads=B_HQ, num_kv_heads=B_HK, head_dim=B_D,
+        prefill_chunk=B_CHUNK, dtype="bfloat16")
+    # nothing runs, so the values mean nothing: zeros, not 2.2 G random
+    # draws on the CPU (two minutes)
+    net.initialize(mx_init.Zero())
+    return net
+
+
+def _state_report(compiled, pools, label):
+    """As ``_pool_report``, for the two state pools: the program's
+    temporaries beside the pools' bytes, and every ``copy`` whose result
+    is shaped like either pool."""
+    pool_bytes = sum(int(onp.prod(p.shape)) * 4 for p in pools)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    shaped = [f" = f32[{','.join(map(str, p.shape))}]" for p in pools]
+    copies = [line.strip()[:160] for line in compiled.as_text().splitlines()
+              if " copy(" in line and any(s in line for s in shaped)]
+    print(f"{label}: pools {pool_bytes / 2**20:.1f} MiB, temporaries "
+          f"{temp / 2**20:.1f} MiB, pool-shaped copies {len(copies)}")
+    return temp, pool_bytes, copies
+
+
+def _state_pools():
+    return (_s((2, B_SLOTS, B_HK, B_D, B_DP), "float32"),
+            _s((2, B_SLOTS, B_HK, B_DP), "float32"))
+
+
+def test_state_decode_program_compiles_for_v5e(retention_lm, one_chip,
+                                               no_compile_cache, on_tpu):
+    """The engine's one decode program over a model whose cache is a
+    state — ``paged_decode_program`` as it stands, the block table one
+    slot wide — at the cell's geometry (16 lanes, 17 slots), pools
+    donated: the step kernel takes the pools aliased, so the program
+    holds no copy shaped like a pool. Its temporaries are stated: the
+    rows XLA lays out for the kernel (phi of 48 heads x 16 lanes, 34 MB a
+    layer) and the float32 logits of 16 rows."""
+    from mxnet_tpu.gluon.model_zoo.generation import paged_decode_program
+
+    run, params = paged_decode_program(
+        retention_lm, max_running=B_LANES, num_blocks=B_SLOTS, block_size=16,
+        max_blocks_per_seq=1, kv_cache_dtype="float32", donate=True)
+    pools = _state_pools()
+    compiled = _compile(
+        run._fn,
+        (params, _s((B_LANES, 1), "int32"), *pools, _s((B_LANES, 1), "int32"),
+         _s((B_LANES,), "int32"), _s((2,), "uint32")), one_chip,
+        donate=(2, 3))
+    assert compiled.as_text().count("%power_retention_step") >= 2
+    temp, pool_bytes, copies = _state_report(compiled, pools, "state decode")
+    assert not copies, copies
+    assert temp < 0.15 * pool_bytes, (temp, pool_bytes)
+
+
+def test_state_prefill_program_compiles_for_v5e(retention_lm, one_chip,
+                                                no_compile_cache, on_tpu):
+    """The one chunk-prefill program (1,024 tokens of one lane), pools
+    donated and updated in place by the chunk kernel. Its temporaries are
+    the chunk's activations (the FFN's two 1,024 x 17,408 float32
+    intermediates are 136 MiB) and the logits of ONE row — the head never
+    sees the chunk's other rows, which at 151,936 words would be 594 MiB
+    of float32."""
+    from mxnet_tpu.gluon.model_zoo.generation import state_prefill_program
+
+    run, params = state_prefill_program(
+        retention_lm, chunk=B_CHUNK, num_blocks=B_SLOTS, donate=True)
+    pools = _state_pools()
+    compiled = _compile(
+        run._fn,
+        (params, _s((1, B_CHUNK), "int32"), _s((), "int32"), _s((), "int32"),
+         *pools, _s((), "int32"), _s((2,), "uint32")), one_chip,
+        donate=(4, 5))
+    assert compiled.as_text().count("%power_retention_chunk") >= 2
+    temp, pool_bytes, copies = _state_report(compiled, pools,
+                                             "state prefill 1024")
+    assert not copies, copies
+    assert temp < B_CHUNK * B_V * 4, temp      # no whole-chunk logits
