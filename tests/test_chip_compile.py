@@ -76,22 +76,51 @@ def _s(shape, dtype):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
 
 
-def _pool(dtype):
+def _pool(dtype, heads=H):
     """One KV pool of the 2-layer ``lm`` in the one pool layout,
     ``(L, NB, bs, H*D')``; ``None`` is the model's own dtype."""
     dp = D + 4 if dtype == "int8" else D
-    return _s((2, NB, BS, H * dp), dtype or "bfloat16")
+    return _s((2, NB, BS, heads * dp), dtype or "bfloat16")
 
 
 # --- one case per kernel of the main path ----------------------------------
-def _paged(pool_dtype):
+def _paged(pool_dtype, heads=H, q_dtype="bfloat16"):
+    """The paged kernel at GPT-2-small's rows (12 heads: 768 lanes) or
+    GPT-2-large's (20: 1,280), under the query a bf16 model hands it:
+    float32, since its LayerNorms are float32 (``q_dtype``)."""
     from mxnet_tpu.ops.pallas.paged_attention import paged_attention_kernel
 
-    pool = _pool(pool_dtype)
+    pool = _pool(pool_dtype, heads)
     return (lambda q, k, v, bt, ln, layer: paged_attention_kernel(
                 q, k, v, bt, ln, layer, interpret=False),
-            (_s((R, H, D), "bfloat16"), pool, pool,
+            (_s((R, heads, D), q_dtype), pool, pool,
              _s((R, MB), "int32"), _s((R,), "int32"), _s((), "int32")))
+
+
+def _paged_scratch(fn, args):
+    """The VMEM scratch of the program's paged kernels as traced: the
+    grid of the first, and the bytes of its scratch by memory space
+    (the query's rows, the softmax carry, and two slots of a group's K
+    and V rows where the kernel copies them by hand)."""
+    calls = []
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if (e.primitive.name == "pallas_call" and "_paged_kernel"
+                    in e.params["jaxpr"].debug_info.func_name):
+                calls.append(e)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    grid = calls[0].params["grid_mapping"]
+    sizes = {}
+    for v in calls[0].params["jaxpr"].invars[-grid.num_scratch_operands:]:
+        space = str(v.aval.memory_space or "vmem")
+        if space != "semaphore_mem":
+            sizes[space] = (sizes.get(space, 0) + int(onp.prod(v.aval.shape))
+                            * jnp.dtype(v.aval.dtype).itemsize)
+    return len(calls), tuple(grid.grid), sizes
 
 
 def _fused_qkv(store_dtype):
@@ -152,6 +181,12 @@ def _cross_entropy(dtype):
 KERNELS = {
     "paged-float-pools": lambda: _paged("bfloat16"),
     "paged-int8-pools": lambda: _paged("int8"),
+    "paged-float-pools-768-f32q": lambda: _paged("bfloat16", 12, "float32"),
+    "paged-int8-pools-768-f32q": lambda: _paged("int8", 12, "float32"),
+    "paged-float-pools-1280-f32q": lambda: _paged("bfloat16", 20, "float32"),
+    "paged-int8-pools-1280-f32q": lambda: _paged("int8", 20, "float32"),
+    "paged-float-pools-1280": lambda: _paged("bfloat16", 20),
+    "paged-f32-pools-1280-f32q": lambda: _paged("float32", 20, "float32"),
     "fused-qkv-float-store": lambda: _fused_qkv("bfloat16"),
     "fused-qkv-int8-store": lambda: _fused_qkv("int8"),
     "fused-out": _fused_out,
@@ -172,6 +207,10 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache, on_tpu):
     fn, args = KERNELS[case]()
     compiled = _compile(fn, args, one_chip)
     assert "tpu_custom_call" in compiled.as_text(), case
+    if case.startswith("paged"):
+        _, grid, scratch = _paged_scratch(fn, args)
+        print(f"{case}: grid {grid}, scratch {scratch}")
+        assert onp.prod(grid) <= R * MB // 4, grid     # blocks in groups
     if "fwd-bwd" in case:                 # the Pallas backward, not the scan
         assert compiled.as_text().count("tpu_custom_call") >= 3, case
 
@@ -244,10 +283,9 @@ def test_decode_step_program_compiles_for_v5e(lm, kv_cache_dtype, one_chip,
         lm, max_running=R, num_blocks=NB, block_size=BS,
         max_blocks_per_seq=MB, kv_cache_dtype=kv_cache_dtype, donate=True)
     pool = _pool(kv_cache_dtype)
-    compiled = _compile(
-        run._fn,
-        (params, _s((R, 1), "int32"), pool, pool, _s((R, MB), "int32"),
-         _s((R,), "int32"), _s((2,), "uint32")), one_chip, donate=(2, 3))
+    args = (params, _s((R, 1), "int32"), pool, pool, _s((R, MB), "int32"),
+            _s((R,), "int32"), _s((2,), "uint32"))
+    compiled = _compile(run._fn, args, one_chip, donate=(2, 3))
     # per layer: two norms and the paged kernel, whatever the pool dtype
     assert compiled.as_text().count("tpu_custom_call") >= 2 * 3
     temp, pools, copies = _pool_report(
@@ -255,6 +293,9 @@ def test_decode_step_program_compiles_for_v5e(lm, kv_cache_dtype, one_chip,
     if kv_cache_dtype is None:
         assert temp < 0.05 * pools, (temp, pools)
         assert not copies, copies
+    n, grid, scratch = _paged_scratch(run._fn, args)
+    print(f"paged kernel x {n}: grid {grid}, scratch {scratch}")
+    assert n == 2 and scratch["vmem"] < 16 * 2 ** 20, (n, scratch)
 
 
 @pytest.mark.parametrize("kv_cache_dtype", ["int8", None])

@@ -57,9 +57,10 @@ def _tiny_draft(seed=99, vocab=37, units=16, heads=4, max_length=64):
 # ---------------------------------------------------------------------------
 # op level
 # ---------------------------------------------------------------------------
-def _pools(rng, layers, nb, bs, h, d, dtype):
+def _pools(rng, layers, nb, bs, h, d, dtype, garbage_block=None):
     """Random K and V pools in the one pool layout,
-    ``(L, NB, bs, H*D')``, and the per-head float values they hold."""
+    ``(L, NB, bs, H*D')``, and the per-head float values they hold;
+    ``garbage_block``: a block of every layer that holds 1e30s."""
     import jax.numpy as jnp
 
     from mxnet_tpu.ops.nn import (kv_cache_dequantize, kv_cache_quantize,
@@ -68,6 +69,8 @@ def _pools(rng, layers, nb, bs, h, d, dtype):
     out = []
     for _ in range(2):
         t = jnp.asarray(rng.randn(layers, nb, bs, h, d), jnp.float32)
+        if garbage_block is not None:
+            t = t.at[:, garbage_block].set(1e30)
         if dtype == "int8":
             c = kv_cache_quantize(t)
             assert c.dtype == jnp.int8 and c.shape[-1] == d + 4
@@ -111,11 +114,45 @@ def test_paged_attention_matches_manual():
                                         atol=2e-5)
 
 
-@pytest.mark.parametrize("t", [1, 3])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "bfloat16-f32q",
-                                   "int8"])
-@pytest.mark.parametrize("heads,d", [(12, 64), (20, 64), (4, 16)])
-def test_paged_kernel_matches_jnp(heads, d, dtype, t):
+# (bs, mb, lengths) of the kernel test's geometries. "toy": lengths that
+# end inside a block. "cell": the benchmark's serving cell (blocks of 16,
+# 64 to a lane) with lengths at the edges of a group of blocks (a grid
+# step handles G of them: 128 or 256 positions there), a lane of one
+# position and a full one. "mb5" / "mb13": tables that no G divides.
+_KERNEL_GEOMETRIES = {
+    "toy": (8, 4, [5, 17, 32]),
+    "cell": (16, 64, [1, 16, 127, 128, 129, 1000, 1024]),
+    "mb5": (16, 5, [1, 33, 80]),
+    "mb13": (16, 13, [16, 129, 208]),
+}
+_KERNEL_CASES = [
+    (heads, d, dtype, t, "toy", False)
+    for heads, d in [(12, 64), (20, 64), (4, 16)]
+    for dtype in ["float32", "bfloat16", "bfloat16-f32q", "int8"]
+    for t in [1, 3]
+] + [
+    (20, 64, "bfloat16-f32q", 1, "cell", False),    # what the cell serves
+    (20, 64, "bfloat16-f32q", 3, "cell", False),
+    (12, 64, "float32", 1, "cell", False),
+    (12, 64, "int8", 1, "cell", False),
+    (12, 64, "bfloat16", 3, "cell", False),
+    (20, 64, "bfloat16-f32q", 1, "cell", True),     # garbage past a length
+    (12, 64, "float32", 1, "cell", True),
+    (12, 64, "int8", 1, "cell", True),
+    (4, 16, "float32", 1, "mb5", False),
+    (20, 64, "bfloat16-f32q", 1, "mb5", True),
+    (4, 16, "int8", 3, "mb5", False),
+    (4, 16, "float32", 1, "mb13", True),
+    (12, 64, "bfloat16-f32q", 1, "mb13", False),
+    (12, 64, "int8", 3, "mb13", False),
+]
+
+
+@pytest.mark.parametrize(
+    "heads,d,dtype,t,geometry,garbage", _KERNEL_CASES,
+    ids=["-".join(map(str, c[:5])) + ("-garbage" if c[5] else "")
+         for c in _KERNEL_CASES])
+def test_paged_kernel_matches_jnp(heads, d, dtype, t, geometry, garbage):
     """The Pallas kernel (interpret mode on CPU — the compiled Mosaic
     path on TPU) against the jnp gather oracle on the one pool layout:
     GPT-2's heads x head size (rows of 768 and 1,280 lanes) and a toy,
@@ -123,26 +160,37 @@ def test_paged_kernel_matches_jnp(heads, d, dtype, t):
     dequantize inside the kernel), bf16 pools under a float32 query (a
     bf16 model's norms hand float32 on: what the chip serves), T = 1
     (decode) and T > 1 (suffix prefill, speculative verify: the same
-    kernel on R*T virtual lanes), a layer other than 0, lengths that
-    end inside a block."""
+    kernel on R*T virtual lanes), a layer other than 0, and the
+    geometries above. ``garbage``: every table entry past a lane's
+    length points at a block of 1e30s, as the engine's point at its
+    trash block — what is there may be anything finite and the result
+    may not feel it."""
     import jax.numpy as jnp
 
     from mxnet_tpu.ops.nn import paged_attention, paged_attention_multi
 
     rng = onp.random.RandomState(1 + heads + t)
-    r, bs, nb, mb, layer = 3, 8, 10, 4, 2
+    bs, mb, lens = _KERNEL_GEOMETRIES[geometry]
+    lens = onp.array(lens, onp.int32)
+    r, nb, layer = len(lens), mb + 6, 2
     dtype, _, f32q = dtype.partition("-")
     qdt = "float32" if dtype == "int8" or f32q else dtype
-    kp, _, vp, _ = _pools(rng, 3, nb, bs, heads, d, dtype)
-    bt = jnp.asarray(rng.randint(0, nb, (r, mb)).astype(onp.int32))
+    kp, _, vp, _ = _pools(rng, 3, nb, bs, heads, d, dtype,
+                          garbage_block=nb - 1 if garbage else None)
+    bt = rng.randint(0, nb - 1, (r, mb)).astype(onp.int32)
+    if garbage:
+        past = onp.arange(mb)[None, :] * bs >= lens[:, None]
+        bt = onp.where(past, nb - 1, bt).astype(onp.int32)
+    bt = jnp.asarray(bt)
     if t == 1:
         q = jnp.asarray(rng.randn(r, heads, d), qdt)
-        lens = jnp.asarray(onp.array([5, 17, 32], onp.int32))
-        ref, got = (paged_attention(q, kp, vp, bt, lens, layer=layer,
-                                    use_kernel=uk) for uk in (False, True))
+        ref, got = (paged_attention(q, kp, vp, bt, jnp.asarray(lens),
+                                    layer=layer, use_kernel=uk)
+                    for uk in (False, True))
     else:
         q = jnp.asarray(rng.randn(r, t, heads, d), qdt)
-        pos = jnp.asarray(onp.array([2, 14, 32 - t], onp.int32))
+        # the last of a lane's T queries sees ``lens`` positions
+        pos = jnp.asarray(onp.maximum(lens - t, 0))
         ref, got = (paged_attention_multi(q, kp, vp, bt, pos, layer=layer,
                                           use_kernel=uk)
                     for uk in (False, True))
@@ -151,6 +199,33 @@ def test_paged_kernel_matches_jnp(heads, d, dtype, t):
     onp.testing.assert_allclose(onp.asarray(got, dtype=onp.float32),
                                 onp.asarray(ref, dtype=onp.float32),
                                 rtol=tol, atol=tol)
+
+
+def test_paged_kernel_grid_takes_blocks_in_groups():
+    """The mechanism of PR 30, held without a chip: at the serving
+    cell's geometry (32 lanes x 64 blocks of 16, rows of 1,280, bf16
+    pools under a float32 query) one grid step handles at least four of
+    a lane's blocks, so the ``pallas_call`` has at most 32 * 64 / 4 grid
+    steps. One block a step (73,728 steps a decode step at 0.57 us each,
+    whatever the lanes held) was 88% of the cell's device time."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.pallas.paged_attention import paged_attention_kernel
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+    r, h, d, bs, mb = 32, 20, 64, 16, 64
+    pool = s((36, 1701, bs, h * d), "bfloat16")
+    jaxpr = jax.make_jaxpr(
+        lambda *a: paged_attention_kernel(*a, interpret=True))(
+        s((r, h, d), "float32"), pool, pool, s((r, mb), "int32"),
+        s((r,), "int32"), s((), "int32"))
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1, [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    grid = calls[0].params["grid_mapping"].grid
+    assert int(onp.prod(grid)) <= r * mb // 4, grid
 
 
 # ---------------------------------------------------------------------------
