@@ -109,7 +109,7 @@ def sharded_phase(net, vocab, quick):
     base = _engine(net)
     try:
         toks0 = list(base.submit(prompt, 6).wait(timeout=300))
-        bytes_tp1 = base._pool_bytes_per_device()
+        bytes_tp1 = base._kv.bytes_per_device()
     finally:
         base.close()
 
@@ -363,7 +363,7 @@ def garble_drill(net, vocab, quick):
         remote_errors = [0]
         dp.each_engine(lambda e: remote_errors.__setitem__(
             0, remote_errors[0]
-            + int(e._spill.stats()["remote_errors"])))
+            + int(e._kv.spill.stats()["remote_errors"])))
         row = {
             "fallback_correct": got == expect,
             "wall_s": round(wall, 3),

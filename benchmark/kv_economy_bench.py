@@ -253,7 +253,7 @@ def capacity_phase(net, vocab, quick):
     try:
         # the engine's exact per-block byte cost (k + v pool rows)
         per_block = 2 * int(
-            onp.asarray(eng._pool_k[:, 0]).nbytes)
+            onp.asarray(eng._kv.pools[0][0][:, 0]).nbytes)
         hbm_blocks = eng.num_blocks
         spill_cap = spill_bytes // per_block
         # measured: a working set ~2x the HBM pool, streamed twice —
@@ -274,7 +274,7 @@ def capacity_phase(net, vocab, quick):
         dh, dm = hit1 - hit0, miss1 - miss0
         second_pass_rate = (round(dh / (dh + dm), 5)
                             if (dh + dm) > 0 else 0.0)
-        spilled_now, spilled_bytes = eng._spill.level()
+        spilled_now, spilled_bytes = eng._kv.spill.level()
         row = {
             "per_block_bytes": per_block,
             "hbm_blocks": hbm_blocks,
@@ -395,7 +395,7 @@ def garble_drill(net, vocab, quick):
                 wall = time.monotonic() - t0
             if got != first:
                 lost.append("garble fallback output diverged")
-            remote_errors = b._spill.stats()["remote_errors"]
+            remote_errors = b._kv.spill.stats()["remote_errors"]
             row = {
                 "fallback_correct": got == first,
                 "wall_s": round(wall, 3),

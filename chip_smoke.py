@@ -294,9 +294,8 @@ def decode_program_text(eng, compiled=False) -> str:
     bt = onp.zeros((eng.max_running, eng.max_blocks_per_seq), onp.int32)
     pos = onp.zeros((eng.max_running,), onp.int32)
     with eng._mesh_ctx():
-        lowered = jax.jit(eng._decode_run._fn).lower(
-            eng._params, toks, eng._pool_k, eng._pool_v, bt, pos,
-            eng._key)
+        lowered = jax.jit(eng._decode.run._fn).lower(
+            eng._decode.params, toks, *eng._kv.pools[0], bt, pos, eng._key)
         return lowered.compile().as_text() if compiled \
             else lowered.as_text()
 
@@ -320,7 +319,7 @@ def serve_phase(net, sz, prompt_lens, kv_cache_dtype, seed, compiles,
         note(f"{label}: pools {st['kv_cache_dtype']}, "
              f"{st['pool_blocks_total']} blocks of {st['block_size']}")
         got = run_engine(eng, prompts, new_tokens, compiles, label)
-        for pool in (eng._pool_k, eng._pool_v):
+        for pool in eng._kv.pools[0]:
             assert on_device(pool, devices), pool.devices()
         note(f"{label}: paged attention in the decode program = "
              f"{attention_path(decode_program_text(eng))}")
@@ -355,7 +354,7 @@ def sharded_phase(sz, seed, compiles, devices):
     one = LLMEngine(net, **kw)
     try:
         base = run_engine(one, prompts, new_tokens, compiles, "serve/1")
-        assert on_device(one._pool_k, devices[:1])
+        assert on_device(one._kv.pools[0][0], devices[:1])
         one_pool_bytes = int(one.metrics.shard_pool_bytes.get())
     finally:
         one.close()
@@ -366,18 +365,19 @@ def sharded_phase(sz, seed, compiles, devices):
         assert st["devices"] == 4, st
         assert st["pool_bytes_per_device"] * 4 == one_pool_bytes, \
             (st, one_pool_bytes)
-        for pool in (eng._pool_k, eng._pool_v):
+        for pool in eng._kv.pools[0]:
             shards = pool.addressable_shards
             assert {s.device for s in shards} == set(devices)
             assert all(s.data.shape[-1] * 4 == pool.shape[-1]
                        for s in shards)          # a row's heads, in groups
-        sharded = [k for k, v in eng._params.items()
+        params = eng._decode.params
+        sharded = [k for k, v in params.items()
                    if not v.sharding.is_fully_replicated]
         for k in sharded:
-            assert {s.device for s in eng._params[k].addressable_shards} \
+            assert {s.device for s in params[k].addressable_shards} \
                 == set(devices), k
         assert any("qkv" in k for k in sharded), sharded
-        note(f"serve/tp4: {len(sharded)} of {len(eng._params)} parameters "
+        note(f"serve/tp4: {len(sharded)} of {len(params)} parameters "
              f"and both pools spread over 4 devices; pool bytes per "
              f"device {st['pool_bytes_per_device']} = 1/4 of "
              f"{one_pool_bytes}")

@@ -136,16 +136,6 @@ def _sample(logits, key, greedy, temperature, top_k):
 _KV_CACHE_DTYPES = (None, "int8", "float32", "bfloat16", "float16")
 
 
-def _fused_state() -> bool:
-    """The fused-Pallas-decode arm state at program-build time — part of
-    every paged program's cache key, so toggling
-    ``MXNET_TPU_LLM_FUSED_DECODE`` between engines on one model never
-    resurrects a program traced the other way."""
-    from ...ops.pallas.fused_decode import fused_decode_armed
-
-    return fused_decode_armed()
-
-
 def _resolve_cache_dtype(model, kv_cache_dtype):
     """Validate + default the KV cache dtype (shared by the dense
     generate()/beam_search() path and the paged serving programs)."""
@@ -468,8 +458,7 @@ def paged_decode_program(model, *, max_running, num_blocks, block_size,
                                           weight_dtype)
     tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
     ckey = ("paged_decode", r, int(num_blocks), int(block_size), mb,
-            bool(greedy), *tkey, cache_dtype, weight_dtype, bool(donate),
-            _fused_state())
+            bool(greedy), *tkey, cache_dtype, weight_dtype, bool(donate))
     store, cached = _decode_cache(model, ckey)
     if cached is not None:
         return cached, params
@@ -652,8 +641,7 @@ def paged_suffix_prefill_program(model, *, suffix_len, num_blocks,
                                           weight_dtype)
     tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
     ckey = ("paged_suffix", sb, int(num_blocks), bs, mb, bool(greedy),
-            *tkey, cache_dtype, weight_dtype, bool(donate),
-            _fused_state())
+            *tkey, cache_dtype, weight_dtype, bool(donate))
     store, cached = _decode_cache(model, ckey)
     if cached is not None:
         return cached, params
@@ -789,8 +777,7 @@ def paged_spec_draft_program(model, *, max_running, draft_k, num_blocks,
                                           weight_dtype)
     tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
     ckey = ("spec_draft", r, kk, int(num_blocks), int(block_size), mb,
-            bool(greedy), *tkey, cache_dtype, weight_dtype, bool(donate),
-            _fused_state())
+            bool(greedy), *tkey, cache_dtype, weight_dtype, bool(donate))
     store, cached = _decode_cache(model, ckey)
     if cached is not None:
         return cached, params
@@ -852,8 +839,7 @@ def paged_spec_verify_program(model, *, max_running, draft_k, num_blocks,
                                           weight_dtype)
     tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
     ckey = ("spec_verify", r, kk, int(num_blocks), int(block_size), mb,
-            bool(greedy), *tkey, cache_dtype, weight_dtype, bool(donate),
-            _fused_state())
+            bool(greedy), *tkey, cache_dtype, weight_dtype, bool(donate))
     store, cached = _decode_cache(model, ckey)
     if cached is not None:
         return cached, params

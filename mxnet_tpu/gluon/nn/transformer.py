@@ -192,19 +192,8 @@ class MultiHeadAttention(HybridBlock):
         multiple of 128 at GPT-2 widths) are what lets this scatter, the
         kernel's block and the donated buffer share one row-major layout.
         Static shapes throughout, so one XLA program serves every step
-        at every mix of sequence lengths.
-
-        When the fused Pallas decode path is armed
-        (:func:`~mxnet_tpu.ops.pallas.fused_decode.fused_decode_armed`),
-        the QKV projection (+ int8 KV quantization) and the output
-        projection run as Pallas kernels around the scalar-prefetch
-        paged-attend kernel instead of separate XLA ops."""
+        at every mix of sequence lengths."""
         units, heads = self._units, self._heads
-        from ...ops.pallas import fused_decode as _fused
-
-        if self._fused_eligible() and _fused.fused_decode_armed():
-            return self._forward_step_paged_fused(
-                x, pool_k, pool_v, block_table, positions, layer)
         proj = self.qkv(x)
 
         def fn(p, pk, pv, bt, pos):
@@ -250,45 +239,6 @@ class MultiHeadAttention(HybridBlock):
             fn, (proj, pool_k, pool_v, block_table, positions),
             name="MultiHeadAttentionPagedStep", n_out=3)
         return self.out_proj(out), new_pk, new_pv
-
-    def _fused_eligible(self) -> bool:
-        """Fused Pallas decode only covers the plain (non-TP) Dense
-        projections — TP shards heads across a mesh axis the kernels do
-        not model."""
-        return isinstance(self.qkv, Dense) and isinstance(
-            self.out_proj, Dense)
-
-    def _forward_step_paged_fused(self, x, pool_k, pool_v, block_table,
-                                  positions, layer):
-        """Fused-kernel variant of :meth:`forward_step_paged`: one
-        Pallas kernel per (QKV projection + int8 quantize), the
-        scalar-prefetch paged-attend kernel, and one fused out-proj
-        kernel; the KV write lands in place on the donated pool
-        buffers. Oracle: the jnp path above (interpret mode on CPU)."""
-        from ...ops.pallas.fused_decode import fused_decode_step
-
-        units, heads = self._units, self._heads
-        w_qkv = self.qkv.weight.data()
-        b_qkv = self.qkv.bias.data() if self.qkv.bias is not None else None
-        w_out = self.out_proj.weight.data()
-        b_out = (self.out_proj.bias.data()
-                 if self.out_proj.bias is not None else None)
-
-        def fn(xv, wq, pk, pv, bt, pos, wo, *biases):
-            bq = biases[0] if b_qkv is not None else None
-            bo = biases[-1] if b_out is not None else None
-            return fused_decode_step(
-                xv, wq, bq, wo, bo, pk, pv, bt, pos, layer, heads=heads,
-                units=units)
-
-        args = [x, w_qkv, pool_k, pool_v, block_table, positions, w_out]
-        if b_qkv is not None:
-            args.append(b_qkv)
-        if b_out is not None:
-            args.append(b_out)
-        out, new_pk, new_pv = _call(
-            fn, tuple(args), name="FusedPagedDecodeStep", n_out=3)
-        return out, new_pk, new_pv
 
 
 class PositionwiseFFN(HybridBlock):

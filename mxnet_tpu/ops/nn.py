@@ -949,9 +949,8 @@ _KV_SCALE_BYTES = 4
 # The scale's four bytes (little-endian, the f32's own bit pattern) are
 # taken apart and put together with 32-bit integer ops, not with a
 # 4 x int8 <-> f32 bitcast: Mosaic has no such bitcast, and these two
-# functions are the ONE definition of the layout — the Pallas kernels
-# (ops/pallas/paged_attention.py, fused_decode.py) call them inside the
-# kernel body.
+# functions are the ONE definition of the layout — the Pallas kernel
+# (ops/pallas/paged_attention.py) calls them inside the kernel body.
 def kv_cache_quantize(t):
     """(..., D) float -> (..., D+4) int8 [values | f32 scale bytes]."""
     t = t.astype(jnp.float32)

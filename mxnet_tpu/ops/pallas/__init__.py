@@ -3,10 +3,6 @@ kernels and the NVRTC pointwise-fusion JIT (``src/operator/fusion/``) played
 in the reference. Everything else rides XLA's own fusion.
 """
 from .flash_attention import flash_attention
-from .fused_decode import (fused_decode_armed, fused_decode_step,
-                           fused_out_project, fused_qkv_project)
 from .paged_attention import paged_attention_kernel
 
-__all__ = ["flash_attention", "paged_attention_kernel",
-           "fused_decode_armed", "fused_decode_step",
-           "fused_qkv_project", "fused_out_project"]
+__all__ = ["flash_attention", "paged_attention_kernel"]

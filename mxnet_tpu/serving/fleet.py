@@ -150,9 +150,11 @@ def fleet_affinity_blocks() -> int:
 def fleet_affinity_block_size() -> int:
     """``MXNET_TPU_FLEET_AFFINITY_BLOCK_SIZE`` — MUST match the
     engines' KV block size or affinity keys drift from cache keys
-    (default: the engine default, ``MXNET_TPU_LLM_BLOCK_SIZE`` / 16)."""
+    (default: the engine's, ``llm.DEFAULT_BLOCK_SIZE``, 16)."""
+    from .llm import DEFAULT_BLOCK_SIZE
+
     return int(env_float("MXNET_TPU_FLEET_AFFINITY_BLOCK_SIZE",
-                         env_float("MXNET_TPU_LLM_BLOCK_SIZE", 16)))
+                         DEFAULT_BLOCK_SIZE))
 
 
 def fleet_affinity_max_load() -> float:

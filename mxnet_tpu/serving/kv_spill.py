@@ -20,7 +20,7 @@ keys on:
   ``MXNET_TPU_LLM_KV_SPILL_PEERS``): fetch over the PR-17 block
   transport plane (:class:`~mxnet_tpu.io.transport.BlockClient`) from
   the :class:`~mxnet_tpu.io.transport.BlockServer` another engine
-  exposes (``MXNET_TPU_LLM_KV_SPILL_SERVE``) — the multi-turn session
+  exposes (``kv_spill_serve=True``) — the multi-turn session
   that returns to a *different* replica re-attaches instead of
   re-prefilling.
 

@@ -34,11 +34,13 @@ timeline (``tools/trace_view.py`` attributes them), chaos site
 ``serving.llm`` on the prefill-splice path, and scheduler faults typed
 through the resilience transient-vs-fatal classifier.
 
-See ``docs/llm_serving.md`` for block-table anatomy and scheduler
-policy.
+This module is the scheduler; the pools and everything that indexes them
+are :mod:`.kv_cache` (``docs/llm_serving.md``: modules, block-table
+anatomy, scheduler policy).
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -47,14 +49,22 @@ import jax
 import numpy as onp
 
 from .. import telemetry
-from ..base import FatalError, MXNetError, TransientError, env_float
+from ..base import FatalError, MXNetError, TransientError
 from ..resilience import chaos
 from ..resilience.retry import classify, TRANSIENT
 from ..telemetry import get_registry
 from .admission import (AdmissionQueue, DeadlineExceeded, Request,
                         RequestCancelled, ServerOverload)
+from .kv_cache import KVCache
 
 __all__ = ["LLMEngine", "GenRequest"]
+
+# what an LLMEngine built without the argument gets (num_blocks: enough
+# for every lane at max_context; prefix_cache, kv_spill, kv_spill_serve:
+# off)
+DEFAULT_MAX_RUNNING = 8
+DEFAULT_BLOCK_SIZE = 16
+DEFAULT_DRAFT_K = 4
 
 
 class GenRequest(Request):
@@ -95,6 +105,19 @@ class GenRequest(Request):
         self.trace_id = trace_id
 
 
+def _typed(exc: BaseException, what: str) -> BaseException:
+    """``exc`` as the resilience classifier types it: itself where it
+    already is a Transient- or FatalError, else the one of the two that
+    :func:`classify` says, naming ``what`` faulted, with ``exc`` as its
+    cause."""
+    if isinstance(exc, (TransientError, FatalError)):
+        return exc
+    cls = TransientError if classify(exc) == TRANSIENT else FatalError
+    typed = cls(f"{what}: {exc!r}")
+    typed.__cause__ = exc
+    return typed
+
+
 class _Lane:
     """One decode lane: the request it carries + its block reservation."""
 
@@ -106,6 +129,47 @@ class _Lane:
         self.blocks = blocks        # pool block ids owned by this lane
         self.pos = pos              # absolute position of the NEXT write
         self.last_token = last_token
+
+
+# the prefill programs' manifest labels, by (suffix, draft)
+_PREFILL_LABELS = {(False, False): "llm.prefill",
+                   (False, True): "llm.draft_prefill",
+                   (True, False): "llm.prefill_suffix",
+                   (True, True): "llm.draft_suffix"}
+
+
+@dataclasses.dataclass(eq=False)
+class _Program:
+    """One compiled serving program as the scheduler calls it.
+    ``prog(*host)`` is ``run(params, *host[:pool_at], k, v,
+    *host[pool_at:], key)`` with the cache manager's pool pair ``pair``
+    (0 the target's, 1 the draft's): the pools it returns go back to the
+    manager, the rest comes back as a list. Its first call records its
+    warm-up manifest entry (``label``, ``bucket``); warm-up and the
+    serving path call the same object, so a program's argument order is
+    written at its call and nowhere else."""
+
+    engine: "LLMEngine"
+    run: Callable
+    params: Dict
+    pair: int
+    pool_at: int
+    label: str
+    bucket: int
+    fresh: bool = True          # never called: neither warm nor recorded
+
+    def __call__(self, *host):
+        eng, at = self.engine, self.pool_at
+        pools = eng._kv.pools[self.pair]
+        *out, pools[0], pools[1] = self.run(
+            self.params, *host[:at], *pools, *host[at:], eng._next_key())
+        if self.fresh:
+            self.fresh = False
+            # with the arguments as they are after the call (the pools
+            # it returned): what resolved_key looks the signature up by
+            eng._record_manifest(self, (self.params, *host[:at], *pools,
+                                        *host[at:], eng._key))
+        return out
 
 
 class LLMMetrics:
@@ -274,17 +338,6 @@ class LLMMetrics:
 _engine_seq = __import__("itertools").count()
 
 
-# donate the pool buffer: the scatter updates HBM in place (a DMA of
-# the restored rows), never a functional copy of the whole pool
-_pool_scatter = jax.jit(
-    lambda pool, idx, rows: pool.at[:, idx].set(rows),
-    donate_argnums=(0,))
-
-# batched block-row gather for spill demotion (one D2H per pool per
-# eviction wave, not one per block)
-_pool_gather = jax.jit(lambda pool, idx: pool[:, idx])
-
-
 class LLMEngine:
     """Continuous-batching generation over a paged KV block pool.
 
@@ -301,17 +354,16 @@ class LLMEngine:
         cache geometries", says which features each carries.
     max_running : int
         Decode lanes (the fixed batch axis of the ONE decode program).
-        Default ``MXNET_TPU_LLM_MAX_RUNNING`` (8).
+        Default 8.
     block_size : int
-        Positions per KV block. Default ``MXNET_TPU_LLM_BLOCK_SIZE``
-        (16).
+        Positions per KV block. Default 16.
     max_context : int
         Longest prompt+generation a lane may hold. Defaults to the
         model's context window (``pos_embed`` rows), capped at 2048.
     num_blocks : int
         Pool capacity in blocks (+1 trash block is added internally).
-        Default ``MXNET_TPU_LLM_POOL_BLOCKS``, else enough for every
-        lane at ``max_context`` (no admission ever waits on blocks).
+        Default: enough for every lane at ``max_context`` (no
+        admission ever waits on blocks).
         Smaller pools admit lazily: a request is admitted only when its
         worst-case ``ceil((prompt+max_new)/block_size)`` reservation
         fits the free list, so an in-flight sequence can never hit pool
@@ -341,8 +393,8 @@ class LLMEngine:
         runs its own block pools addressed by the SAME block tables, so
         admission/free/prefix-sharing govern both caches at once.
     draft_k : int, optional
-        Draft tokens proposed per verify step. Default
-        ``MXNET_TPU_LLM_DRAFT_K`` (4). The engine reserves ``draft_k``
+        Draft tokens proposed per verify step. Default 4. The
+        engine reserves ``draft_k``
         extra positions of block capacity per lane (verify writes up to
         K positions past the accepted length; rollback is just not
         advancing the position).
@@ -353,7 +405,7 @@ class LLMEngine:
         on); a request whose leading blocks are resident reuses them
         copy-on-write (per-block refcounts; a block is freed only at
         refcount zero) and prefills ONLY its uncached suffix. Default
-        ``MXNET_TPU_LLM_PREFIX_CACHE`` (off).
+        off.
     kv_spill : bool, optional
         Arms **tiered KV block storage** (requires ``prefix_cache``):
         a refcount-0 LRU block evicted from the pool spills its exact
@@ -363,15 +415,15 @@ class LLMEngine:
         disk tier (``kv_spill_dir``) — and a later admission whose
         prefix misses HBM but hits a spill tier re-attaches by DMA
         instead of re-prefilling (token-identical: the payload is the
-        raw pool rows). Default ``MXNET_TPU_LLM_KV_SPILL`` (off).
+        raw pool rows). Default off.
     kv_spill_bytes / kv_spill_dir / kv_spill_serve / kv_spill_peers :
         Spill-tier shape: host-RAM byte bound
         (``MXNET_TPU_LLM_KV_SPILL_BYTES``, 256 MiB), disk tier root
         (``MXNET_TPU_LLM_KV_SPILL_DIR``), expose spilled blocks to
         remote replicas over a
-        :class:`~mxnet_tpu.io.transport.BlockServer`
-        (``MXNET_TPU_LLM_KV_SPILL_SERVE``; endpoint at
-        :attr:`kv_spill_endpoint`), and peer endpoints to fetch from
+        :class:`~mxnet_tpu.io.transport.BlockServer` (default off;
+        endpoint at :attr:`kv_spill_endpoint`), and peer endpoints to
+        fetch from
         (``MXNET_TPU_LLM_KV_SPILL_PEERS``) — a session resuming on a
         *different* replica re-attaches over the transport plane.
     step_hook : callable, optional
@@ -440,27 +492,10 @@ class LLMEngine:
                  step_hook: Optional[Callable[[], None]] = None,
                  metrics: Optional[LLMMetrics] = None,
                  mesh=None, rules=None, role: Optional[str] = None):
-        from ..gluon.model_zoo.generation import _resolve_cache_dtype
-
         if role not in (None, "prefill", "decode"):
             raise ValueError(
                 f"role {role!r} not supported (None/'prefill'/'decode')")
         self.role = role
-        if role is not None:
-            # disaggregated serving (docs/llm_serving.md): both halves
-            # speak the chain-hash + shared-codec handoff protocol, so
-            # both need the prefix cache and a spill tier. The prefill
-            # side SERVES its exported rows; the decode side probes
-            # peers (wired later via set_kv_spill_peers).
-            if prefix_cache is False or kv_spill is False:
-                raise ValueError(
-                    f"role={role!r} requires prefix_cache and kv_spill "
-                    "(the handoff is keyed by chain hashes and carried "
-                    "by the spill tier)")
-            prefix_cache = True
-            kv_spill = True
-            if role == "prefill" and kv_spill_serve is None:
-                kv_spill_serve = True
         self._mesh = mesh
         if mesh is not None:
             if weight_dtype is not None:
@@ -476,18 +511,19 @@ class LLMEngine:
             self._rules = None
 
         if max_running is None:
-            max_running = int(env_float("MXNET_TPU_LLM_MAX_RUNNING", 8))
+            max_running = DEFAULT_MAX_RUNNING
         if block_size is None:
-            block_size = int(env_float("MXNET_TPU_LLM_BLOCK_SIZE", 16))
+            block_size = DEFAULT_BLOCK_SIZE
         if max_running < 1 or block_size < 1:
             raise ValueError("max_running and block_size must be >= 1")
         self.max_running = int(max_running)
         self.block_size = int(block_size)
-        # the model states the cache's geometry, the engine allocates:
-        # what a pool block is (block_size K/V rows, or one request's
-        # whole state), what a request of n tokens reserves, and which
-        # features the cache cannot carry (generation.CacheGeometry). This
-        # is the one place it is asked for; everything below is one path.
+        # the model states the cache's geometry, the cache manager
+        # allocates: what a pool block is (block_size K/V rows, or one
+        # request's whole state), what a request of n tokens reserves,
+        # and which features the cache cannot carry
+        # (generation.CacheGeometry). This is the one place it is asked
+        # for; everything below is one path.
         geom = self._geom = model.cache_geometry(self.block_size)
         model_ctx = geom.max_positions
         if max_context is None:
@@ -501,19 +537,10 @@ class LLMEngine:
         # so there max_context bounds positions and nothing else
         self.max_blocks_per_seq = geom.blocks_for(self.max_context)
         if num_blocks is None:
-            num_blocks = int(env_float("MXNET_TPU_LLM_POOL_BLOCKS", 0)) \
-                or self.max_running * self.max_blocks_per_seq
+            num_blocks = self.max_running * self.max_blocks_per_seq
         if num_blocks < 1:
             raise ValueError("num_blocks must be >= 1")
         self.num_blocks = int(num_blocks)
-        if geom.cache_dtypes is not None:
-            if kv_cache_dtype not in (None, *geom.cache_dtypes):
-                raise ValueError(
-                    f"kv_cache_dtype {kv_cache_dtype!r} is not supported "
-                    f"with a {geom.kind} cache: it is held as "
-                    f"{'/'.join(geom.cache_dtypes)} (pass that, or None)")
-            kv_cache_dtype = kv_cache_dtype or geom.cache_dtypes[0]
-        self._kv_dtype = _resolve_cache_dtype(model, kv_cache_dtype)
         self._weight_dtype = weight_dtype
         self._greedy = bool(greedy)
         self._temperature = float(temperature)
@@ -527,50 +554,12 @@ class LLMEngine:
         # speculative decoding (armed by a draft model)
         self._draft = draft_model
         if draft_k is None:
-            draft_k = int(env_float("MXNET_TPU_LLM_DRAFT_K", 4))
+            draft_k = DEFAULT_DRAFT_K
         self._draft_k = max(int(draft_k), 1)
         self._spec = draft_model is not None
         # verify writes up to draft_k positions past the accepted
         # length; the block reservation carries that slack
         self._slack = self._draft_k if self._spec else 0
-        # shared-prefix block cache (off unless armed: callers that pin
-        # "free list returns to full" keep that invariant)
-        if prefix_cache is None:
-            prefix_cache = bool(env_float("MXNET_TPU_LLM_PREFIX_CACHE", 0))
-        self._prefix_on = bool(prefix_cache)
-
-        # tiered KV spill under the pool (host RAM / disk / remote) —
-        # indexed by the SAME chain hashes as the prefix cache
-        if kv_spill is None:
-            kv_spill = bool(env_float("MXNET_TPU_LLM_KV_SPILL", 0))
-        armed = {"role": role is not None, "mesh": mesh is not None,
-                 "draft_model": draft_model is not None,
-                 "kv_spill": bool(kv_spill),
-                 "prefix_cache": self._prefix_on}
-        for feature, on in armed.items():
-            if on and feature in geom.unsupported:
-                raise ValueError(
-                    f"{feature} is not supported with a {geom.kind} "
-                    f"cache: {geom.unsupported[feature]}")
-        self._spill = None
-        if kv_spill:
-            if not self._prefix_on:
-                raise ValueError(
-                    "kv_spill requires prefix_cache: spilled blocks are "
-                    "indexed by the prefix cache's chain hashes")
-            from .kv_spill import (KVSpillTier, spill_dir_from_env,
-                                   spill_peers_from_env)
-
-            if kv_spill_serve is None:
-                kv_spill_serve = bool(
-                    env_float("MXNET_TPU_LLM_KV_SPILL_SERVE", 0))
-            self._spill = KVSpillTier(
-                bytes_limit=kv_spill_bytes,
-                root=(kv_spill_dir if kv_spill_dir is not None
-                      else spill_dir_from_env()),
-                peers=(list(kv_spill_peers) if kv_spill_peers is not None
-                       else spill_peers_from_env()),
-                serve=bool(kv_spill_serve))
 
         if donate is None:
             donate = jax.default_backend() != "cpu"
@@ -580,42 +569,25 @@ class LLMEngine:
         self.metrics.lanes_total.set(self.max_running)
         self.metrics.pool_total.set(self.num_blocks)
 
-        # pool state: +1 trash block at index num_blocks — retired lanes
-        # and pad splices write there, never into a live sequence
-        self._trash = self.num_blocks
-        pk, pv = model.init_block_pool(self.num_blocks + 1,
-                                       self.block_size,
-                                       dtype=self._kv_dtype)
-        self._pool_k = self._shard_pool(pk._data)
-        self._pool_v = self._shard_pool(pv._data)
-        self._free: List[int] = list(range(self.num_blocks))
-        self.metrics.pool_free.set(len(self._free))
-        # per-block refcounts (lane ownership + prefix-cache residency;
-        # a block returns to the free list only at refcount zero — the
-        # copy-on-write discipline: shared prompt blocks are read-only
-        # by construction, divergence starts at the first uncached
-        # block, so "copy" never actually copies)
-        self._ref: Dict[int, int] = {}
-        # chain-hash -> resident block id, LRU-ordered (a radix lookup
-        # flattened: the chain hash of block j commits to blocks 0..j,
-        # so longest-prefix match is consecutive dict hits)
-        from collections import OrderedDict
-
-        self._prefix: "OrderedDict[bytes, int]" = OrderedDict()
-        self._prefix_hits = 0
-        # the draft model's block pools, addressed by the SAME block
-        # tables/ids as the target's (one allocation governs both)
-        if self._spec:
-            dk, dv = draft_model.init_block_pool(
-                self.num_blocks + 1, self.block_size,
-                dtype=self._kv_dtype)
-            self._dpool_k = self._shard_pool(dk._data)
-            self._dpool_v = self._shard_pool(dv._data)
+        # the pools and everything that indexes them: free list,
+        # refcounts, the shared-prefix index (off unless armed: callers
+        # that pin "free list returns to full" keep that invariant) and
+        # the spill tiers under it. The draft model's pools are addressed
+        # by the SAME block tables/ids as the target's (one allocation
+        # governs both).
+        self._kv = KVCache(
+            model, geom, num_blocks=self.num_blocks,
+            block_size=self.block_size, kv_cache_dtype=kv_cache_dtype,
+            metrics=self.metrics, draft_model=draft_model,
+            prefix_cache=prefix_cache, kv_spill=kv_spill,
+            kv_spill_bytes=kv_spill_bytes, kv_spill_dir=kv_spill_dir,
+            kv_spill_serve=kv_spill_serve, kv_spill_peers=kv_spill_peers,
+            role=role, mesh=mesh)
 
         # lane state (host side; device arrays mirror it each step)
         self._lanes: List[Optional[_Lane]] = [None] * self.max_running
         self._bt = onp.full((self.max_running, self.max_blocks_per_seq),
-                            self._trash, onp.int32)
+                            self._kv.trash, onp.int32)
         self._pos = onp.zeros((self.max_running,), onp.int32)
         self._toks = onp.zeros((self.max_running, 1), onp.int32)
         # the token at positions-1 per lane (the draft catch-up input)
@@ -623,62 +595,44 @@ class LLMEngine:
 
         # compiled programs (memoized per model config in generation.py;
         # compiled through aot.cached_jit, so MXNET_TPU_AOT_CACHE serves
-        # fresh replicas with zero cold compiles)
+        # fresh replicas with zero cold compiles), each behind the one
+        # helper the serving path and warm-up both call
         from .. import aot
         from ..gluon.model_zoo.generation import (
-            paged_decode_program, paged_prefill_program,
-            paged_spec_draft_program, paged_spec_verify_program,
-            paged_suffix_prefill_program, state_prefill_program)
+            paged_decode_program, paged_spec_draft_program,
+            paged_spec_verify_program, state_prefill_program)
 
-        # prefill: whole prompts in length buckets, or (a state) chunks
-        # of one fixed size that carry it — one program, one warm-up shape
-        self._chunk = geom.prefill_chunk
-        if self._chunk:
-            self._chunk_run, _ = state_prefill_program(
-                model, chunk=self._chunk, num_blocks=self.num_blocks + 1,
-                greedy=greedy, temperature=temperature, top_k=top_k,
-                donate=self._donate)
-        self._paged_prefill_program = paged_prefill_program
-        self._paged_suffix_program = paged_suffix_prefill_program
-        self._decode_run, self._params = paged_decode_program(
-            model, max_running=self.max_running,
-            num_blocks=self.num_blocks + 1, block_size=self.block_size,
-            max_blocks_per_seq=self.max_blocks_per_seq,
-            kv_cache_dtype=self._kv_dtype, weight_dtype=weight_dtype,
-            greedy=greedy, temperature=temperature, top_k=top_k,
-            donate=self._donate)
+        self._warmup_manifest = aot.WarmupManifest()
+        self._paged = dict(block_size=self.block_size,
+                           kv_cache_dtype=self._kv.dtype)
+        step = dict(self._paged, max_running=self.max_running,
+                    max_blocks_per_seq=self.max_blocks_per_seq)
         # GSPMD serving: committed NamedSharding params/pools make the
         # existing plain-jit programs global-array programs — sharding
         # propagates from the inputs, no per-program in_shardings
-        self._params = self._shard_params(self._params)
+        self._params: Dict[bool, Dict] = {}     # by "the draft's?"
+        self._decode = self._program(
+            paged_decode_program, "llm.decode", self.max_running, 1,
+            weight_dtype=weight_dtype, **step)
+        # prefill: whole prompts in length buckets (built on demand,
+        # _prefill_program), or (a state) chunks of one fixed size that
+        # carry it — one program, one warm-up shape
+        self._chunk = geom.prefill_chunk
+        if self._chunk:
+            self._chunk_step = self._program(
+                state_prefill_program, "llm.prefill_chunk", self._chunk, 3,
+                chunk=self._chunk)
+        self._bucketed: Dict[tuple, _Program] = {}
         if self._spec:
-            self._draft_run, self._draft_params = paged_spec_draft_program(
-                draft_model, max_running=self.max_running,
-                draft_k=self._draft_k, num_blocks=self.num_blocks + 1,
-                block_size=self.block_size,
-                max_blocks_per_seq=self.max_blocks_per_seq,
-                kv_cache_dtype=self._kv_dtype, weight_dtype=None,
-                greedy=greedy, temperature=temperature, top_k=top_k,
-                donate=self._donate)
-            self._draft_params = self._shard_params(self._draft_params)
-            self._verify_run, _ = paged_spec_verify_program(
-                model, max_running=self.max_running,
-                draft_k=self._draft_k, num_blocks=self.num_blocks + 1,
-                block_size=self.block_size,
-                max_blocks_per_seq=self.max_blocks_per_seq,
-                kv_cache_dtype=self._kv_dtype, weight_dtype=weight_dtype,
-                greedy=greedy, temperature=temperature, top_k=top_k,
-                donate=self._donate)
-        self._prefill_runs: Dict[int, Callable] = {}
-        self._draft_prefill_runs: Dict[int, Callable] = {}
-        self._suffix_runs: Dict[int, Callable] = {}
-        self._draft_suffix_runs: Dict[int, Callable] = {}
-        self._warmup_manifest = aot.WarmupManifest()
-        self._warm: set = set()
-        self._manifest_keyed: set = set()
+            self._draft_step = self._program(
+                paged_spec_draft_program, "llm.draft", self._draft_k, 2,
+                draft=True, draft_k=self._draft_k, weight_dtype=None, **step)
+            self._verify = self._program(
+                paged_spec_verify_program, "llm.verify", self._draft_k, 3,
+                draft_k=self._draft_k, weight_dtype=weight_dtype, **step)
         self.metrics.shard_devices.set(
             int(mesh.devices.size) if mesh is not None else 1)
-        self.metrics.shard_pool_bytes.set(self._pool_bytes_per_device())
+        self.metrics.shard_pool_bytes.set(self._kv.bytes_per_device())
 
         # scheduler; the state lock covers pool/lane mutation (the
         # scheduler tick vs a caller-thread warmup())
@@ -722,27 +676,6 @@ class LLMEngine:
 
         return use_mesh(self._mesh)
 
-    def _shard_pool(self, arr):
-        """Commit one KV block pool to the mesh as a global array,
-        sharded on its LAST axis (the one pool layout,
-        ``(L, NB+1, bs, H*D')``: a row holds the heads side by side, so
-        ``tp`` equal parts of a row are contiguous groups of whole
-        heads — heads are embarrassingly parallel under paged
-        attention, each head's ``D'`` values with their int8
-        bitcast-scale tail stay together as long as ``tp`` divides the
-        heads, and the block axis stays whole so block ids keep
-        addressing the global pool). On a mesh without a ``tp`` axis
-        the spec collapses to replication (the ``named_sharding``
-        contract)."""
-        if self._mesh is None:
-            return arr
-        from jax.sharding import PartitionSpec as P
-
-        from ..parallel.mesh import named_sharding
-
-        return jax.device_put(
-            arr, named_sharding(P(None, None, None, "tp"), self._mesh))
-
     def _shard_params(self, params):
         """Partition the flat param dict by the rule catalog
         (megatron tp column/row via ``TRANSFORMER_RULES`` unless the
@@ -757,21 +690,6 @@ class LLMEngine:
         specs = match_partition_rules(self._rules, params)
         return shard_tree(params, specs, self._mesh)
 
-    def _pool_bytes_per_device(self) -> int:
-        """Bytes of KV pool resident PER DEVICE — the number that
-        decides whether a model fits a chip. Sharded pools divide the
-        heads of every row across the mesh, so this is the
-        largest-servable-model lever: per-device share = total / tp."""
-        pools = [self._pool_k, self._pool_v]
-        if self._spec:
-            pools += [self._dpool_k, self._dpool_v]
-        total = 0
-        for arr in pools:
-            shards = getattr(arr, "addressable_shards", None)
-            total += (int(shards[0].data.nbytes) if shards
-                      else int(arr.nbytes))
-        return total
-
     # -- prompt bucketing --------------------------------------------------
     def _prefill_bucket(self, p: int) -> int:
         """Smallest pow2 multiple of block_size >= p, capped at the
@@ -782,191 +700,67 @@ class LLMEngine:
         return self.block_size * _pow2_bucket(
             -(-p // self.block_size), self.max_blocks_per_seq)
 
-    def _prefill_run(self, bucket: int) -> Callable:
-        run = self._prefill_runs.get(bucket)
-        if run is None:
-            run, _ = self._paged_prefill_program(
-                self._model, prefill_len=bucket,
-                num_blocks=self.num_blocks + 1,
-                block_size=self.block_size,
-                kv_cache_dtype=self._kv_dtype,
-                weight_dtype=self._weight_dtype, greedy=self._greedy,
-                temperature=self._temperature, top_k=self._top_k,
-                donate=self._donate)
-            self._prefill_runs[bucket] = run
-        return run
+    # -- the compiled programs ---------------------------------------------
+    def _program(self, build, label: str, bucket: int, pool_at: int,
+                 draft: bool = False, **shape) -> "_Program":
+        """One of generation.py's programs (``build``) for the target
+        model or the draft, behind the call helper. A model's programs
+        share one param tree: that of the first built (the decode
+        program's, the draft program's), laid out on the mesh."""
+        run, params = build(
+            self._draft if draft else self._model,
+            num_blocks=self.num_blocks + 1, greedy=self._greedy,
+            temperature=self._temperature, top_k=self._top_k,
+            donate=self._donate, **shape)
+        if draft not in self._params:
+            self._params[draft] = self._shard_params(params)
+        return _Program(self, run, self._params[draft], int(draft), pool_at,
+                        label, bucket)
 
-    def _draft_prefill_run(self, bucket: int) -> Callable:
-        run = self._draft_prefill_runs.get(bucket)
-        if run is None:
-            run, _ = self._paged_prefill_program(
-                self._draft, prefill_len=bucket,
-                num_blocks=self.num_blocks + 1,
-                block_size=self.block_size,
-                kv_cache_dtype=self._kv_dtype,
-                weight_dtype=None, greedy=self._greedy,
-                temperature=self._temperature, top_k=self._top_k,
-                donate=self._donate)
-            self._draft_prefill_runs[bucket] = run
-        return run
+    def _prefill_program(self, bucket: int, suffix: bool = False,
+                         draft: bool = False) -> "_Program":
+        """The whole-prompt prefill program of one length bucket, or the
+        ``suffix`` one that runs behind cached blocks; for the target or
+        the ``draft`` model. Built at its first use."""
+        label = _PREFILL_LABELS[suffix, draft]
+        prog = self._bucketed.get((label, bucket))
+        if prog is None:
+            from ..gluon.model_zoo.generation import (
+                paged_prefill_program, paged_suffix_prefill_program)
 
-    def _suffix_run(self, bucket: int, draft: bool = False) -> Callable:
-        cache = self._draft_suffix_runs if draft else self._suffix_runs
-        run = cache.get(bucket)
-        if run is None:
-            run, _ = self._paged_suffix_program(
-                self._draft if draft else self._model,
-                suffix_len=bucket, num_blocks=self.num_blocks + 1,
-                block_size=self.block_size,
-                max_blocks_per_seq=self.max_blocks_per_seq,
-                kv_cache_dtype=self._kv_dtype,
-                weight_dtype=None if draft else self._weight_dtype,
-                greedy=self._greedy, temperature=self._temperature,
-                top_k=self._top_k, donate=self._donate)
-            cache[bucket] = run
-        return run
-
-    # -- block accounting (refcounts + prefix cache) -----------------------
-    def _prefix_hashes(self, prompt) -> List[bytes]:
-        """Chain hashes of the prompt's FULL blocks: hash j commits to
-        tokens [0, (j+1)*block_size) — equal hash <=> equal prefix, the
-        radix-trie lookup flattened into consecutive dict hits. The
-        discipline lives in :mod:`.kv_hash` — ONE definition shared
-        with the fleet router's prefix-affinity dispatch and the spill
-        tiers, so they can never drift."""
-        from . import kv_hash
-
-        return kv_hash.chain_hashes(prompt, self.block_size)
-
-    def _incref(self, blk: int) -> None:
-        self._ref[blk] = self._ref.get(blk, 0) + 1
-
-    def _decref(self, blk: int) -> None:
-        n = self._ref.get(blk, 0) - 1
-        if n > 0:
-            self._ref[blk] = n
-            return
-        self._ref.pop(blk, None)
-        self._free.append(blk)
-
-    def _alloc(self, n: int) -> Optional[List[int]]:
-        """Take ``n`` blocks off the free list (refcount 1 each),
-        evicting LRU prefix-cache entries that nothing else references
-        when the list runs short. None when even a drained cache cannot
-        cover the reservation."""
-        evicted: List[tuple] = []
-        while len(self._free) < n and self._prefix:
-            for hsh, blk in self._prefix.items():   # LRU order
-                if self._ref.get(blk, 0) == 1:      # cache-only resident
-                    del self._prefix[hsh]
-                    if self._spill is not None:
-                        evicted.append((hsh, blk))
-                    self.metrics.prefix_evictions.inc()
-                    self._decref(blk)
-                    break
+            shape = dict(self._paged,
+                         weight_dtype=None if draft else self._weight_dtype)
+            if suffix:
+                build, pool_at = paged_suffix_prefill_program, 3
+                shape.update(suffix_len=bucket,
+                             max_blocks_per_seq=self.max_blocks_per_seq)
             else:
-                break                               # all cached blocks live
-        if evicted:
-            # demote instead of drop: the blocks' exact rows park in
-            # the host-RAM tier, re-attachable by DMA on the prefix's
-            # next admission. Batched on purpose — a freed block's rows
-            # stay intact until this _alloc hands it back out below, and
-            # eviction runs inside admission, so every per-block D2H
-            # dispatch saved here is TTFT shaved off the incoming
-            # request.
-            self._spill_save(evicted)
-        # gauge tracks evictions even when the allocation still fails —
-        # free + cached must reconcile during the overload window too
-        self.metrics.prefix_cached_blocks.set(len(self._prefix))
-        if len(self._free) < n:
-            return None
-        got = [self._free.pop() for _ in range(n)]
-        for b in got:
-            self._ref[b] = 1
-        return got
+                build, pool_at = paged_prefill_program, 2
+                shape.update(prefill_len=bucket)
+            prog = self._bucketed[label, bucket] = self._program(
+                build, label, bucket, pool_at, draft=draft, **shape)
+        return prog
 
+    # -- the cache manager's surface, as the fleet layer reads it ----------
     def evictable_blocks(self) -> int:
-        """Prefix-cache residents nothing else references (refcount 1)
-        — blocks ``_alloc`` reclaims on demand. Advisory racy read on
-        purpose (no scheduler lock): the fleet's free-capacity gauge
-        adds this to the free list so an idle prefix-cache engine —
-        which keeps served blocks resident instead of returning them —
-        doesn't read as permanently saturated to the router's
-        quota/deadline-class pressure shed or the autoscaler's
-        free-fraction trigger."""
-        try:
-            return sum(1 for b in list(self._prefix.values())
-                       if self._ref.get(b, 0) == 1)
-        except RuntimeError:
-            return 0            # snapshot raced a resize — next read wins
+        """Prefix-cache residents nothing else references — blocks the
+        cache manager reclaims on demand (:meth:`KVCache.evictable`: an
+        advisory read, no scheduler lock)."""
+        return self._kv.evictable()
 
-    # -- tiered KV spill (host RAM / disk / remote) ------------------------
     @property
     def kv_spill_endpoint(self) -> Optional[str]:
         """``host:port`` of this engine's spill BlockServer (None
         unless ``kv_spill_serve`` armed it) — what a peer engine puts
         in its ``kv_spill_peers`` list."""
-        return self._spill.endpoint if self._spill is not None else None
+        return self._kv.endpoint
 
     def set_kv_spill_peers(self, peers: List[str]) -> None:
         """(Re)wire the spill tier's remote peers. The disagg router
         points every decode-role engine at the live prefill fleet's
         export endpoints through this, re-calling it on each scale or
         death event; a no-spill engine ignores it."""
-        if self._spill is not None:
-            self._spill.set_peers(list(peers))
-
-    def _spill_save(self, evicted: List[tuple]) -> None:
-        """Copy the evicted blocks' exact pool rows (and the draft
-        pools' when speculative decoding shares the block ids) into
-        the spill tier — ONE batched gather + D2H per pool, not a
-        dispatch per block. Byte-exact rows are the token-identity
-        guarantee: re-attach restores precisely the KV the prefill
-        wrote, int8 bitcast-scale layout included."""
-        arr = onp.asarray([blk for _, blk in evicted], onp.int32)
-        cols = {"k": onp.asarray(_pool_gather(self._pool_k, arr)),
-                "v": onp.asarray(_pool_gather(self._pool_v, arr))}
-        if self._spec:
-            cols["dk"] = onp.asarray(_pool_gather(self._dpool_k, arr))
-            cols["dv"] = onp.asarray(_pool_gather(self._dpool_v, arr))
-        for i, (hsh, _) in enumerate(evicted):
-            self._spill.put(
-                hsh, {kk: vv[:, i].copy() for kk, vv in cols.items()})
-        blocks, nbytes = self._spill.level()
-        self.metrics.kv_spill_blocks.set(blocks)
-        self.metrics.kv_spill_bytes.set(nbytes)
-
-    def _reattach(self, ids: List[int], payloads: List[Dict],
-                  tiers: List[str], hashes: List[bytes]) -> None:
-        """Write re-attached payload rows back into freshly allocated
-        pool blocks (ONE donated scatter per pool — the donation lets
-        XLA update the pool buffer in place, so the cost is the DMA of
-        the restored rows, not a functional copy of the whole pool) and
-        admit them into the prefix cache as residents."""
-        arr = onp.asarray(ids, onp.int32)
-        self._pool_k = _pool_scatter(
-            self._pool_k, arr,
-            onp.stack([pl["k"] for pl in payloads], axis=1))
-        self._pool_v = _pool_scatter(
-            self._pool_v, arr,
-            onp.stack([pl["v"] for pl in payloads], axis=1))
-        if self._spec:
-            self._dpool_k = _pool_scatter(
-                self._dpool_k, arr,
-                onp.stack([pl["dk"] for pl in payloads], axis=1))
-            self._dpool_v = _pool_scatter(
-                self._dpool_v, arr,
-                onp.stack([pl["dv"] for pl in payloads], axis=1))
-        for blk, hsh in zip(ids, hashes):
-            if hsh not in self._prefix:
-                self._prefix[hsh] = blk
-                self._incref(blk)       # cache residency over the lane ref
-        for t in tiers:
-            self.metrics.count_reattach(t)
-        self.metrics.prefix_cached_blocks.set(len(self._prefix))
-        blocks, nbytes = self._spill.level()
-        self.metrics.kv_spill_blocks.set(blocks)
-        self.metrics.kv_spill_bytes.set(nbytes)
+        self._kv.set_peers(peers)
 
     # -- client surface ----------------------------------------------------
     def submit(self, prompt_ids, max_new_tokens: int,
@@ -1030,10 +824,8 @@ class LLMEngine:
         with self._state_lock, self._mesh_ctx():
             for lane in self._lanes:
                 if lane is not None and lane.req is req:
-                    ids = onp.asarray(lane.blocks, onp.int32)
                     return (lane.pos, list(req.tokens),
-                            _pool_gather(self._pool_k, ids),
-                            _pool_gather(self._pool_v, ids))
+                            *self._kv.snapshot(lane.blocks))
         return None
 
     # -- scheduler ---------------------------------------------------------
@@ -1063,7 +855,7 @@ class LLMEngine:
         occupied = sum(1 for ln in self._lanes if ln is not None)
         with telemetry.span("llm.tick", args={
                 "active": occupied, "queue_len": len(self._queue),
-                "blocks_in_use": self.num_blocks - len(self._free)}) as tick:
+                "blocks_in_use": self._kv.blocks_in_use}) as tick:
             if self._step_hook is not None:
                 # inside the containment: a hook fault (e.g. an armed
                 # serving.fleet.replica chaos rule) routes through _fault
@@ -1097,8 +889,6 @@ class LLMEngine:
                     raise
                 active = [i for i in range(self.max_running)
                           if self._lanes[i] is not None]
-                free = [i for i in range(self.max_running)
-                        if self._lanes[i] is None]
             if not active:
                 if not occupied and not took:
                     # no lane held a request and none came: an idle engine
@@ -1176,14 +966,8 @@ class LLMEngine:
                     if req.trace_id is not None else None) as sp:
                 self._admit_locked(req, lane_idx, sp)
         except Exception as e:  # noqa: BLE001 — typed + escalated
-            if isinstance(e, (TransientError, FatalError)):
-                typed = e
-            else:
-                cls = (TransientError if classify(e) == TRANSIENT
-                       else FatalError)
-                typed = cls(f"LLM admission fault: {e!r}")
-                typed.__cause__ = e
-            if req.fail(typed):     # no-op when already failed inside
+            # no-op when already failed inside
+            if req.fail(_typed(e, "LLM admission fault")):
                 self.metrics.count("failed")
             raise
 
@@ -1198,90 +982,19 @@ class LLMEngine:
             return
         p = int(req.prompt.shape[0])
         bs = self.block_size
-        need = self._geom.blocks_for(p + req.max_new_tokens + self._slack)
+        n_tokens = p + req.max_new_tokens + self._slack
         sp.args["prompt_tokens"] = p
-        # prefix-cache lookup: the longest run of resident chain hashes
-        # (consecutive dict hits == the radix descent, since hash j
-        # commits to the whole prefix through block j)
-        hashes: List[bytes] = []
-        hit_hashes: List[bytes] = []
-        hit_blocks: List[int] = []
-        spill_payloads: List[Dict] = []
-        spill_tiers: List[str] = []
-        if self._prefix_on:
-            hashes = self._prefix_hashes(req.prompt)
-            for hsh in hashes:
-                blk = self._prefix.get(hsh)
-                if blk is None:
-                    break
-                hit_hashes.append(hsh)
-                hit_blocks.append(blk)
-            if self._spill is not None and len(hit_blocks) < len(hashes):
-                # extend the resident run from the spill tiers: blocks
-                # whose content parks in host RAM / disk / a peer
-                # re-attach by DMA instead of re-prefilling. Probed in
-                # chain order — the hit run must stay consecutive.
-                # Remote probes are deadline-bounded and contained
-                # (any transport fault reads as a miss).
-                for j in range(len(hit_blocks), len(hashes)):
-                    payload, tier = self._spill.get(hashes[j])
-                    if payload is None:
-                        break
-                    if self._spec and ("dk" not in payload
-                                       or "dv" not in payload):
-                        break   # a draft-less peer payload cannot
-                    spill_payloads.append(payload)  # feed draft pools
-                    spill_tiers.append(tier)
-            run = len(hit_blocks) + len(spill_payloads)
-            if run and run * bs == p:
-                # the last real token must still run (its logits sample
-                # the first generated token): never consume it from cache
-                if spill_payloads:
-                    spill_payloads.pop()
-                    spill_tiers.pop()
-                else:
-                    hit_blocks.pop()
-                    hit_hashes.pop()
-                run -= 1
-            if run:
-                sb = self._prefill_bucket(p - run * bs)
-                if run + sb // bs > self.max_blocks_per_seq:
-                    # suffix bucket would spill past the block-covered
-                    # context window: fall back to a full prefill
-                    hit_blocks, hit_hashes = [], []
-                    spill_payloads, spill_tiers = [], []
-        n_res = len(hit_blocks)             # HBM-resident shared blocks
-        n_hit = n_res + len(spill_payloads)  # prefill skipped for these
-        # pin the hits BEFORE allocating: _alloc's LRU eviction must
-        # never evict (and re-issue) the very blocks this admission is
-        # about to share — a pinned block (refcount >= 2) is not
-        # evictable
-        for blk, hsh in zip(hit_blocks, hit_hashes):
-            self._incref(blk)
-            self._prefix.move_to_end(hsh)          # LRU bump
-        fresh = self._alloc(need - n_res)
-        if fresh is None:
+        res = self._kv.reserve(req.prompt, n_tokens, self._suffix_fits)
+        if res is None:
             # no free blocks: shed typed-transient so the client's retry
             # loop backs off and resubmits (never blocks the decode batch)
-            for blk in hit_blocks:
-                self._decref(blk)
             self.metrics.count("shed_overload")
             req.fail(ServerOverload(
-                f"KV pool exhausted ({len(self._free)} free blocks, "
-                f"need {need - n_res}) — back off and retry"))
+                f"KV pool exhausted ({self._kv.free_blocks} free blocks, "
+                f"need {self._geom.blocks_for(n_tokens)}) — back off and "
+                "retry"))
             return
-        if spill_payloads:
-            # re-attach: the first len(spill_payloads) fresh blocks
-            # receive the spilled rows and become cache residents
-            self._reattach(fresh[:len(spill_payloads)], spill_payloads,
-                           spill_tiers,
-                           hashes[n_res:n_res + len(spill_payloads)])
-        blocks = hit_blocks + fresh
-        self.metrics.pool_free.set(len(self._free))
-        if self._prefix_on:
-            self.metrics.observe_prefix(n_hit * bs, p - n_hit * bs)
-            if n_hit:
-                self._prefix_hits += 1
+        blocks, n_hit = res.blocks, res.n_hit
         # the request has its blocks: it is admitted, and has waited
         # from submission until the top of this call
         req.admitted_s = now
@@ -1304,25 +1017,17 @@ class LLMEngine:
                 with st.phase("device", "llm.prefill", tid) as prefill:
                     ran = True
                     if n_hit:
-                        first = self._suffix_prefill(req, blocks, n_hit)
+                        first = self._suffix_prefill(req.prompt, blocks,
+                                                     n_hit)
                     elif self._chunk:
-                        first = self._chunk_prefill(req, blocks)
+                        first = self._chunk_prefill(req.prompt, blocks)
                     else:
-                        first = self._full_prefill(req, blocks)
+                        first = self._full_prefill(req.prompt, blocks)
         except Exception as e:
             # contained: the fault fails THIS request, typed through the
             # classifier; the engine keeps serving
-            for b in blocks:
-                self._decref(b)
-            self.metrics.pool_free.set(len(self._free))
-            if isinstance(e, (TransientError, FatalError)):
-                typed = e
-            else:
-                cls = (TransientError if classify(e) == TRANSIENT
-                       else FatalError)
-                typed = cls(f"LLM prefill fault: {e!r}")
-                typed.__cause__ = e
-            req.fail(typed)
+            self._kv.release(blocks)
+            req.fail(_typed(e, "LLM prefill fault"))
             self.metrics.count("failed")
             self.metrics.count("resets")
             if ran and self._donate:
@@ -1334,30 +1039,7 @@ class LLMEngine:
         self.metrics.count("prefills")
         self.metrics.prefill_ms.observe(prefill.dur_s * 1e3)
         self.metrics.tokens_prefill.inc()
-        # admit this prompt's freshly-computed full blocks into the
-        # cache (+1 cache ref each; they are never written again —
-        # decode writes land at positions >= p, past every full block)
-        if self._prefix_on:
-            fresh_cached: List[tuple] = []
-            for j in range(n_hit, min(p // bs, len(hashes))):
-                hsh = hashes[j]
-                if hsh not in self._prefix:
-                    self._prefix[hsh] = blocks[j]
-                    self._incref(blocks[j])
-                    fresh_cached.append((hsh, blocks[j]))
-            self.metrics.prefix_cached_blocks.set(len(self._prefix))
-            if self.role == "prefill" and fresh_cached:
-                # disaggregated handoff: a prefill-role engine EXPORTS
-                # every freshly computed full block's rows into its
-                # serving spill tier the moment prefill lands — the
-                # decode replica fetches them as its "remote" tier and
-                # re-attaches by DMA. Export precedes req.finish(), so
-                # the router's prefill wait() doubles as the
-                # export-complete barrier. (Same batched D2H gather as
-                # eviction demotion; an evicted export later reads as a
-                # contained miss and the decode side re-prefills.)
-                self._spill_save(fresh_cached)
-                self.metrics.handoff_exported.inc(len(fresh_cached))
+        self._kv.commit(res, p)
         req.prefill_s = prefill.dur_s
         req.first_token_s = req.latency_s
         lane = _Lane(req, blocks, pos=p, last_token=first)
@@ -1367,7 +1049,7 @@ class LLMEngine:
         if self._retire_if_done(lane, lane_idx=None):
             return
         self._lanes[lane_idx] = lane
-        self._bt[lane_idx, :] = self._trash
+        self._bt[lane_idx, :] = self._kv.trash
         self._bt[lane_idx, :len(blocks)] = blocks
         self._pos[lane_idx] = lane.pos
         self._toks[lane_idx, 0] = lane.last_token
@@ -1376,37 +1058,34 @@ class LLMEngine:
         self.metrics.lanes_active.set(
             sum(1 for ln in self._lanes if ln is not None))
 
-    def _full_prefill(self, req: GenRequest, blocks: List[int]) -> int:
+    def _suffix_fits(self, run: int, rest: int) -> bool:
+        """Can the ``rest`` of a prompt be prefilled behind ``run`` cached
+        blocks? Not where its bucket would reach past the block-covered
+        context window: the prompt then prefills whole
+        (``KVCache.reserve`` asks: the buckets are the scheduler's)."""
+        return (run + self._prefill_bucket(rest) // self.block_size
+                <= self.max_blocks_per_seq)
+
+    def _full_prefill(self, prompt, blocks: List[int]) -> int:
         """Bucketed whole-prompt prefill (+ the draft model's, writing
-        the SAME block ids into its own pools, when spec is armed)."""
-        p = int(req.prompt.shape[0])
+        the SAME block ids into its own pools, when spec is armed).
+        Warm-up calls it with a prompt that fills the bucket and no
+        blocks: every row then lands in the trash block."""
+        p = int(prompt.shape[0])
         bucket = self._prefill_bucket(p)
-        nb_bucket = bucket // self.block_size
-        nb_real = -(-p // self.block_size)
-        ids = onp.full((nb_bucket,), self._trash, onp.int32)
-        ids[:nb_real] = blocks[:nb_real]
+        real = blocks[:-(-p // self.block_size)]
+        ids = onp.full((bucket // self.block_size,), self._kv.trash,
+                       onp.int32)
+        ids[:len(real)] = real
         padded = onp.zeros((1, bucket), onp.int32)
-        padded[0, :p] = req.prompt
-        run = self._prefill_run(bucket)
-        first, self._pool_k, self._pool_v = run(
-            self._params, padded, onp.int32(p - 1), self._pool_k,
-            self._pool_v, ids, self._next_key())
-        self._record_manifest(
-            "llm.prefill", bucket, run,
-            (self._params, padded, onp.int32(p - 1), self._pool_k,
-             self._pool_v, ids, self._key))
+        padded[0, :p] = prompt
+        first, = self._prefill_program(bucket)(padded, onp.int32(p - 1), ids)
         if self._spec:
-            drun = self._draft_prefill_run(bucket)
-            _, self._dpool_k, self._dpool_v = drun(
-                self._draft_params, padded, onp.int32(p - 1),
-                self._dpool_k, self._dpool_v, ids, self._next_key())
-            self._record_manifest(
-                "llm.draft_prefill", bucket, drun,
-                (self._draft_params, padded, onp.int32(p - 1),
-                 self._dpool_k, self._dpool_v, ids, self._key))
+            self._prefill_program(bucket, draft=True)(
+                padded, onp.int32(p - 1), ids)
         return int(first)
 
-    def _chunk_prefill(self, req: GenRequest, blocks: List[int]) -> int:
+    def _chunk_prefill(self, prompt, blocks: List[int]) -> int:
         """Prefill a prompt of any length as a loop over the one chunk
         program, the lane's state carried from chunk to chunk in its
         slot (``blocks[0]``; the first chunk starts it from zero inside
@@ -1414,61 +1093,38 @@ class LLMEngine:
         admits it, as a whole-prompt prefill does; each is waited for,
         so that ``llm.prefill.chunk`` is the chunk's time on the chip and
         not its launch."""
-        p, c = int(req.prompt.shape[0]), self._chunk
+        p, c = int(prompt.shape[0]), self._chunk
         slot = onp.int32(blocks[0])
         for start in range(0, p, c):
             n = min(c, p - start)
             padded = onp.zeros((1, c), onp.int32)
-            padded[0, :n] = req.prompt[start:start + n]
+            padded[0, :n] = prompt[start:start + n]
             with telemetry.span("llm.prefill.chunk",
                                 args={"tokens": n, "pad": c - n,
                                       "start": start}):
-                first, self._pool_k, self._pool_v = self._chunk_run(
-                    self._params, padded, onp.int32(start), onp.int32(n),
-                    self._pool_k, self._pool_v, slot, self._next_key())
+                first, = self._chunk_step(padded, onp.int32(start),
+                                          onp.int32(n), slot)
                 first = int(first)
             self.metrics.count("prefill_chunks")
-        self._record_manifest(
-            "llm.prefill_chunk", c, self._chunk_run,
-            (self._params, padded, onp.int32(start), onp.int32(n),
-             self._pool_k, self._pool_v, slot, self._key))
         return first
 
-    def _suffix_prefill(self, req: GenRequest, blocks: List[int],
-                        n_hit: int) -> int:
+    def _suffix_prefill(self, prompt, blocks: List[int], n_hit: int) -> int:
         """Prefill ONLY the uncached suffix: one multi-token paged step
         attending over the resident prefix blocks through the lane's
         table — the cached prefix's prefill compute is skipped
         entirely."""
-        p = int(req.prompt.shape[0])
-        bs = self.block_size
-        start = n_hit * bs
-        s = p - start
+        start = n_hit * self.block_size
+        s = int(prompt.shape[0]) - start
         bucket = self._prefill_bucket(s)
         padded = onp.zeros((1, bucket), onp.int32)
-        padded[0, :s] = req.prompt[start:]
-        table = onp.full((1, self.max_blocks_per_seq), self._trash,
+        padded[0, :s] = prompt[start:]
+        table = onp.full((1, self.max_blocks_per_seq), self._kv.trash,
                          onp.int32)
         table[0, :len(blocks)] = blocks
-        run = self._suffix_run(bucket)
-        first, self._pool_k, self._pool_v = run(
-            self._params, padded, onp.int32(start), onp.int32(s - 1),
-            self._pool_k, self._pool_v, table, self._next_key())
-        self._record_manifest(
-            "llm.prefill_suffix", bucket, run,
-            (self._params, padded, onp.int32(start), onp.int32(s - 1),
-             self._pool_k, self._pool_v, table, self._key))
+        args = (padded, onp.int32(start), onp.int32(s - 1), table)
+        first, = self._prefill_program(bucket, suffix=True)(*args)
         if self._spec:
-            drun = self._suffix_run(bucket, draft=True)
-            _, self._dpool_k, self._dpool_v = drun(
-                self._draft_params, padded, onp.int32(start),
-                onp.int32(s - 1), self._dpool_k, self._dpool_v, table,
-                self._next_key())
-            self._record_manifest(
-                "llm.draft_suffix", bucket, drun,
-                (self._draft_params, padded, onp.int32(start),
-                 onp.int32(s - 1), self._dpool_k, self._dpool_v, table,
-                 self._key))
+            self._prefill_program(bucket, suffix=True, draft=True)(*args)
         return int(first)
 
     def _lane_trace_ids(self, active: List[int]) -> List[str]:
@@ -1495,9 +1151,7 @@ class LLMEngine:
             # gets futures back; fetch: the host waits for the chip
             with st.phase("device", "llm.decode.launch",
                           dict(at, trace_ids=tids) if tids else at) as launch:
-                nxt, self._pool_k, self._pool_v = self._decode_run(
-                    self._params, self._toks, self._pool_k, self._pool_v,
-                    self._bt, self._pos, self._next_key())
+                nxt, = self._decode(self._toks, self._bt, self._pos)
             with st.phase("device", "llm.decode.fetch", at) as fetch:
                 nxt = onp.asarray(nxt)
         with telemetry.span("llm.emit", args={"tokens": len(active)}):
@@ -1506,10 +1160,6 @@ class LLMEngine:
             self.metrics.decode_ms.observe(step_ms)
             self.metrics.token_latency_ms.observe(step_ms / len(active))
             self.metrics.tokens_decode.inc(len(active))
-            self._record_manifest(
-                "llm.decode", self.max_running, self._decode_run,
-                (self._params, self._toks, self._pool_k, self._pool_v,
-                 self._bt, self._pos, self._key))
             self._observe_tok_s(len(active))
             for i in active:
                 lane = self._lanes[i]
@@ -1525,6 +1175,13 @@ class LLMEngine:
                 self._toks[i, 0] = tok
             self.metrics.lanes_active.set(
                 sum(1 for ln in self._lanes if ln is not None))
+
+    def _draft_verify(self, prev, toks, bt, pos):
+        """The two programs of a speculative round: the draft proposes
+        from ``prev`` and ``toks``, the target verifies. Returns the
+        verify program's ``[out_toks, n_acc]``."""
+        d_toks, d_lgs = self._draft_step(prev, toks, bt, pos)
+        return self._verify(toks, d_toks, d_lgs, bt, pos)
 
     def _spec_step(self, active: List[int]) -> None:
         """One speculative round over the whole lane set: the draft
@@ -1546,16 +1203,8 @@ class LLMEngine:
                 # propagates to _fault(), which fails the in-flight
                 # requests typed-transient and keeps the engine serving
                 chaos.site("serving.llm.verify", lanes=len(active))
-                d_toks, d_lgs, self._dpool_k, self._dpool_v = \
-                    self._draft_run(
-                        self._draft_params, self._prev, self._toks,
-                        self._dpool_k, self._dpool_v, self._bt,
-                        self._pos, self._next_key())
-                out, n_acc, self._pool_k, self._pool_v = \
-                    self._verify_run(
-                        self._params, self._toks, d_toks, d_lgs,
-                        self._pool_k, self._pool_v, self._bt, self._pos,
-                        self._next_key())
+                out, n_acc = self._draft_verify(self._prev, self._toks,
+                                                self._bt, self._pos)
             with st.phase("device", "llm.decode.fetch", at) as fetch:
                 out = onp.asarray(out)
                 n_acc = onp.asarray(n_acc)
@@ -1565,14 +1214,6 @@ class LLMEngine:
             self.metrics.count("decode_steps")
             self.metrics.decode_ms.observe(step_ms)
             self.metrics.spec_ms.observe(step_ms)
-            self._record_manifest(
-                "llm.draft", self._draft_k, self._draft_run,
-                (self._draft_params, self._prev, self._toks, self._dpool_k,
-                 self._dpool_v, self._bt, self._pos, self._key))
-            self._record_manifest(
-                "llm.verify", self._draft_k, self._verify_run,
-                (self._params, self._toks, d_toks, d_lgs, self._pool_k,
-                 self._pool_v, self._bt, self._pos, self._key))
             emitted_total = 0
             accepted_total = 0
             for i in active:
@@ -1651,13 +1292,11 @@ class LLMEngine:
         finishes; a block returns to the free list only when its
         refcount hits zero (prefix-cache residents and other lanes
         sharing a prompt prefix keep theirs alive)."""
-        for b in lane.blocks:
-            self._decref(b)
+        self._kv.release(lane.blocks)
         lane.blocks = []
-        self.metrics.pool_free.set(len(self._free))
         if lane_idx is not None:
             self._lanes[lane_idx] = None
-            self._bt[lane_idx, :] = self._trash
+            self._bt[lane_idx, :] = self._kv.trash
             self._pos[lane_idx] = 0
             self._toks[lane_idx, 0] = 0
             self._prev[lane_idx, 0] = 0
@@ -1673,12 +1312,7 @@ class LLMEngine:
 
     def _fault_locked(self, exc: Exception) -> bool:
         kind = classify(exc)
-        if isinstance(exc, (TransientError, FatalError)):
-            typed = exc
-        else:
-            cls = TransientError if kind == TRANSIENT else FatalError
-            typed = cls(f"LLM scheduler fault ({kind}): {exc!r}")
-            typed.__cause__ = exc
+        typed = _typed(exc, f"LLM scheduler fault ({kind})")
         self.metrics.count("resets")
         fatal = kind != TRANSIENT
         if fatal:
@@ -1692,28 +1326,8 @@ class LLMEngine:
                 lane.req.fail(typed)
                 self.metrics.count("failed")
         # the failed program call may have consumed donated pool
-        # buffers: rebuild them (zeroed — no live lanes remain). The
-        # prefix cache indexes pool CONTENT, so it resets with the pool.
-        pk, pv = self._model.init_block_pool(
-            self.num_blocks + 1, self.block_size, dtype=self._kv_dtype)
-        self._pool_k = self._shard_pool(pk._data)
-        self._pool_v = self._shard_pool(pv._data)
-        if self._spec:
-            dk, dv = self._draft.init_block_pool(
-                self.num_blocks + 1, self.block_size,
-                dtype=self._kv_dtype)
-            self._dpool_k = self._shard_pool(dk._data)
-            self._dpool_v = self._shard_pool(dv._data)
-        self._free = list(range(self.num_blocks))
-        self._ref.clear()
-        self._prefix.clear()
-        # the spill tier SURVIVES the rebuild on purpose: it is
-        # content-addressed (chain hash -> exact payload copy), so its
-        # entries stay valid after the pool's block ids are reissued —
-        # the first post-fault admissions re-attach instead of paying a
-        # cold re-prefill
-        self.metrics.prefix_cached_blocks.set(0)
-        self.metrics.pool_free.set(len(self._free))
+        # buffers: rebuild them (zeroed — no live lanes remain)
+        self._kv.reset()
         self.metrics.lanes_active.set(0)
         if not fatal:
             return True                 # keep serving new requests
@@ -1741,21 +1355,18 @@ class LLMEngine:
         if span > 0:
             self.metrics.tok_s.set(sum(x[1] for x in w[1:]) / span)
 
-    def _record_manifest(self, label: str, bucket: int, run=None,
-                         args=()) -> None:
+    def _record_manifest(self, prog: _Program, args) -> None:
         """Decode-frontier warmup manifest: every compiled program's
         signature (+ AOT store key when the persistent cache is armed)
         so replicas replay exactly this frontier (``engine.warmup``,
-        ``tools/aot_warmup.py --manifest``). Best-effort: must never
-        fail a served step."""
-        ident = (label, bucket)
-        if ident in self._manifest_keyed:
-            return
-        self._manifest_keyed.add(ident)
-        entry = {"label": label, "bucket": int(bucket),
-                 "dtype": str(self._kv_dtype)}
+        ``tools/aot_warmup.py --manifest``). Called by a program's first
+        call, with its arguments. Best-effort: must never fail a served
+        step."""
+        entry = {"label": prog.label, "bucket": int(prog.bucket),
+                 "dtype": str(self._kv.dtype)}
         try:
-            key = getattr(run, "resolved_key", lambda *a: None)(*args)
+            key = getattr(prog.run, "resolved_key",
+                          lambda *a: None)(*args)
             if key:
                 entry["key"] = key
         except Exception:  # noqa: BLE001
@@ -1791,77 +1402,27 @@ class LLMEngine:
                     else [self.block_size])
             buckets = sorted({self._prefill_bucket(int(p)) for p in lens})
         # warming is running: one real (trash-table) call per program
-        self._warmup_buckets(buckets)
-        return buckets
-
-    def _warmup_buckets(self, buckets) -> None:
         with self._state_lock, self._mesh_ctx():
             self._warmup_buckets_locked(buckets)
+        return buckets
 
     def _warmup_buckets_locked(self, buckets) -> None:
-        if self._chunk and "prefill_chunk" not in self._warm:
-            args = (self._params, onp.zeros((1, self._chunk), onp.int32),
-                    onp.int32(0), onp.int32(1))
-            _, self._pool_k, self._pool_v = self._chunk_run(
-                *args, self._pool_k, self._pool_v,
-                onp.int32(self._trash), self._next_key())
-            self._warm.add("prefill_chunk")
-            self._record_manifest(
-                "llm.prefill_chunk", self._chunk, self._chunk_run,
-                (*args, self._pool_k, self._pool_v,
-                 onp.int32(self._trash), self._key))
+        """Each program that has not run yet, once, on trash tables."""
+        trash = self._kv.trash
+        if self._chunk and self._chunk_step.fresh:
+            self._chunk_step(onp.zeros((1, self._chunk), onp.int32),
+                             onp.int32(0), onp.int32(1), onp.int32(trash))
         for b in buckets:
-            if ("llm.prefill", b) in self._warm:
-                continue
-            run = self._prefill_run(b)
-            padded = onp.zeros((1, b), onp.int32)
-            ids = onp.full((b // self.block_size,), self._trash, onp.int32)
-            _, self._pool_k, self._pool_v = run(
-                self._params, padded, onp.int32(0), self._pool_k,
-                self._pool_v, ids, self._next_key())
-            self._warm.add(("llm.prefill", b))
-            self._record_manifest(
-                "llm.prefill", b, run,
-                (self._params, padded, onp.int32(0), self._pool_k,
-                 self._pool_v, ids, self._key))
-            if self._spec:
-                drun = self._draft_prefill_run(b)
-                _, self._dpool_k, self._dpool_v = drun(
-                    self._draft_params, padded, onp.int32(0),
-                    self._dpool_k, self._dpool_v, ids, self._next_key())
-                self._record_manifest(
-                    "llm.draft_prefill", b, drun,
-                    (self._draft_params, padded, onp.int32(0),
-                     self._dpool_k, self._dpool_v, ids, self._key))
+            if self._prefill_program(b).fresh:
+                self._full_prefill(onp.zeros((b,), onp.int32), [])
         toks = onp.zeros((self.max_running, 1), onp.int32)
-        bt = onp.full((self.max_running, self.max_blocks_per_seq),
-                      self._trash, onp.int32)
+        bt = onp.full((self.max_running, self.max_blocks_per_seq), trash,
+                      onp.int32)
         pos = onp.zeros((self.max_running,), onp.int32)
-        if "decode" not in self._warm:
-            _, self._pool_k, self._pool_v = self._decode_run(
-                self._params, toks, self._pool_k, self._pool_v, bt, pos,
-                self._next_key())
-            self._warm.add("decode")
-            self._record_manifest(
-                "llm.decode", self.max_running, self._decode_run,
-                (self._params, toks, self._pool_k, self._pool_v, bt, pos,
-                 self._key))
-        if self._spec and "spec" not in self._warm:
-            d_toks, d_lgs, self._dpool_k, self._dpool_v = self._draft_run(
-                self._draft_params, toks, toks, self._dpool_k,
-                self._dpool_v, bt, pos, self._next_key())
-            _, _, self._pool_k, self._pool_v = self._verify_run(
-                self._params, toks, d_toks, d_lgs, self._pool_k,
-                self._pool_v, bt, pos, self._next_key())
-            self._warm.add("spec")
-            self._record_manifest(
-                "llm.draft", self._draft_k, self._draft_run,
-                (self._draft_params, toks, toks, self._dpool_k,
-                 self._dpool_v, bt, pos, self._key))
-            self._record_manifest(
-                "llm.verify", self._draft_k, self._verify_run,
-                (self._params, toks, d_toks, d_lgs, self._pool_k,
-                 self._pool_v, bt, pos, self._key))
+        if self._decode.fresh:
+            self._decode(toks, bt, pos)
+        if self._spec and self._draft_step.fresh:
+            self._draft_verify(toks, toks, bt, pos)
 
     def warmup_manifest(self):
         """The live decode-frontier manifest (keeps growing)."""
@@ -1881,8 +1442,8 @@ class LLMEngine:
             "max_running": self.max_running,
             "block_size": self.block_size,
             "pool_blocks_total": self.num_blocks,
-            "pool_blocks_free": len(self._free),
-            "kv_cache_dtype": self._kv_dtype,
+            "pool_blocks_free": self._kv.free_blocks,
+            "kv_cache_dtype": self._kv.dtype,
             "tok_s": round(float(self.metrics.tok_s.get()), 2),
             "decode_step_ms": self.metrics.decode_ms.summary(),
             "prefill_ms": self.metrics.prefill_ms.summary(),
@@ -1912,17 +1473,17 @@ class LLMEngine:
                 "draft_acceptance_rate": round(
                     float(self.metrics.draft_acceptance_rate.get()), 4),
             }
-        if self._prefix_on:
+        if self._kv.prefix_on:
             out["prefix_cache"] = {
-                "cached_blocks": len(self._prefix),
-                "hit_requests": self._prefix_hits,
+                "cached_blocks": len(self._kv.prefix),
+                "hit_requests": self._kv.hit_requests,
                 "hit_tokens": int(self.metrics.prefix_hit_tokens.value),
                 "miss_tokens": int(self.metrics.prefix_miss_tokens.value),
                 "prefix_hit_rate": round(
                     float(self.metrics.prefix_hit_rate.get()), 4),
             }
-        if self._spill is not None:
-            out["kv_spill"] = self._spill.stats()
+        if self._kv.spill is not None:
+            out["kv_spill"] = self._kv.spill.stats()
         return out
 
     @property
@@ -1993,8 +1554,7 @@ class LLMEngine:
                   self.metrics.kv_spill_bytes, self.metrics.shard_devices,
                   self.metrics.shard_pool_bytes):
             g.set(0)
-        if self._spill is not None:
-            self._spill.close()
+        self._kv.close()
 
     def __enter__(self) -> "LLMEngine":
         return self

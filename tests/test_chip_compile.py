@@ -123,24 +123,6 @@ def _paged_scratch(fn, args):
     return len(calls), tuple(grid.grid), sizes
 
 
-def _fused_qkv(store_dtype):
-    from mxnet_tpu.ops.pallas.fused_decode import fused_qkv_project
-
-    return (lambda x, w, b: fused_qkv_project(
-                x, w, b, heads=H, store_dtype=jnp.dtype(store_dtype),
-                interpret=False),
-            (_s((R, U), "bfloat16"), _s((3 * U, U), "bfloat16"),
-             _s((3 * U,), "bfloat16")))
-
-
-def _fused_out():
-    from mxnet_tpu.ops.pallas.fused_decode import fused_out_project
-
-    return (lambda a, w, b: fused_out_project(a, w, b, interpret=False),
-            (_s((R, U), "bfloat16"), _s((U, U), "bfloat16"),
-             _s((U,), "bfloat16")))
-
-
 def _flash(blocks, backward):
     # the module, not the function of the same name the package exports
     fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
@@ -187,9 +169,6 @@ KERNELS = {
     "paged-int8-pools-1280-f32q": lambda: _paged("int8", 20, "float32"),
     "paged-float-pools-1280": lambda: _paged("bfloat16", 20),
     "paged-f32-pools-1280-f32q": lambda: _paged("float32", 20, "float32"),
-    "fused-qkv-float-store": lambda: _fused_qkv("bfloat16"),
-    "fused-qkv-int8-store": lambda: _fused_qkv("int8"),
-    "fused-out": _fused_out,
     "flash-fwd-256x512": lambda: _flash((256, 512), False),
     "flash-fwd-128x128": lambda: _flash((128, 128), False),
     "flash-fwd-bwd-256x512": lambda: _flash((256, 512), True),

@@ -169,7 +169,7 @@ def test_sharded_engine_token_identity_and_pool_shrink():
     base = _engine(_shard_net())
     try:
         expect = list(base.submit(prompt, 4).wait(timeout=300))
-        bytes_tp1 = base._pool_bytes_per_device()
+        bytes_tp1 = base._kv.bytes_per_device()
     finally:
         base.close()
 
@@ -364,7 +364,7 @@ def test_garbled_handoff_frame_falls_back_to_local_prefill():
         assert got == expect
         errs = [0]
         dp.each_engine(lambda e: errs.__setitem__(
-            0, errs[0] + int(e._spill.stats()["remote_errors"])))
+            0, errs[0] + int(e._kv.spill.stats()["remote_errors"])))
         assert errs[0] >= 1, "garble was not exercised/contained"
         # the prefill stage itself succeeded — the miss was decode-side
         assert router.handoff_counts()["exported"] >= 1

@@ -88,8 +88,11 @@ def test_kv_hash_drift_engine_vs_helper():
         rng = onp.random.RandomState(3)
         for n in (4, 9, 16, 23):
             prompt = rng.randint(0, 37, (n,)).astype(onp.int32)
-            assert eng._prefix_hashes(prompt) == kv_hash.chain_hashes(
-                prompt, eng.block_size)
+            with eng._state_lock:
+                res = eng._kv.reserve(prompt, n + 1)
+                eng._kv.release(res.blocks)
+            assert res.hashes == kv_hash.chain_hashes(prompt,
+                                                      eng.block_size)
         prompt = rng.randint(0, 37, (20,)).astype(onp.int32)
         hs = kv_hash.chain_hashes(prompt, 4)
         assert kv_hash.prefix_key(prompt, 4, depth=2) == hs[1]
@@ -176,15 +179,15 @@ def test_spill_reattach_token_identical_and_pool_identity():
             eng.submit(rng.randint(1, 30, (16,)).astype(onp.int32),
                        1).wait()
         assert eng.metrics.prefix_evictions.value > ev0
-        spilled_blocks, spilled_bytes = eng._spill.level()
+        spilled_blocks, spilled_bytes = eng._kv.spill.level()
         assert spilled_blocks > 0 and spilled_bytes > 0
         # gauges mirror the tier's own accounting
         assert int(eng.metrics.kv_spill_blocks.get()) == spilled_blocks
         assert int(eng.metrics.kv_spill_bytes.get()) == spilled_bytes
         # pool identity holds while spilling: spill copies live in host
         # RAM, they never consume (or free) HBM pool blocks
-        in_use = eng.num_blocks - len(eng._free)
-        assert in_use == sum(1 for v in eng._ref.values() if v > 0)
+        in_use = eng.num_blocks - eng._kv.free_blocks
+        assert in_use == sum(1 for v in eng._kv.ref.values() if v > 0)
         r0 = _counter("llm_kv_reattach_total", {"tier": "host"})
         resumed = list(eng.submit(prompt, 5).wait())
         assert _counter("llm_kv_reattach_total", {"tier": "host"}) > r0
@@ -213,11 +216,11 @@ def test_spill_survives_engine_fault_reset():
         for _ in range(10):
             eng.submit(rng.randint(1, 30, (16,)).astype(onp.int32),
                        1).wait()
-        assert eng._spill.level()[0] > 0
+        assert eng._kv.spill.level()[0] > 0
         with eng._state_lock:
             assert eng._fault_locked(TransientError("drill"))
-        assert len(eng._prefix) == 0          # HBM cache reset
-        assert eng._spill.level()[0] > 0      # spill tier survived
+        assert len(eng._kv.prefix) == 0          # HBM cache reset
+        assert eng._kv.spill.level()[0] > 0      # spill tier survived
         r0 = _counter("llm_kv_reattach_total", {"tier": "host"})
         assert list(eng.submit(prompt, 4).wait()) == first
         assert _counter("llm_kv_reattach_total", {"tier": "host"}) > r0
@@ -251,7 +254,7 @@ def test_remote_spill_fetch_reattaches_and_garble_falls_back():
         for _ in range(10):
             a.submit(rng.randint(1, 30, (16,)).astype(onp.int32),
                      1).wait()
-        assert a._spill.level()[0] > 0
+        assert a._kv.spill.level()[0] > 0
         assert a.kv_spill_endpoint is not None
         b = _engine(prefix_cache=True, kv_spill=True,
                     kv_spill_peers=[a.kv_spill_endpoint])
@@ -274,7 +277,7 @@ def test_remote_spill_fetch_reattaches_and_garble_falls_back():
                 wall = time.monotonic() - t0
             assert got == first
             assert wall < 30.0, f"garble fallback took {wall:.1f}s"
-            assert c._spill.stats()["remote_errors"] > 0
+            assert c._kv.spill.stats()["remote_errors"] > 0
         finally:
             c.close()
     finally:

@@ -338,7 +338,7 @@ def test_no_retrace_across_sequence_lengths():
     net = _tiny_lm(seed=6)
     with _engine(net) as eng:
         eng.warmup(prompt_lengths=[3, 5, 9])
-        decode_jit = eng._decode_run._plain
+        decode_jit = eng._decode.run._plain
         assert decode_jit is not None and decode_jit._cache_size() == 1
         compiles0 = eng.stats()["counters"]["compiles"]
         rng = onp.random.RandomState(7)
@@ -449,7 +449,7 @@ def test_scheduler_fatal_typed_and_engine_stops():
         def boom(*a, **k):
             raise ValueError("scheduler bug")  # classifier: FATAL
 
-        eng._decode_run = boom
+        eng._decode.run = boom
         h = eng.submit(onp.array([1, 2, 3], onp.int32), 6)
         with pytest.raises(FatalError):
             h.wait(timeout=120)
@@ -843,77 +843,6 @@ def test_spec_plus_prefix_combined_token_identity():
         assert st["speculative"]["proposed"] > 0
 
 
-# ---------------------------------------------------------------------------
-# ISSUE 11: fused Pallas decode step
-# ---------------------------------------------------------------------------
-@pytest.mark.seed(58)
-def test_fused_decode_engine_token_identical(monkeypatch):
-    """The fused QKV/attend/out-proj kernel path (forced on; interpret
-    mode on CPU) serves greedy tokens identical to offline generate()
-    — the interpret-mode oracle the cost-model gate relies on."""
-    monkeypatch.setenv("MXNET_TPU_LLM_FUSED_DECODE", "1")
-    net = _tiny_lm(seed=35)
-    with _engine(net) as eng:
-        from mxnet_tpu.ops.pallas.fused_decode import fused_decode_armed
-
-        assert fused_decode_armed()
-        for p_len, n_new in ((4, 5), (3, 6)):
-            prompt = onp.arange(1, p_len + 1, dtype=onp.int32) % 37
-            ref = generate(net, prompt[None], max_new_tokens=n_new,
-                           greedy=True).asnumpy()[0]
-            onp.testing.assert_array_equal(
-                onp.asarray(eng.generate(prompt, n_new)), ref)
-
-
-@pytest.mark.seed(59)
-def test_fused_decode_int8_pool_close_to_unfused(monkeypatch):
-    """Fused int8: the in-kernel quantize + in-kernel dequant round
-    trip must match the unfused int8 path numerically (same layout,
-    same math) on one decode step."""
-    import jax.numpy as jnp
-
-    from mxnet_tpu import numpy as mxnp
-
-    net = _tiny_lm(seed=36)
-    pk, pv = net.init_block_pool(9, 4, dtype="int8")
-    toks = mxnp.array(onp.array([[7], [11]], onp.int32))
-    bt = mxnp.array(onp.array([[0, 1, 8, 8], [2, 3, 8, 8]], onp.int32))
-    pos = mxnp.array(onp.array([2, 5], onp.int32))
-    from mxnet_tpu.ops.nn import kv_cache_dequantize, kv_pool_heads
-
-    monkeypatch.setenv("MXNET_TPU_LLM_FUSED_DECODE", "0")
-    ref_lg, ref_pk, _ = net.decode_step_paged(toks, pk, pv, bt, pos)
-    monkeypatch.setenv("MXNET_TPU_LLM_FUSED_DECODE", "1")
-    got_lg, got_pk, _ = net.decode_step_paged(toks, pk, pv, bt, pos)
-    onp.testing.assert_allclose(got_lg.asnumpy(), ref_lg.asnumpy(),
-                                rtol=2e-4, atol=2e-4)
-    # same bitcast-scale layout, same quantizer math: the DEQUANTIZED
-    # pools agree to quantization-step tolerance (bit-identity is not
-    # guaranteed — the fused projection's fp association can flip
-    # near-tie roundings)
-    ref_vals = onp.asarray(kv_cache_dequantize(
-        kv_pool_heads(jnp.asarray(ref_pk.asnumpy()), 4), jnp.float32))
-    got_vals = onp.asarray(kv_cache_dequantize(
-        kv_pool_heads(jnp.asarray(got_pk.asnumpy()), 4), jnp.float32))
-    onp.testing.assert_allclose(got_vals, ref_vals, rtol=0.1, atol=0.05)
-
-
-def test_fused_gate_is_the_env_knob_alone(monkeypatch):
-    """The fused trio is unarmed unless the knob asks for it — no
-    backend probe, no cost model — and never inside ``no_pallas``."""
-    from mxnet_tpu.ops.nn import no_pallas
-    from mxnet_tpu.ops.pallas.fused_decode import fused_decode_armed
-
-    monkeypatch.delenv("MXNET_TPU_LLM_FUSED_DECODE", raising=False)
-    assert fused_decode_armed() is False
-    monkeypatch.setenv("MXNET_TPU_LLM_FUSED_DECODE", "0")
-    assert fused_decode_armed() is False
-    monkeypatch.setenv("MXNET_TPU_LLM_FUSED_DECODE", "1")
-    assert fused_decode_armed() is True
-    with no_pallas():
-        assert fused_decode_armed() is False
-
-
 def test_kv_quantizer_layout_is_values_then_the_scale_bytes():
     """``kv_cache_quantize`` builds the scale's four bytes with integer
     ops (what Mosaic accepts inside the kernels): they must be the f32's
@@ -1026,7 +955,7 @@ def test_deadline_retires_expired_lane_mid_decode():
         assert eng.metrics.counters()["retired_deadline"] == 1
         # the lane and its blocks came back: the engine keeps serving
         assert len(eng.generate([5, 6], 3, timeout_ms=None)) == 3
-        assert len(eng._free) == eng.num_blocks
+        assert eng._kv.free_blocks == eng.num_blocks
     finally:
         eng.close()
 
@@ -1046,7 +975,7 @@ def test_cancel_retires_lane_and_frees_blocks():
             with pytest.raises(RequestCancelled):
                 h.wait(timeout=120)
         assert eng.metrics.counters()["cancelled"] == 1
-        assert len(eng._free) == eng.num_blocks
+        assert eng._kv.free_blocks == eng.num_blocks
         assert len(eng.generate([5, 6], 3, timeout_ms=None)) == 3
     finally:
         eng.close()

@@ -145,8 +145,21 @@ def test_grad_through_rewrite():
     g1x, g1w = jax.grad(lambda x, w: f(x, w).sum(), argnums=(0, 1))(x, w)
     g2x, g2w = jax.grad(lambda x, w: f2(x, w).sum(), argnums=(0, 1))(x, w)
     assert g1x.shape == g2x.shape and g1w.shape == g2w.shape
-    assert onp.allclose(g1x, g2x, rtol=2e-5, atol=1e-6)
-    assert onp.allclose(g1w, g2w, rtol=2e-5, atol=1e-6)
+    # Each gradient is a dot over 520 float32 products, and the padded
+    # operands have another shape, so the backend accumulates them in
+    # another order: the plain and the rewritten gradient each lie 3-7e-6
+    # from the float64 one (entries up to 8.6), and as far from each
+    # other. Hold both to the float64 gradient within the error a sum of
+    # n terms has, sqrt(n) * eps * the largest entry: a rewrite that
+    # dropped or moved a column is off by 1e-2 and more.
+    tx, tw = jax.grad(lambda x, w: f(x, w).sum(), argnums=(0, 1))(
+        x.astype(jnp.float64), w.astype(jnp.float64))
+    eps = float(jnp.finfo(jnp.float32).eps)
+    for true, plain, rewritten in ((tx, g1x, g2x), (tw, g1w, g2w)):
+        atol = 520 ** 0.5 * eps * float(jnp.abs(true).max())
+        assert plain.dtype == rewritten.dtype == jnp.float32
+        assert onp.allclose(plain, true, rtol=2e-5, atol=atol)
+        assert onp.allclose(rewritten, true, rtol=2e-5, atol=atol)
 
 
 def test_custom_vjp_rule_survives_rewrite():

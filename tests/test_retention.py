@@ -220,15 +220,15 @@ def test_stats_reads_no_live_pool(net):
     array raised on the chip, in one run of ten of the cell)."""
     eng = _engine(net)
     try:
-        want = eng._pool_bytes_per_device()
+        want = eng._kv.bytes_per_device()
         with eng._state_lock:
-            pools = eng._pool_k, eng._pool_v
-            eng._pool_k = eng._pool_v = None    # as good as deleted
+            pools = eng._kv.pools
+            eng._kv.pools = None                # as good as deleted
             try:
                 assert eng.stats()["pool_blocks_total"] == 3
                 assert int(eng.metrics.shard_pool_bytes.get()) == want
             finally:
-                eng._pool_k, eng._pool_v = pools
+                eng._kv.pools = pools
     finally:
         eng.close()
 

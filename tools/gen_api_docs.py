@@ -112,6 +112,9 @@ MODULES = {
                              "KV block pool, prefill/decode split, "
                              "in-flight admission, speculative decode, "
                              "shared-prefix block caching",
+    "mxnet_tpu.serving.kv_cache": "the KV-cache manager under LLMEngine: "
+                                  "pools, free list, refcounts, prefix "
+                                  "index, eviction, spill and re-attach",
     "mxnet_tpu.serving.kv_hash": "the one chain-hash discipline shared "
                                  "by the prefix cache, prefix-affinity "
                                  "routing and the KV spill tiers",
