@@ -181,8 +181,8 @@ class SoftmaxCrossEntropyLoss(Loss):
         axis = self._axis if self._axis >= 0 else pred.ndim + self._axis
         if (self._sparse_label and not self._from_logits
                 and axis == pred.ndim - 1):
-            # fused path: lse - picked in one pass (Pallas on TPU) instead
-            # of materializing log_softmax over the class axis; out-of-range
+            # fused path: lse - picked from two jitted programs instead of
+            # materializing log_softmax over the class axis; out-of-range
             # labels clip, matching npx.pick's default mode on the old path
             n_cls = pred.shape[-1]
             nll = npx.softmax_cross_entropy(

@@ -196,8 +196,8 @@ def log_softmax(x, axis=-1, temperature=None):
 
 
 def softmax_cross_entropy(data, label, per_example=False):
-    """Sparse-label CE over (N, V) logits — Pallas single-pass lse on TPU
-    (ops/pallas/cross_entropy.py); reference loss_binary_op.cc contract."""
+    """Sparse-label CE over (N, V) logits: one jitted forward and one
+    jitted pullback (ops/nn.py); reference loss_binary_op.cc contract."""
     return _call(
         lambda d, l: _nn.softmax_cross_entropy(d, l, per_example=per_example),
         (data, label), name="softmax_cross_entropy")

@@ -684,51 +684,79 @@ def softmax_cross_entropy(data, label, per_example=False):
     matches the reference contract: a shape-(1,) SUM over rows of
     ``-log(max(softmax(data)[i, label[i]], 1e-8))``
     (loss_binary_op-inl.h:44-57). ``per_example=True`` returns the
-    unclamped per-row NLL instead (the gluon-loss building block).
+    unclamped per-row NLL instead (the gluon-loss building block). Rows
+    with a negative label contribute 0 (ignore-index).
 
-    On TPU the row reduction is the single-pass Pallas online-lse kernel
-    (ops/pallas/cross_entropy.py) — the logits stream HBM→VMEM once,
-    instead of the reference's materialized-softmax workspace or XLA's
-    two-pass max+sumexp lowering. Elsewhere: fused XLA lse. Rows with a
-    negative label contribute 0 (ignore-index).
+    Two programs whoever calls: one jitted forward ``(data, label) ->
+    (loss, lse)`` and one jitted pullback ``(data, label, lse, g) ->
+    dlogits``, the rules of one ``custom_vjp``. An eager caller
+    (``apply_op`` jits nothing) launches exactly these, and the only new
+    (N, V) array is ``dlogits``; inside a hybridized block or under a
+    mesh they are inlined into the caller's program.
     """
     if data.ndim != 2 or label.ndim != 1:
         raise ValueError(
             f"softmax_cross_entropy expects (N, V) data and (N,) label, "
             f"got {data.shape} / {label.shape}")
-    lab = label.astype(jnp.int32)
-    if _tpu_kernels_selected():
-        from .pallas.cross_entropy import cross_entropy_with_logits
-        nll = cross_entropy_with_logits(data, lab)
-    else:
-        x = data.astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(x, axis=-1)
-        picked = jnp.take_along_axis(x, jnp.clip(lab, 0, None)[:, None],
-                                     axis=-1)[:, 0]
-        nll = jnp.where(lab >= 0, lse - picked, 0.0)
+    return _softmax_ce(data, label.astype(jnp.int32), bool(per_example))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _softmax_ce(data, lab, per_example):
+    return _ce_forward(data, lab, per_example)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _ce_forward(data, lab, per_example):
+    """The forward program: ``(loss, lse)`` in two passes over ``data``
+    and nothing else shaped (N, V). The row reduction is XLA's
+    ``logsumexp`` (a max pass, then an exp-sum pass); the label's logit is
+    a masked row sum, which fuses into the second pass, needs no gather
+    and splits over a sharded vocabulary. No Pallas kernel: the chip keeps
+    an (8192, 50257) array column-major, a kernel would first copy all of
+    it into rows, and XLA's two passes over the array as it lies are
+    faster than that copy (PERF.md section 6, PR 32)."""
+    x = data.astype(jnp.float32)
+    lse = jax.scipy.special.logsumexp(x, axis=-1)
+    hit = lax.broadcasted_iota(jnp.int32, x.shape, 1) == lab[:, None]
+    nll = jnp.where(lab >= 0, lse - jnp.sum(jnp.where(hit, x, 0.0), axis=-1),
+                    0.0)
     if per_example:
-        return nll  # f32: per-row NLL keeps full precision for reductions
-    # value-only clamp: the reference backward (loss_binary_op-inl.h:85-106)
-    # is softmax-onehot UNCONDITIONALLY — the forward's 1e-8 floor must not
-    # zero dlogits on confidently-wrong rows
-    nll = _clamp_value_only(nll)
-    return jnp.sum(nll, keepdims=True).astype(data.dtype)
+        return nll, lse  # f32: per-row NLL keeps full precision
+    # the reference's 1e-8 floor, in the value only (see _ce_backward); a
+    # masked label (prob exactly 0, nll=+inf) reads the finite cap
+    nll = jnp.minimum(nll, -jnp.log(jnp.float32(1e-8)))
+    return jnp.sum(nll, keepdims=True).astype(data.dtype), lse
 
 
-@jax.custom_vjp
-def _clamp_value_only(nll):
-    """min(nll, -log(1e-8)) in the value, identity in the gradient.
+@jax.jit
+def _ce_backward(data, lab, lse, g):
+    """The pullback program: ``(softmax - onehot) * g`` from the saved
+    lse, which XLA fuses into one pass that reads ``data`` and writes
+    ``dlogits`` in its dtype; the iota, the comparison and the float32
+    softmax never reach HBM. ``g`` is (1,) for the sum and (N,) per row.
 
-    A custom_vjp rather than a stop_gradient straight-through: a masked
-    label (softmax prob exactly 0, nll=+inf — the very case the 1e-8
-    floor exists for) would make ``nll + sg(min(nll, cap) - nll)``
-    evaluate inf-inf = NaN; here the forward is a plain minimum and the
-    backward never touches the forward value."""
-    return jnp.minimum(nll, -jnp.log(jnp.float32(1e-8)))
+    The reference backward (loss_binary_op-inl.h:85-106) is
+    softmax-onehot UNCONDITIONALLY: the forward's 1e-8 floor is the
+    identity here and must not zero dlogits on confidently-wrong rows."""
+    p = jnp.exp(data.astype(jnp.float32) - lse[:, None])
+    hit = lax.broadcasted_iota(jnp.int32, data.shape, 1) == lab[:, None]
+    gr = jnp.where(lab >= 0, g.astype(jnp.float32), 0.0)
+    return ((p - hit.astype(jnp.float32)) * gr[:, None]).astype(data.dtype)
 
 
-_clamp_value_only.defvjp(
-    lambda nll: (_clamp_value_only(nll), None), lambda _, g: (g,))
+def _softmax_ce_fwd(data, lab, per_example):
+    out, lse = _ce_forward(data, lab, per_example)
+    return out, (data, lab, lse)
+
+
+def _softmax_ce_bwd(per_example, res, g):
+    data, lab, lse = res
+    return (_ce_backward(data, lab, lse, g),
+            onp.zeros(lab.shape, jax.dtypes.float0))
+
+
+_softmax_ce.defvjp(_softmax_ce_fwd, _softmax_ce_bwd)
 
 
 def masked_softmax(x, mask, axis=-1, temperature=1.0):

@@ -1,6 +1,7 @@
-"""Pallas fused LayerNorm / RMSNorm — the third of SURVEY §7's named
-Pallas targets (softmax → cross_entropy.py, attention →
-flash_attention.py, norm → here).
+"""Pallas fused LayerNorm / RMSNorm — one of SURVEY §7's named Pallas
+targets (attention → flash_attention.py, norm → here; the softmax
+family's row reduction is XLA's since PR 32: ops/nn.py
+``softmax_cross_entropy``).
 
 The reference computes LayerNorm as a multi-kernel sequence
 (src/operator/nn/layer_norm.cc: mean reduce, variance reduce, then the

@@ -65,6 +65,9 @@ def on_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
+HLO_DTYPE = {"float32": "f32", "bfloat16": "bf16", "int8": "s8"}
+
+
 def _compile(fn, args, one_chip, donate=()):
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
@@ -185,6 +188,15 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache, on_tpu):
     assert jax.config.jax_enable_x64      # base.py's setting is in force
     fn, args = KERNELS[case]()
     compiled = _compile(fn, args, one_chip)
+    if case.startswith("cross-entropy"):
+        # no kernel since PR 32: the loss and its gradient in one program
+        # hold the logits, ``dlogits`` and next to nothing beside them
+        mem = compiled.memory_analysis()
+        assert "tpu_custom_call" not in compiled.as_text(), case
+        assert mem.temp_size_in_bytes < 64 * 2 ** 20, case
+        assert mem.output_size_in_bytes < args[0].size * args[
+            0].dtype.itemsize + 2 ** 20, case
+        return
     assert "tpu_custom_call" in compiled.as_text(), case
     if case.startswith("paged"):
         _, grid, scratch = _paged_scratch(fn, args)
@@ -192,6 +204,64 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache, on_tpu):
         assert onp.prod(grid) <= R * MB // 4, grid     # blocks in groups
     if "fwd-bwd" in case:                 # the Pallas backward, not the scan
         assert compiled.as_text().count("tpu_custom_call") >= 3, case
+
+
+# --- the loss's two programs, alone ----------------------------------------
+def _nv_shaped(text, dtype):
+    """The ``pad`` and ``copy`` operations of a compiled program whose
+    result has the logits' shape, as it is or padded to whole blocks."""
+    shapes = [f" = {HLO_DTYPE[dtype]}[{8 * L},{v}]"
+              for v in (V, -(-V // 2048) * 2048)]
+    return [line.strip()[:160] for line in text.splitlines()
+            if any(sh in line for sh in shapes)
+            and (" pad(" in line or " copy(" in line)]
+
+
+@pytest.mark.parametrize("per_example", [False, True], ids=["sum", "rows"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_loss_forward_program_for_v5e(dtype, per_example, one_chip,
+                                      no_compile_cache):
+    """The forward rule of ``softmax_cross_entropy`` at the train cell's
+    (8,192, 50,257): the program makes nothing the size of the logits, no
+    temporary, no output, no padded or transposed copy, whichever way the
+    compiler lays its parameter out (for this shape, by columns)."""
+    from mxnet_tpu.ops.nn import _ce_forward
+
+    nv = 8 * L * V * jnp.dtype(dtype).itemsize
+    compiled = _compile(
+        lambda x, lab: _ce_forward(x, lab, per_example),
+        (_s((8 * L, V), dtype), _s((8 * L,), "int32")), one_chip)
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    print(f"forward {dtype}: temporaries {mem.temp_size_in_bytes / 2**20:.1f}"
+          f" MiB, outputs {mem.output_size_in_bytes / 2**20:.3f} MiB")
+    assert "tpu_custom_call" not in text
+    assert mem.temp_size_in_bytes < 64 * 2**20 < nv
+    assert mem.output_size_in_bytes < 2**20
+    assert not _nv_shaped(text, dtype)
+
+
+@pytest.mark.parametrize("g_rows", [1, 8 * L], ids=["sum", "rows"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_loss_backward_program_for_v5e(dtype, g_rows, one_chip,
+                                       no_compile_cache):
+    """The pullback: one output shaped like the logits, in their dtype,
+    and under 64 MiB beside it. The iota, the comparison and the float32
+    softmax are fused away, so no second (N, V) array exists."""
+    from mxnet_tpu.ops.nn import _ce_backward
+
+    nv = 8 * L * V * jnp.dtype(dtype).itemsize
+    g_dtype = dtype if g_rows == 1 else "float32"
+    compiled = _compile(
+        _ce_backward,
+        (_s((8 * L, V), dtype), _s((8 * L,), "int32"),
+         _s((8 * L,), "float32"), _s((g_rows,), g_dtype)), one_chip)
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    print(f"backward {dtype}: temporaries "
+          f"{mem.temp_size_in_bytes / 2**20:.1f} MiB, outputs "
+          f"{mem.output_size_in_bytes / 2**20:.1f} MiB of {nv / 2**20:.1f}")
+    assert mem.temp_size_in_bytes < 64 * 2**20
+    assert nv <= mem.output_size_in_bytes < nv + 2**20
+    assert not _nv_shaped(text, dtype)
 
 
 # --- whole programs, 2 layers at full width --------------------------------
@@ -208,8 +278,8 @@ def lm():
 def test_train_step_program_compiles_for_v5e(lm, one_chip,
                                              no_compile_cache, on_tpu):
     """Forward, loss and backward of the causal LM on a batch of
-    8 x 1024: the flash, layer-norm and cross-entropy kernels inside one
-    program, as the hybridized trainer step holds them."""
+    8 x 1024: the flash and layer-norm kernels and the loss's two
+    programs inside one program, as a hybridized trainer step holds them."""
     import mxnet_tpu as mx
     from mxnet_tpu.ops.nn import softmax_cross_entropy
 
@@ -224,8 +294,8 @@ def test_train_step_program_compiles_for_v5e(lm, one_chip,
         jax.value_and_grad(loss),
         (params, _s((8, L), "int32"), _s((8 * L,), "int32"),
          _s((2,), "uint32")), one_chip)
-    # per layer: flash fwd + dq + dkv, two norms; then final norm and CE
-    assert compiled.as_text().count("tpu_custom_call") >= 2 * 5 + 2
+    # per layer: flash fwd + dq + dkv, two norms; then the final norm
+    assert compiled.as_text().count("tpu_custom_call") >= 2 * 5 + 1
 
 
 def _pool_report(compiled, pool, label):
@@ -235,7 +305,7 @@ def _pool_report(compiled, pool, label):
     pool_bytes = 2 * int(onp.prod(pool.shape)) * pool.dtype.itemsize
     temp = compiled.memory_analysis().temp_size_in_bytes
     dims = ",".join(map(str, pool.shape))
-    short = {"bfloat16": "bf16", "int8": "s8"}[pool.dtype.name]
+    short = HLO_DTYPE[pool.dtype.name]
     copies = [line.strip()[:160] for line in compiled.as_text().splitlines()
               if f" = {short}[{dims}]" in line and " copy(" in line]
     print(f"{label}: pools {pool_bytes / 2**20:.1f} MiB, temporaries "
