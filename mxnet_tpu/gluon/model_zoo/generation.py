@@ -38,7 +38,14 @@ class CacheGeometry:
 
     ``kind``: ``"kv_blocks"`` — blocks of ``block_size`` K/V rows, a
     request holds as many as its tokens fill — or ``"state_slots"`` — a
-    block is one request's whole recurrent state. ``blocks_for(tokens)``:
+    block is one request's whole recurrent state. ``lane_state``: beside
+    its blocks the model keeps a state that every lane holds for its
+    whole life — ``init_block_pool(..., state_slots=)`` then returns the
+    pools the blocks index first (K, V) and after them the pools the
+    lane's own index does; the programs take and give back all of them,
+    the chunk program also the lane's slot and table, and a slot handed
+    to a new request starts from zero inside its first chunk's program.
+    ``blocks_for(tokens)``:
     the pool blocks a request of that many tokens reserves (which is also
     the width of a lane's block table, at ``max_context``).
     ``max_positions``: the model's context window, or None.
@@ -54,6 +61,7 @@ class CacheGeometry:
     prefill_chunk: Optional[int] = None
     cache_dtypes: Optional[tuple] = None
     unsupported: dict = dataclasses.field(default_factory=dict)
+    lane_state: bool = False
 
 
 class _StepAdapter(HybridBlock):
@@ -75,9 +83,8 @@ class _PagedStepAdapter(HybridBlock):
         super().__init__()
         self.model = model
 
-    def forward(self, tokens, pool_k, pool_v, block_table, positions):
-        return self.model.decode_step_paged(tokens, pool_k, pool_v,
-                                            block_table, positions)
+    def forward(self, tokens, *pools_table_positions):
+        return self.model.decode_step_paged(tokens, *pools_table_positions)
 
 
 _DECODE_CACHE_MAX = 32
@@ -418,6 +425,15 @@ def _paged_jit(fn, label, donate, store):
                                 donate_argnums=donate))
 
 
+def _with_counts(tokens, counts):
+    """The sampled tokens and, behind them, what the model's step counted
+    on the device (none, or one int32 vector): one array, one fetch."""
+    if not counts:
+        return tokens
+    return jnp.concatenate([jnp.reshape(tokens, (-1,)),
+                            counts[0].astype(jnp.int32)])
+
+
 def paged_decode_program(model, *, max_running, num_blocks, block_size,
                          max_blocks_per_seq, kv_cache_dtype=None,
                          weight_dtype=None, greedy=True, temperature=1.0,
@@ -427,7 +443,11 @@ def paged_decode_program(model, *, max_running, num_blocks, block_size,
 
     Returns ``(run, params)``: ``run(params, tokens (R,1) i32, pool_k,
     pool_v, block_table (R,MB) i32, positions (R,) i32, key) ->
-    (next_tokens (R,) i32, new_pool_k, new_pool_v)``. Lane ``r``'s token
+    (next_tokens (R,) i32, new_pool_k, new_pool_v)`` — with however many
+    pools the model's ``init_block_pool`` returns in the pair's place,
+    and, where the model's step returns counters between its logits and
+    its pools (int32, made on the device), those behind the tokens in
+    the one array the host fetches. Lane ``r``'s token
     is written at ``positions[r]`` through its block table, attended
     through the pool, and sampled (greedy argmax by default). Inactive
     lanes must point at a trash block — their outputs are garbage the
@@ -447,13 +467,14 @@ def paged_decode_program(model, *, max_running, num_blocks, block_size,
     # pool, so a 2-block template avoids transiently holding a second
     # full-size pool (which for an HBM-sized pool would double KV
     # memory at engine startup)
-    pk, pv = model.init_block_pool(min(int(num_blocks), 2), block_size,
+    pools0 = model.init_block_pool(min(int(num_blocks), 2), block_size,
                                    dtype=cache_dtype)
+    n = len(pools0)
     tokens0 = mxnp.array(onp.zeros((r, 1), onp.int32))
     bt0 = mxnp.array(onp.zeros((r, mb), onp.int32))
     pos0 = mxnp.array(onp.zeros((r,), onp.int32))
     adapter = _PagedStepAdapter(model)
-    step_fn, params = adapter.functionalize(tokens0, pk, pv, bt0, pos0)
+    step_fn, params = adapter.functionalize(tokens0, *pools0, bt0, pos0)
     step_fn, params = _apply_weight_dtype(model, step_fn, params,
                                           weight_dtype)
     tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
@@ -463,13 +484,15 @@ def paged_decode_program(model, *, max_running, num_blocks, block_size,
     if cached is not None:
         return cached, params
 
-    def run(params, tokens, pool_k, pool_v, block_table, positions, key):
-        (logits, pool_k, pool_v), _ = step_fn(
-            params, tokens, pool_k, pool_v, block_table, positions)
-        nxt = _sample(logits[:, -1], key, greedy, temperature, top_k)
-        return nxt, pool_k, pool_v
+    def run(params, tokens, *pools_table_positions_key):
+        (logits, *counts_pools), _ = step_fn(
+            params, tokens, *pools_table_positions_key[:-1])
+        nxt = _sample(logits[:, -1], pools_table_positions_key[-1], greedy,
+                      temperature, top_k)
+        return (_with_counts(nxt, counts_pools[:-n]), *counts_pools[-n:])
 
-    jrun = _paged_jit(run, "llm.decode", (2, 3) if donate else (), store)
+    jrun = _paged_jit(run, "llm.decode",
+                      tuple(range(2, 2 + n)) if donate else (), store)
     return jrun, params
 
 
@@ -549,15 +572,16 @@ class _ChunkStepAdapter(HybridBlock):
         super().__init__()
         self.model = model
 
-    def forward(self, tokens, pool_s, pool_z, slot, start, n_real):
-        return self.model.prefill_chunk_step(tokens, pool_s, pool_z, slot,
-                                             start, n_real)
+    def forward(self, tokens, *pools_where_start_n):
+        return self.model.prefill_chunk_step(tokens, *pools_where_start_n)
 
 
-def state_prefill_program(model, *, chunk, num_blocks, greedy=True,
-                          temperature=1.0, top_k=0, donate=False):
-    """Build (or fetch memoized) the ONE prefill program of a model whose
-    cache is a state (``CacheGeometry.kind == "state_slots"``): a chunk
+def state_prefill_program(model, *, chunk, num_blocks, block_size=0,
+                          max_blocks_per_seq=None, kv_cache_dtype=None,
+                          greedy=True, temperature=1.0, top_k=0,
+                          donate=False):
+    """Build (or fetch memoized) the ONE prefill program of a model that
+    carries a state (``CacheGeometry.prefill_chunk``): a chunk
     of ``chunk`` tokens of one lane that carries the lane's state, so a
     prompt of any length is a loop over it — no length buckets, one
     warm-up shape.
@@ -570,31 +594,50 @@ def state_prefill_program(model, *, chunk, num_blocks, greedy=True,
     The slot counts as zero where ``start == 0`` — a slot handed to a new
     request needs no clearing. ``next_token`` is sampled from the logits
     of the last real row alone (it is the request's first token after its
-    last chunk, and means nothing before)."""
+    last chunk, and means nothing before).
+
+    A model that keeps its state *beside* blocks of K/V rows
+    (``CacheGeometry.lane_state``; ``max_blocks_per_seq`` given) has all
+    its pools where the two stand, and its chunk also writes the rows of
+    its tokens through the lane's table: ``run(params, tokens, start,
+    n_real, *pools, slot, table (MB,) i32, key)``. Counters the step
+    returns come behind the token, as in :func:`paged_decode_program`."""
     from ... import numpy as mxnp
 
     cc = int(chunk)
-    ps, pz = model.init_block_pool(min(int(num_blocks), 2), 0)
+    where = [mxnp.array(onp.zeros((), onp.int32))]      # the slot
+    if max_blocks_per_seq is None:
+        pools0 = model.init_block_pool(min(int(num_blocks), 2), 0)
+        ckey = ()
+    else:
+        cache_dtype = _resolve_cache_dtype(model, kv_cache_dtype)
+        pools0 = model.init_block_pool(min(int(num_blocks), 2),
+                                       int(block_size), dtype=cache_dtype)
+        where.append(mxnp.array(onp.zeros((int(max_blocks_per_seq),),
+                                          onp.int32)))
+        ckey = (int(block_size), int(max_blocks_per_seq), cache_dtype)
+    n, w = len(pools0), len(where)
     tokens0 = mxnp.array(onp.zeros((1, cc), onp.int32))
     zero = mxnp.array(onp.zeros((), onp.int32))
     adapter = _ChunkStepAdapter(model)
-    step_fn, params = adapter.functionalize(tokens0, ps, pz, zero, zero,
+    step_fn, params = adapter.functionalize(tokens0, *pools0, *where, zero,
                                             zero)
     tkey = (0.0, 0) if greedy else (float(temperature), int(top_k))
     ckey = ("state_prefill", cc, int(num_blocks), bool(greedy), *tkey,
-            bool(donate))
+            bool(donate), *ckey)
     store, cached = _decode_cache(model, ckey)
     if cached is not None:
         return cached, params
 
-    def run(params, tokens, start, n_real, pool_s, pool_z, slot, key):
-        (logits, pool_s, pool_z), _ = step_fn(
-            params, tokens, pool_s, pool_z, slot, start, n_real)
-        nxt = _sample(logits, key, greedy, temperature, top_k)[0]
-        return nxt, pool_s, pool_z
+    def run(params, tokens, start, n_real, *pools_where_key):
+        (logits, *counts_pools), _ = step_fn(
+            params, tokens, *pools_where_key[:n + w], start, n_real)
+        nxt = _sample(logits, pools_where_key[-1], greedy, temperature,
+                      top_k)[0]
+        return (_with_counts(nxt, counts_pools[:-n]), *counts_pools[-n:])
 
-    jrun = _paged_jit(run, "llm.prefill_chunk", (4, 5) if donate else (),
-                      store)
+    jrun = _paged_jit(run, "llm.prefill_chunk",
+                      tuple(range(4, 4 + n)) if donate else (), store)
     return jrun, params
 
 

@@ -1088,6 +1088,9 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths, layer=0,
 
         return paged_attention_kernel(q, k_pool, v_pool, block_table,
                                       lengths, layer)
+    if k_pool.dtype != jnp.int8 and k_pool.shape[-1] != h * d:
+        return _paged_attention_grouped(q, k_pool, v_pool, block_table,
+                                        lengths, layer)
     keys = _gather_lanes(k_pool, layer, block_table, h, q.dtype)
     vals = _gather_lanes(v_pool, layer, block_table, h, q.dtype)
     # the dense MultiHeadAttention.forward_step arithmetic with T=1 and
@@ -1101,6 +1104,32 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths, layer=0,
     scores = jnp.where(live[:, None, :], scores, -jnp.inf)
     attn = jax.nn.softmax(scores, axis=-1).astype(vals.dtype)
     return jnp.einsum("rhl,rhld->rhd", attn, vals)
+
+
+def _paged_attention_grouped(q, k_pool, v_pool, block_table, lengths,
+                             layer):
+    """The jnp path of :func:`paged_attention` for grouped K/V heads:
+    pool rows hold ``Hkv = row / D`` heads and query head ``i`` reads K/V
+    head ``i // (H / Hkv)``. Scores and sums in float32, the weights
+    cast to the values' dtype as in the ungrouped path."""
+    r, h, d = q.shape
+    hkv = k_pool.shape[-1] // d
+    if hkv < 1 or h % hkv or hkv * d != k_pool.shape[-1]:
+        raise ValueError(f"{h} query heads of {d} do not divide over pool "
+                         f"rows of {k_pool.shape[-1]}")
+    keys = _gather_lanes(k_pool, layer, block_table, hkv, q.dtype)
+    vals = _gather_lanes(v_pool, layer, block_table, hkv, q.dtype)
+    qg = q.reshape(r, hkv, h // hkv, d)
+    scores = jnp.einsum("rjgd,rjld->rjgl", qg, keys,
+                        preferred_element_type=jnp.float32)
+    scores = scores / onp.sqrt(d).astype(onp.float32)
+    live = jnp.arange(keys.shape[2])[None, :] \
+        < lengths[:, None].astype(jnp.int32)
+    scores = jnp.where(live[:, None, None, :], scores, -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1).astype(vals.dtype)
+    out = jnp.einsum("rjgl,rjld->rjgd", attn, vals,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(r, h, d).astype(vals.dtype)
 
 
 def paged_attention_multi(q, k_pool, v_pool, block_table, positions,

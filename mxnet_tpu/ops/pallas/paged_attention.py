@@ -124,7 +124,7 @@ def _group_blocks(bs, mb, hdp, hd, pool_dtype):
 
 def _paged_kernel(bt_ref, len_ref, layer_ref, q_ref, *rest, bs, mb, g,
                   heads, d, hr, quantized, sm_scale, precision, q_parts,
-                  mm, by_hand):
+                  mm, by_hand, share=1):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -217,8 +217,11 @@ def _paged_kernel(bt_ref, len_ref, layer_ref, q_ref, *rest, bs, mb, g,
                                    preferred_element_type=f32)
 
     def of_head(shape, first_row):
-        """0/1: column ``c`` belongs to head ``row - first_row``."""
+        """0/1: column ``c`` belongs to (the K/V head of) head ``row -
+        first_row``."""
         h = jax.lax.broadcasted_iota(jnp.int32, shape, 0) - first_row
+        if share > 1:           # ``share`` query heads read one K/V head
+            h = h // i32(share)
         col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         return (col >= h * i32(d)) & (col < (h + 1) * i32(d))
 
@@ -227,6 +230,13 @@ def _paged_kernel(bt_ref, len_ref, layer_ref, q_ref, *rest, bs, mb, g,
         # once per lane: the query as one row per head (its low part,
         # where it has one, in the rows after the heads' ``hr``)
         q = q_ref[0].astype(f32)
+        if share > 1:
+            # grouped heads: the block is (H, D), a head a row; lay each
+            # row under every K/V head's columns, of_head keeps its own
+            q = jnp.concatenate(
+                [jnp.pad(q, ((0, hr - heads), (0, 0)))] * (heads // share),
+                axis=1)
+            q = jnp.concatenate([q] * q_parts, axis=0)
         hi = q.astype(mm).astype(f32)
         qe_ref[...] = sum(
             jnp.where(of_head(qe_ref.shape, i * hr), part, f32(0))
@@ -262,7 +272,12 @@ def _paged_kernel(bt_ref, len_ref, layer_ref, q_ref, *rest, bs, mb, g,
         inv = 1.0 / jnp.maximum(l_ref[...], 1e-30)
         own = jnp.where(of_head(acc_ref.shape, 0),
                         acc_ref[...] * inv[:, :1], f32(0))
-        o_ref[0] = jnp.sum(own, axis=0, keepdims=True).astype(o_ref.dtype)
+        if share > 1:           # a head's D columns, a head a row
+            o_ref[0] = sum(own[:heads, kv * d:(kv + 1) * d] for kv in range(
+                heads // share)).astype(o_ref.dtype)
+        else:
+            o_ref[0] = jnp.sum(own, axis=0,
+                               keepdims=True).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q, k_pool, v_pool, block_table, lengths,
@@ -309,8 +324,14 @@ def _call(q, k_pool, v_pool, block_table, lengths, layer, *, interpret):
 
     r, h, d = q.shape
     bs, hdp = k_pool.shape[2:]
-    hd = h * d
     quantized = k_pool.dtype == jnp.int8
+    # grouped K/V heads (float pools): a row holds Hkv heads of D, and
+    # query head i reads K/V head i // share
+    share = 1 if quantized else h * d // hdp
+    if not quantized and (share < 1 or h % share or h // share * d != hdp):
+        raise ValueError(f"{h} query heads of {d} do not divide over pool "
+                         f"rows of {hdp}")
+    hd = h // share * d
     mb = block_table.shape[1]
     out_dtype = q.dtype if quantized else v_pool.dtype
     # bf16 rows feed the MXU as they are, in one pass (HIGHEST on bf16 is
@@ -327,7 +348,7 @@ def _call(q, k_pool, v_pool, block_table, lengths, layer, *, interpret):
     kernel = functools.partial(
         _paged_kernel, bs=bs, mb=mb, g=g, heads=h, d=d, hr=hr,
         quantized=quantized, sm_scale=float(d) ** -0.5, q_parts=q_parts,
-        mm=mm, by_hand=by_hand,
+        mm=mm, by_hand=by_hand, share=share,
         precision=(jax.lax.Precision.DEFAULT if native
                    else jax.lax.Precision.HIGHEST))
 
@@ -348,7 +369,10 @@ def _call(q, k_pool, v_pool, block_table, lengths, layer, *, interpret):
                           i32(mb - 1))
         return ly_[0], bt_[i, col], i32(0), i32(0)
 
-    q_spec = pl.BlockSpec((1, 1, hd), lane_map)
+    # a lane's query and output: one row of all heads, or (grouped
+    # heads, whose values share columns) a row a head
+    q_shape = (1, 1, hd) if share == 1 else (1, h, d)
+    q_spec = pl.BlockSpec(q_shape, lane_map)
     scratch = [
         pltpu.VMEM((q_parts * hr, hd), mm),      # the query, a row a head
         pltpu.VMEM((hr, 128), jnp.float32),      # running max
@@ -384,10 +408,10 @@ def _call(q, k_pool, v_pool, block_table, lengths, layer, *, interpret):
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, 1, hd), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((r,) + q_shape[1:], out_dtype),
         compiler_params=compiler_params,
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      jnp.reshape(layer, (1,)).astype(jnp.int32), q.reshape(r, 1, hd),
-      *operands)
+      jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q.reshape((r,) + q_shape[1:]), *operands)
     return out.reshape(r, h, d)

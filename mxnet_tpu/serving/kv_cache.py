@@ -4,7 +4,8 @@ pools and everything that indexes them.
 The scheduler (:mod:`.llm`) knows requests, lanes, lane tables and which
 program to run; this module knows what a pool block is
 (:class:`~mxnet_tpu.gluon.model_zoo.generation.CacheGeometry`: rows in
-blocks, or one state a slot), which blocks are free, who holds each
+blocks, one state a slot, or rows in blocks with a state a lane beside
+them), which blocks are free, who holds each
 (refcounts), which hold a cached prefix (the chain-hash index, LRU),
 what to evict and where evicted rows go (the spill tiers of
 :mod:`.kv_spill`) and how they come back (re-attach). It is the only
@@ -73,14 +74,21 @@ class KVCache:
     :class:`~.llm.LLMEngine`'s arguments of those names as the caller
     gave them (None: the default — ``dtype`` is what was resolved); a
     ``"prefill"`` role exports every committed block through the tier.
-    ``pools[0]`` is the target's ``[k, v]``, ``pools[1]`` the draft's:
-    the programs take a pair and give it back (donated), so the engine's
-    call helper swaps them here. Not thread-safe: the engine's state lock
-    covers every call but :meth:`evictable`.
+    ``pools[0]`` is the target's pools as ``init_block_pool`` returns
+    them (``[k, v]``; ``[S, z]``; ``[k, v, S, tail]``), ``pools[1]`` the
+    draft's: the programs take them all and give them back (donated), so
+    the engine's call helper swaps them here. **Two families.** Where
+    ``geom.lane_state``, the pools past the first two are not indexed by
+    blocks: they have ``max_running`` slots and the trash slot, a lane's
+    slot is the lane's index, and nothing here allocates them —
+    ``reserve`` / ``commit`` / ``release`` count blocks of rows only.
+    Not thread-safe: the engine's state lock covers every call but
+    :meth:`evictable`.
     """
 
     def __init__(self, model, geom, *, num_blocks: int, block_size: int,
-                 kv_cache_dtype, metrics, draft_model=None,
+                 kv_cache_dtype, metrics, max_running: int = 0,
+                 draft_model=None,
                  prefix_cache: Optional[bool] = None,
                  kv_spill: Optional[bool] = None,
                  kv_spill_bytes: Optional[int] = None,
@@ -108,10 +116,11 @@ class KVCache:
                  "draft_model": draft_model is not None,
                  "kv_spill": bool(kv_spill),
                  "prefix_cache": bool(prefix_cache)}
+        kind = geom.kind + (" + lane state" if geom.lane_state else "")
         for feature, on in armed.items():
             if on and feature in geom.unsupported:
                 raise ValueError(
-                    f"{feature} is not supported with a {geom.kind} "
+                    f"{feature} is not supported with a {kind} "
                     f"cache: {geom.unsupported[feature]}")
         if kv_spill and not prefix_cache:
             raise ValueError(
@@ -121,13 +130,16 @@ class KVCache:
             if kv_cache_dtype not in (None, *geom.cache_dtypes):
                 raise ValueError(
                     f"kv_cache_dtype {kv_cache_dtype!r} is not supported "
-                    f"with a {geom.kind} cache: it is held as "
+                    f"with a {kind} cache: it is held as "
                     f"{'/'.join(geom.cache_dtypes)} (pass that, or None)")
             kv_cache_dtype = kv_cache_dtype or geom.cache_dtypes[0]
         self.dtype = _resolve_cache_dtype(model, kv_cache_dtype)
         self.geom = geom
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
+        # the state family's slots: a lane's own index, then the trash
+        self._lane_slots = {"state_slots": int(max_running) + 1} \
+            if geom.lane_state else {}
         self.metrics = metrics
         self.prefix_on = bool(prefix_cache)
         self._models = [model] + ([draft_model] if draft_model is not None
@@ -173,7 +185,8 @@ class KVCache:
         when they call it."""
         self.pools = [
             [self._shard_pool(p._data) for p in m.init_block_pool(
-                self.num_blocks + 1, self.block_size, dtype=self.dtype)]
+                self.num_blocks + 1, self.block_size, dtype=self.dtype,
+                **self._lane_slots)]
             for m in self._models]
         self.free: List[int] = list(range(self.num_blocks))
         self.ref.clear()
@@ -220,12 +233,15 @@ class KVCache:
                           else int(arr.nbytes))
         return total
 
-    def snapshot(self, blocks: List[int]):
+    def snapshot(self, blocks: List[int], lane: Optional[int] = None):
         """``blocks`` of the target's two pools (``pool[:, blocks]``) as
-        new device arrays."""
-        ids = onp.asarray(blocks, onp.int32)
-        k, v = self.pools[0]
-        return _pool_gather(k, ids), _pool_gather(v, ids)
+        new device arrays; where a lane holds a state beside its blocks,
+        ``lane``'s slot of the two state pools instead (the rows are what
+        the request's tokens already show)."""
+        ids = onp.asarray([lane] if self.geom.lane_state else blocks,
+                          onp.int32)
+        first, second = self.pools[0][-2:]
+        return _pool_gather(first, ids), _pool_gather(second, ids)
 
     # -- levels ------------------------------------------------------------
     @property

@@ -141,10 +141,11 @@ _PREFILL_LABELS = {(False, False): "llm.prefill",
 @dataclasses.dataclass(eq=False)
 class _Program:
     """One compiled serving program as the scheduler calls it.
-    ``prog(*host)`` is ``run(params, *host[:pool_at], k, v,
-    *host[pool_at:], key)`` with the cache manager's pool pair ``pair``
-    (0 the target's, 1 the draft's): the pools it returns go back to the
-    manager, the rest comes back as a list. Its first call records its
+    ``prog(*host)`` is ``run(params, *host[:pool_at], *pools,
+    *host[pool_at:], key)`` with the cache manager's pools ``pair``
+    (0 the target's, 1 the draft's; two or however many the model has):
+    the pools it returns go back to the manager, the rest comes back as a
+    list. Its first call records its
     warm-up manifest entry (``label``, ``bucket``); warm-up and the
     serving path call the same object, so a program's argument order is
     written at its call and nowhere else."""
@@ -161,8 +162,9 @@ class _Program:
     def __call__(self, *host):
         eng, at = self.engine, self.pool_at
         pools = eng._kv.pools[self.pair]
-        *out, pools[0], pools[1] = self.run(
+        got = self.run(
             self.params, *host[:at], *pools, *host[at:], eng._next_key())
+        out, pools[:] = list(got[:-len(pools)]), got[-len(pools):]
         if self.fresh:
             self.fresh = False
             # with the arguments as they are after the call (the pools
@@ -298,6 +300,12 @@ class LLMMetrics:
         self.queue_wait_ms = reg.histogram(
             "llm_queue_wait_ms",
             "Submission to admission into a lane (ms), prefill not in it",
+            ("engine",)).labels(**eng)
+        # a mixture of experts told which experts it holds: per program
+        # call, the fullest held expert's load over the mean load
+        self.expert_load_ratio = reg.histogram(
+            "llm_expert_load_max_over_mean",
+            "Largest over mean load of a held expert, per program call",
             ("engine",)).labels(**eng)
 
     def observe_spec(self, proposed: int, accepted: int) -> None:
@@ -578,7 +586,8 @@ class LLMEngine:
         self._kv = KVCache(
             model, geom, num_blocks=self.num_blocks,
             block_size=self.block_size, kv_cache_dtype=kv_cache_dtype,
-            metrics=self.metrics, draft_model=draft_model,
+            metrics=self.metrics, max_running=self.max_running,
+            draft_model=draft_model,
             prefix_cache=prefix_cache, kv_spill=kv_spill,
             kv_spill_bytes=kv_spill_bytes, kv_spill_dir=kv_spill_dir,
             kv_spill_serve=kv_spill_serve, kv_spill_peers=kv_spill_peers,
@@ -619,9 +628,13 @@ class LLMEngine:
         # carry it — one program, one warm-up shape
         self._chunk = geom.prefill_chunk
         if self._chunk:
+            # (a state beside blocks: the chunk also writes its rows
+            # through the lane's table, so the program is told the blocks)
             self._chunk_step = self._program(
                 state_prefill_program, "llm.prefill_chunk", self._chunk, 3,
-                chunk=self._chunk)
+                chunk=self._chunk, **(dict(
+                    self._paged, max_blocks_per_seq=self.max_blocks_per_seq)
+                    if geom.lane_state else {}))
         self._bucketed: Dict[tuple, _Program] = {}
         if self._spec:
             self._draft_step = self._program(
@@ -819,13 +832,15 @@ class LLMEngine:
         cache has absorbed (the prompt and every emitted token but the
         last, which the next step feeds), the tokens emitted so far, and
         the request's blocks of the two pools (``pool[:, blocks]``: its
-        rows of keys and values, or a state cache's one slot) as new
-        device arrays. ``None`` when no lane carries the request."""
+        rows of keys and values, or a state cache's one slot; where a
+        lane holds a state beside its blocks, the lane's slot of the two
+        state pools) as new device arrays. ``None`` when no lane carries
+        the request."""
         with self._state_lock, self._mesh_ctx():
-            for lane in self._lanes:
+            for i, lane in enumerate(self._lanes):
                 if lane is not None and lane.req is req:
                     return (lane.pos, list(req.tokens),
-                            *self._kv.snapshot(lane.blocks))
+                            *self._kv.snapshot(lane.blocks, i))
         return None
 
     # -- scheduler ---------------------------------------------------------
@@ -1020,7 +1035,8 @@ class LLMEngine:
                         first = self._suffix_prefill(req.prompt, blocks,
                                                      n_hit)
                     elif self._chunk:
-                        first = self._chunk_prefill(req.prompt, blocks)
+                        first = self._chunk_prefill(req.prompt, blocks,
+                                                    lane_idx)
                     else:
                         first = self._full_prefill(req.prompt, blocks)
         except Exception as e:
@@ -1085,28 +1101,58 @@ class LLMEngine:
                 padded, onp.int32(p - 1), ids)
         return int(first)
 
-    def _chunk_prefill(self, prompt, blocks: List[int]) -> int:
+    def _chunk_where(self, blocks: List[int], lane_idx: int) -> tuple:
+        """Where a chunk's program finds the lane's cache: the slot of
+        its state — the block it reserved, or (a state beside blocks) the
+        lane's own index, and then also its table of blocks."""
+        if not self._geom.lane_state:
+            return (onp.int32(blocks[0]),)
+        table = onp.full((self.max_blocks_per_seq,), self._kv.trash,
+                         onp.int32)
+        table[:len(blocks)] = blocks
+        return onp.int32(lane_idx), table
+
+    def _chunk_prefill(self, prompt, blocks: List[int],
+                       lane_idx: int) -> int:
         """Prefill a prompt of any length as a loop over the one chunk
         program, the lane's state carried from chunk to chunk in its
-        slot (``blocks[0]``; the first chunk starts it from zero inside
-        the program). All of a prompt's chunks run in the tick that
+        slot (the first chunk starts it from zero inside the program).
+        All of a prompt's chunks run in the tick that
         admits it, as a whole-prompt prefill does; each is waited for,
         so that ``llm.prefill.chunk`` is the chunk's time on the chip and
         not its launch."""
         p, c = int(prompt.shape[0]), self._chunk
-        slot = onp.int32(blocks[0])
+        where = self._chunk_where(blocks, lane_idx)
         for start in range(0, p, c):
             n = min(c, p - start)
             padded = onp.zeros((1, c), onp.int32)
             padded[0, :n] = prompt[start:start + n]
             with telemetry.span("llm.prefill.chunk",
                                 args={"tokens": n, "pad": c - n,
-                                      "start": start}):
+                                      "start": start}) as sp:
                 first, = self._chunk_step(padded, onp.int32(start),
-                                          onp.int32(n), slot)
-                first = int(first)
+                                          onp.int32(n), *where)
+                first = onp.asarray(first).reshape(-1)
+                self._observe_experts(first[1:], sp.args)
             self.metrics.count("prefill_chunks")
-        return first
+        return int(first[0])
+
+    def _observe_experts(self, counts, args: dict) -> None:
+        """What a program's expert layers counted on the device, fetched
+        with its token (``ops.experts``: assignments on held experts,
+        held experts touched, the largest load of one, experts held; over
+        the layers): into the span's args, the counters and the
+        max-over-mean histogram. Nothing where the model has no such
+        layer."""
+        if not len(counts):
+            return
+        hit, touched, most, held = (int(v) for v in counts)
+        args.update(moe_assignments=hit, moe_experts_touched=touched,
+                    moe_max_load=most, moe_experts_held=held)
+        self.metrics.count("moe_assignments", hit)
+        self.metrics.count("moe_experts_touched", touched)
+        if hit:
+            self.metrics.expert_load_ratio.observe(most * held / hit)
 
     def _suffix_prefill(self, prompt, blocks: List[int], n_hit: int) -> int:
         """Prefill ONLY the uncached suffix: one multi-token paged step
@@ -1154,6 +1200,7 @@ class LLMEngine:
                 nxt, = self._decode(self._toks, self._bt, self._pos)
             with st.phase("device", "llm.decode.fetch", at) as fetch:
                 nxt = onp.asarray(nxt)
+                self._observe_experts(nxt[self.max_running:], fetch.args)
         with telemetry.span("llm.emit", args={"tokens": len(active)}):
             step_ms = (launch.dur_s + fetch.dur_s) * 1e3
             self.metrics.count("decode_steps")
@@ -1410,8 +1457,10 @@ class LLMEngine:
         """Each program that has not run yet, once, on trash tables."""
         trash = self._kv.trash
         if self._chunk and self._chunk_step.fresh:
+            # the trash slot: a block's id, or the lane past the last
             self._chunk_step(onp.zeros((1, self._chunk), onp.int32),
-                             onp.int32(0), onp.int32(1), onp.int32(trash))
+                             onp.int32(0), onp.int32(1),
+                             *self._chunk_where([trash], self.max_running))
         for b in buckets:
             if self._prefill_program(b).fresh:
                 self._full_prefill(onp.zeros((b,), onp.int32), [])
@@ -1452,6 +1501,9 @@ class LLMEngine:
             "queue_len": len(self._queue),
             "aot": aot.stats(),
         }
+        if "moe_assignments" in c:
+            out["expert_load_max_over_mean"] = \
+                self.metrics.expert_load_ratio.summary()
         if self.role is not None:
             out["role"] = self.role
             out["handoff_exported_blocks"] = int(

@@ -494,3 +494,127 @@ def test_state_prefill_program_compiles_for_v5e(retention_lm, one_chip,
                                              "state prefill 1024")
     assert not copies, copies
     assert temp < B_CHUNK * B_V * 4, temp      # no whole-chunk logits
+
+
+# --- two families of cache in one program: the Qwen3-Next programs ---------
+# Qwen3-Next-80B-A3B's published widths, one period of its layers (three
+# delta-rule + one full), one chip's 128 of 512 experts, the cell's geometry
+Q_LANES, Q_SLOTS, Q_CHUNK, Q_NB, Q_MB, Q_V = 64, 65, 2048, 40961, 1088, 37984
+
+
+@pytest.mark.parametrize("heads,kv_heads,d,pool_dtype,q_dtype", [
+    (16, 2, 256, "bfloat16", "float32"),
+    (16, 2, 256, "float32", "float32"),
+    (20, 20, 64, "bfloat16", "float32"),
+])
+def test_paged_kernel_grouped_heads_compiles_for_v5e(
+        heads, kv_heads, d, pool_dtype, q_dtype, one_chip, no_compile_cache):
+    """The paged kernel with grouped K/V heads — 16 query heads over 2
+    K/V heads of 256, a pool row of 512 — beside GPT-2-large's 20 heads
+    of 64 in the same call: the rows are whole multiples of 128 lanes in
+    both, so both are copied by hand."""
+    from mxnet_tpu.ops.pallas.paged_attention import paged_attention_kernel
+
+    pool = _s((2, NB, BS, kv_heads * d), pool_dtype)
+    fn = lambda q, k, v, bt, ln, layer: paged_attention_kernel(  # noqa: E731
+        q, k, v, bt, ln, layer, interpret=False)
+    text = _compile(fn, (_s((R, heads, d), q_dtype), pool, pool,
+                         _s((R, MB), "int32"), _s((R,), "int32"),
+                         _s((), "int32")), one_chip).as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def qwen3next_lm():
+    from mxnet_tpu import initializer as mx_init
+    from mxnet_tpu.gluon.model_zoo import qwen3next
+
+    net = qwen3next.qwen3next_like(
+        vocab_size=Q_V, num_layers=4, experts_held=128,
+        prefill_chunk=Q_CHUNK, dtype="bfloat16")
+    net.initialize(mx_init.Zero())
+    return net
+
+
+def _qwen_pools():
+    rows = _s((1, Q_NB, BS, 512), "bfloat16")
+    return (rows, rows, _s((3, Q_SLOTS, 32, 128, 128), "float32"),
+            _s((3, Q_SLOTS, 3 * 8192), "float32"))
+
+
+def _four_pool_report(compiled, pools, label):
+    """As ``_pool_report``, for the tuple of four pools: the program's
+    temporaries, the pools' bytes, and every ``copy`` whose result is
+    shaped like any of them."""
+    pool_bytes = sum(int(onp.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    shaped = {f" = {HLO_DTYPE[str(p.dtype)]}[{','.join(map(str, p.shape))}]"
+              for p in pools}
+    copies = [line.strip()[:160] for line in compiled.as_text().splitlines()
+              if " copy(" in line and any(s in line for s in shaped)]
+    print(f"{label}: pools {pool_bytes / 2**20:.1f} MiB, temporaries "
+          f"{temp / 2**20:.1f} MiB, pool-shaped copies {len(copies)}")
+    return temp, pool_bytes, copies
+
+
+def test_two_family_decode_program_compiles_for_v5e(
+        qwen3next_lm, one_chip, no_compile_cache, on_tpu):
+    """The engine's one decode program over K/V rows in blocks and a
+    state a lane — ``paged_decode_program`` as it stands, four pools
+    where the pair stood — at the cell's geometry (64 lanes, 65 slots,
+    40,960 blocks), all four donated: the delta-rule kernel takes its
+    pool aliased, the K/V rows and the convolution's tails are scattered
+    in place, and the program holds no copy shaped like a pool. The
+    three kernels print under their names. Temporaries: the 640 sorted
+    rows of each expert layer and the float32 logits of 64 rows."""
+    from mxnet_tpu.gluon.model_zoo.generation import paged_decode_program
+
+    run, params = paged_decode_program(
+        qwen3next_lm, max_running=Q_LANES, num_blocks=Q_NB, block_size=BS,
+        max_blocks_per_seq=Q_MB, kv_cache_dtype="bfloat16", donate=True)
+    pools = _qwen_pools()
+    compiled = _compile(
+        run._fn,
+        (params, _s((Q_LANES, 1), "int32"), *pools,
+         _s((Q_LANES, Q_MB), "int32"), _s((Q_LANES,), "int32"),
+         _s((2,), "uint32")), one_chip, donate=(2, 3, 4, 5))
+    text = compiled.as_text()
+    assert text.count("%gated_delta_step") >= 3
+    assert text.count("%moe_grouped_ffn") >= 4
+    temp, pool_bytes, copies = _four_pool_report(compiled, pools,
+                                                 "two-family decode")
+    assert not copies, copies
+    assert temp < 0.1 * pool_bytes, (temp, pool_bytes)
+
+
+def test_two_family_chunk_program_compiles_for_v5e(
+        qwen3next_lm, one_chip, no_compile_cache, on_tpu):
+    """The one chunk-prefill program (2,048 tokens of one lane): it
+    writes the chunk's K/V rows through the lane's table and carries the
+    state, all four pools donated and updated in place. No ``(heads,
+    chunk, context)`` score array: the attention goes a block of 512 keys
+    at a time under a loop (17,408 keys at once would be 2.3 GB; a
+    block's scores are ``f32[2,16384,512]``, 64 MiB). Temporaries stated:
+    the expert layer's 20,480 sorted rows in and out (2 x 80 MiB), a key
+    block's scores and the attention's running rows, the delta rule's
+    per-sub-chunk matrices, and the logits of ONE row."""
+    from mxnet_tpu.gluon.model_zoo.generation import state_prefill_program
+
+    run, params = state_prefill_program(
+        qwen3next_lm, chunk=Q_CHUNK, num_blocks=Q_NB, block_size=BS,
+        max_blocks_per_seq=Q_MB, kv_cache_dtype="bfloat16", donate=True)
+    pools = _qwen_pools()
+    compiled = _compile(
+        run._fn,
+        (params, _s((1, Q_CHUNK), "int32"), _s((), "int32"), _s((), "int32"),
+         *pools, _s((), "int32"), _s((Q_MB,), "int32"), _s((2,), "uint32")),
+        one_chip, donate=(4, 5, 6, 7))
+    text = compiled.as_text()
+    assert text.count("%moe_grouped_ffn") >= 4
+    assert "flash" not in text      # the chunk's attention is the XLA loop
+    assert f"f32[16,{Q_CHUNK},{Q_MB * BS}]" not in text
+    assert f"f32[2,8,{Q_CHUNK},{Q_MB * BS}]" not in text
+    temp, pool_bytes, copies = _four_pool_report(compiled, pools,
+                                                 "two-family chunk 2048")
+    assert not copies, copies
+    assert temp < 1.5 * 2 ** 30, temp

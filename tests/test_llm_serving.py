@@ -145,6 +145,13 @@ _KERNEL_CASES = [
     (4, 16, "float32", 1, "mb13", True),
     (12, 64, "bfloat16-f32q", 1, "mb13", False),
     (12, 64, "int8", 3, "mb13", False),
+    # grouped K/V heads, (query heads, K/V heads): Qwen3-Next's 16 over
+    # 2 of 256 (a pool row of 512), beside GPT-2-large's 20 of 64 above
+    ((16, 2), 256, "bfloat16-f32q", 1, "toy", False),
+    ((16, 2), 256, "float32", 1, "toy", False),
+    ((16, 2), 256, "bfloat16", 1, "mb5", False),
+    ((16, 2), 256, "bfloat16-f32q", 1, "cell", True),
+    ((4, 2), 128, "float32", 1, "mb13", True),
 ]
 
 
@@ -160,8 +167,9 @@ def test_paged_kernel_matches_jnp(heads, d, dtype, t, geometry, garbage):
     dequantize inside the kernel), bf16 pools under a float32 query (a
     bf16 model's norms hand float32 on: what the chip serves), T = 1
     (decode) and T > 1 (suffix prefill, speculative verify: the same
-    kernel on R*T virtual lanes), a layer other than 0, and the
-    geometries above. ``garbage``: every table entry past a lane's
+    kernel on R*T virtual lanes), a layer other than 0, grouped K/V
+    heads (``heads`` a pair: the pool rows hold the second number), and
+    the geometries above. ``garbage``: every table entry past a lane's
     length points at a block of 1e30s, as the engine's point at its
     trash block — what is there may be anything finite and the result
     may not feel it."""
@@ -169,13 +177,14 @@ def test_paged_kernel_matches_jnp(heads, d, dtype, t, geometry, garbage):
 
     from mxnet_tpu.ops.nn import paged_attention, paged_attention_multi
 
+    heads, kv_heads = heads if isinstance(heads, tuple) else (heads, heads)
     rng = onp.random.RandomState(1 + heads + t)
     bs, mb, lens = _KERNEL_GEOMETRIES[geometry]
     lens = onp.array(lens, onp.int32)
     r, nb, layer = len(lens), mb + 6, 2
     dtype, _, f32q = dtype.partition("-")
     qdt = "float32" if dtype == "int8" or f32q else dtype
-    kp, _, vp, _ = _pools(rng, 3, nb, bs, heads, d, dtype,
+    kp, _, vp, _ = _pools(rng, 3, nb, bs, kv_heads, d, dtype,
                           garbage_block=nb - 1 if garbage else None)
     bt = rng.randint(0, nb - 1, (r, mb)).astype(onp.int32)
     if garbage:

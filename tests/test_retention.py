@@ -398,21 +398,26 @@ def test_the_block_path_answers_as_before():
     assert geom.prefill_chunk is None and not geom.unsupported
 
 
-def test_existing_imports_do_not_load_the_new_modules():
-    """Nothing this model brings is paid for by a program that serves no
+@pytest.mark.parametrize("new", [
+    ("mxnet_tpu.ops.retention", "mxnet_tpu.ops.pallas.power_retention",
+     "mxnet_tpu.gluon.nn.retention", "mxnet_tpu.gluon.model_zoo.brumby"),
+    ("mxnet_tpu.ops.gated_delta", "mxnet_tpu.ops.gated_attention",
+     "mxnet_tpu.ops.experts", "mxnet_tpu.ops.pallas.gated_delta",
+     "mxnet_tpu.ops.pallas.moe_ffn", "mxnet_tpu.gluon.nn.qwen3next",
+     "mxnet_tpu.gluon.model_zoo.qwen3next"),
+], ids=["brumby", "qwen3next"])
+def test_existing_imports_do_not_load_the_new_modules(new):
+    """Nothing a new model brings is paid for by a program that serves no
     such model: the packages the benchmark's other cells import leave the
-    new modules out of ``sys.modules``."""
-    code = textwrap.dedent("""
+    model's modules out of ``sys.modules`` (PR 29's rule; every later
+    model is a case)."""
+    code = textwrap.dedent(f"""
         import sys
         import mxnet_tpu, mxnet_tpu.gluon, mxnet_tpu.serving
         import mxnet_tpu.ops.pallas
         from mxnet_tpu.gluon.model_zoo import bert
         from mxnet_tpu.serving import LLMEngine
-        new = ("mxnet_tpu.ops.retention",
-               "mxnet_tpu.ops.pallas.power_retention",
-               "mxnet_tpu.gluon.nn.retention",
-               "mxnet_tpu.gluon.model_zoo.brumby")
-        print([m for m in new if m in sys.modules])
+        print([m for m in {new!r} if m in sys.modules])
     """)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, check=True, env={
