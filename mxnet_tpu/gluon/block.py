@@ -15,6 +15,7 @@ reference's aux-array mutation.
 from __future__ import annotations
 
 import logging
+import math
 import re
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -233,18 +234,49 @@ class _HookHandle:
             self._list.remove(self._hook)
 
 
+def _residual_policy(prim, *avals, **params) -> bool:
+    """What a recorded forward hands its backward (a ``jax.checkpoint``
+    policy): what a kernel or a convolution gave; a matmul's result unless
+    it is larger than the two operands it can be rebuilt from (at 8,192
+    tokens the QKV and FFN-in projections are, three- and fourfold); and
+    the sum of two arrays, which is where a residual stream lives — kept,
+    each layer's backward starts from that layer's input and not from the
+    embedding. The rest — an activation, a bias add, a weight's transpose,
+    a re-layout, a dropout mask from its key — the backward program
+    rebuilds in passes that fuse into their consumers."""
+    if prim.name == "dot_general":
+        (lc, rc), (_, rb) = params["dimension_numbers"]
+        l, r = avals[0].shape, avals[1].shape
+        out = (math.prod(l) // math.prod(l[i] for i in lc)
+               * math.prod(n for i, n in enumerate(r) if i not in rc + rb))
+        return out <= math.prod(l) + math.prod(r)
+    if prim.name in ("add", "add_any"):
+        return avals[0].shape == avals[1].shape != ()
+    return prim.name in ("conv_general_dilated", "pallas_call")
+
+
 class _CachedGraph:
     """One compiled trace (the CachedOp).
 
-    Two executables, mirroring CachedOp::Forward/Backward
-    (reference cached_op.cc:759/:1004):
-    - ``fwd_fn``: jit(pure_fn) — the forward program.
-    - ``bwd_fn``: jit of vjp(pure_fn) applied to cotangents — the backward
-      program, which rematerializes the forward inside one fused XLA
-      computation. (vjp *around* an already-jitted callable fails to
-      linearize on the TPU backend, and remat-in-backward is the better
-      TPU design anyway: no residual round-trips through HBM between two
-      dispatches.)
+    Three lazily compiled programs over the one traced ``pure_fn``; which
+    of the first two a call runs is decided by whether it is recorded,
+    and a program a process never calls is never compiled:
+    - ``fwd_fn``: jit(pure_fn) — the forward of a call nothing records
+      (predict mode, ``autograd.pause``, ``grad_req="null"``, serving).
+    - ``fwd_res_fn``: jit of ``jax.vjp(pure_fn)`` — the forward of a
+      recorded call. It returns the outputs and the pullback's residuals,
+      so the net's forward runs once a training step (CachedOp::Forward
+      with its saved entries, reference cached_op.cc:759).
+    - ``bwd_fn``: jit of that pullback applied to the cotangents
+      (CachedOp::Backward, reference cached_op.cc:1004): the transposes,
+      and of the forward only what :func:`_residual_policy` leaves to be
+      rebuilt.
+    The residuals are the arrays the recorded forward hands over and live
+    in the tape node's closure alone: ``ops.dispatch._backward`` drops it
+    after the node ran (unless ``retain_graph``). A residual that is one
+    of the program's own inputs (a weight, the tokens) or outputs is not
+    returned a second time — XLA would copy it — but named in ``res_plan``
+    and taken from the call's own arrays.
     ``diff_idx`` are the positions (params + float inputs) the backward
     differentiates; cotangents for untracked inputs are simply dropped by
     the tape router.
@@ -252,7 +284,9 @@ class _CachedGraph:
 
     __slots__ = (
         "fwd_fn",
+        "fwd_res_fn",
         "bwd_fn",
+        "res_plan",
         "n_outputs",
         "out_treedef",
         "mutated_params",
@@ -261,9 +295,16 @@ class _CachedGraph:
         "warm",
     )
 
-    def __init__(self, fwd_fn, bwd_fn, n_outputs, out_treedef, mutated_params, param_list, diff_idx):
+    def __init__(self, fwd_fn, fwd_res_fn, bwd_fn, res_plan, n_outputs,
+                 out_treedef, mutated_params, param_list, diff_idx):
         self.fwd_fn = fwd_fn
+        self.fwd_res_fn = fwd_res_fn
         self.bwd_fn = bwd_fn
+        # written by fwd_res_fn's trace: the pullback's treedef; per leaf
+        # where it comes from (src: "res" / "in" / "out" and a position
+        # among the residuals / the call's inputs / its outputs); and
+        # nbytes, the bytes of the "res" leaves by their avals
+        self.res_plan = res_plan
         self.n_outputs = n_outputs
         self.out_treedef = out_treedef
         self.mutated_params = mutated_params
@@ -539,14 +580,14 @@ class HybridBlock(Block):
         return result
 
     def _invoke_cached(self, cg: _CachedGraph, arrays, n_total):
-        """Run the compiled forward; under autograd, record a tape node whose
-        pullback is the compiled backward (CachedOp::Backward)."""
+        """Run the compiled forward. A call autograd records runs the
+        program that also returns the pullback's residuals, and its tape
+        node's pullback is the compiled backward over them
+        (CachedOp::Backward); any other call runs the plain forward."""
         from ..ops.dispatch import TapeNode, _differentiable
 
         st = autograd_state
         vals = [_unwrap(a) for a in arrays]
-        out_vals = cg.fwd_fn(*vals)
-        outs = tuple(_wrap(v) for v in out_vals)
 
         record = st.recording and st.tape is not None
         if record:
@@ -560,21 +601,28 @@ class HybridBlock(Block):
                 )
                 for a in diff_arrays
             )
-        if record:
-            bwd = cg.bwd_fn
+        if not record:
+            return tuple(_wrap(v) for v in cg.fwd_fn(*vals))
 
-            def vjp_fn(cts):
-                full = cts if isinstance(cts, tuple) else (cts,)
-                return bwd(tuple(full), *vals)
+        out_vals, res = cg.fwd_res_fn(*vals)
+        outs = tuple(_wrap(v) for v in out_vals)
+        # the node's closure is the residuals' only owner: _backward drops
+        # it after the node ran, and the arrays go with it
+        bwd = cg.bwd_fn
 
-            node = TapeNode(
-                vjp_fn,
-                [arrays[i] for i in cg.diff_idx],
-                n_total,
-                type(self).__name__ + "_cached",
-                out_avals=[(o.shape, o.dtype) for o in outs],
-            )
-            st.tape.add(node, outs)
+        def vjp_fn(cts):
+            full = cts if isinstance(cts, tuple) else (cts,)
+            return bwd(res, vals, out_vals, tuple(full))
+
+        node = TapeNode(
+            vjp_fn,
+            [arrays[i] for i in cg.diff_idx],
+            n_total,
+            type(self).__name__ + "_cached",
+            out_avals=[(o.shape, o.dtype) for o in outs],
+            residual_bytes=cg.res_plan["nbytes"],
+        )
+        st.tape.add(node, outs)
         return outs
 
     def _ensure_params_ready(self, args):
@@ -656,22 +704,53 @@ class HybridBlock(Block):
         diff_idx = [i for i, v in enumerate(probe_vals[:-1])
                     if _differentiable(v)]
 
+        # jax.jit compiles on the first call: a process that only trains
+        # never compiles fwd_fn, one that only serves neither of the others
         fwd_fn = jax.jit(pure_fn)
+        plan = {}
 
-        def bwd(cts, *vals):
+        def fwd_res(*vals):
             def for_diff(*dvals):
                 full = list(vals)
                 for i, dv in zip(diff_idx, dvals):
                     full[i] = dv
                 return pure_fn(*full)
 
-            _, vjp = jax.vjp(for_diff, *[vals[i] for i in diff_idx])
-            return vjp(tuple(cts))
+            outs, pull = jax.vjp(
+                jax.checkpoint(for_diff, prevent_cse=False,
+                               policy=_residual_policy),
+                *[vals[i] for i in diff_idx])
+            leaves, treedef = jax.tree_util.tree_flatten(pull)
+            # a residual that is an input (a weight, the tokens) or an
+            # output of this program is named, not returned a second time:
+            # XLA would hand back a copy of it
+            where = {id(v): ("in", i) for i, v in enumerate(vals)}
+            for j, o in enumerate(outs):
+                where.setdefault(id(o), ("out", j))
+            res, src = [], []
+            for leaf in leaves:
+                at = where.get(id(leaf))
+                if at is None:
+                    at = where[id(leaf)] = ("res", len(res))
+                    res.append(leaf)
+                src.append(at)
+            plan.update(
+                treedef=treedef, src=src,
+                nbytes=sum(math.prod(r.shape) * r.dtype.itemsize
+                           for r in res))
+            return outs, res
 
-        bwd_fn = jax.jit(bwd)
+        def bwd(res, vals, outs, cts):
+            pick = {"res": res, "in": vals, "out": outs}
+            pull = jax.tree_util.tree_unflatten(
+                plan["treedef"], [pick[k][n] for k, n in plan["src"]])
+            return pull(cts)
+
         return _CachedGraph(
             fwd_fn,
-            bwd_fn,
+            jax.jit(fwd_res),
+            jax.jit(bwd),
+            plan,
             out_info["n_outputs"],
             out_info["treedef"],
             mutated_params,

@@ -72,9 +72,11 @@ class TapeNode:
         "out_avals",
         "name",
         "req_grad",
+        "residual_bytes",
     )
 
-    def __init__(self, vjp_fn, inputs, n_out, name, out_avals=(), replay_fn=None):
+    def __init__(self, vjp_fn, inputs, n_out, name, out_avals=(), replay_fn=None,
+                 residual_bytes=0):
         self.vjp_fn = vjp_fn
         self.replay_fn = replay_fn  # pure fn(*input_vals) for higher-order replay
         self.inputs = inputs  # list of ndarray refs (keeps leaves alive)
@@ -86,6 +88,9 @@ class TapeNode:
         self.out_avals = out_avals  # [(shape, dtype)] for zero cotangents
         self.name = name
         self.req_grad = True
+        # bytes a compiled forward handed this node's pullback (a
+        # hybridized block's recorded call); 0 for an eager op
+        self.residual_bytes = residual_bytes
 
 
 class Tape:
@@ -249,7 +254,9 @@ def _backward(tape: Tape, heads, head_grads, retain_graph: bool, sp) -> None:
     """The sweep of :func:`backward` inside its ``autograd.backward``
     span ``sp``. Each pullback runs under an ``autograd.node:<op>``
     annotation (no ring row: an un-hybridized net has thousands a step);
-    the ring gets their sums as ``sp.args["by_op"]``."""
+    the ring gets their sums as ``sp.args["by_op"]``, and as
+    ``sp.args["residual_bytes"]`` what compiled forwards handed the nodes
+    that ran (0: no hybridized block's call was recorded)."""
     import jax.numpy as jnp
 
     from .. import engine as _engine
@@ -292,6 +299,7 @@ def _backward(tape: Tape, heads, head_grads, retain_graph: bool, sp) -> None:
 
     # reverse topological sweep — tape order is already topological
     by_op: dict = {}    # op name -> [wall s, pullbacks called]
+    residual_bytes = 0
     for idx in range(len(tape.nodes) - 1, -1, -1):
         node = tape.nodes[idx]
         slots = [cots.get((idx, s)) for s in range(node.n_out)]
@@ -318,6 +326,7 @@ def _backward(tape: Tape, heads, head_grads, retain_graph: bool, sp) -> None:
             acc = by_op[node.name] = [0.0, 0]
         acc[0] += nsp.dur_s
         acc[1] += 1
+        residual_bytes += node.residual_bytes
         for arr, ct in zip(node.inputs, in_cts):
             _route(arr, ct)
         if not retain_graph:
@@ -325,6 +334,7 @@ def _backward(tape: Tape, heads, head_grads, retain_graph: bool, sp) -> None:
             node.replay_fn = None
 
     sp.args["ran"] = sum(acc[1] for acc in by_op.values())
+    sp.args["residual_bytes"] = residual_bytes
     sp.args["by_op"] = {
         name: [round(acc[0] * 1e3, 3), acc[1]]
         for name, acc in sorted(by_op.items(),

@@ -298,6 +298,81 @@ def test_train_step_program_compiles_for_v5e(lm, one_chip,
     assert compiled.as_text().count("tpu_custom_call") >= 2 * 5 + 1
 
 
+def test_recorded_forward_and_backward_compile_for_v5e(lm, one_chip,
+                                                       no_compile_cache,
+                                                       on_tpu):
+    """The two programs of a hybridized block's recorded call (PR 35) on a
+    batch of 8 x 1024: the forward that also returns the pullback's
+    residuals, and the backward over them. The backward holds each
+    matmul's two transposes and, of the forward, only the two matmuls a
+    layer whose results are larger than their operands (QKV, FFN in: the
+    policy rebuilds those) — no flash forward, no norm kernel; no weight
+    comes back from the forward; and a layer hands over 196 MB: by hand
+    4 x 25.2 (the two sums of the residual stream, the two norms'
+    outputs) + 25.2 (the flash kernel's ``o``) + 50.3 (its ``lse``,
+    padded to 128 lanes) + 21 (the norms' statistics); with two layers
+    the embedding's sum and the final norm add 14 MB a layer."""
+    import re
+
+    import mxnet_tpu as mx
+
+    x = mx.np.array(onp.zeros((8, L), onp.int32))
+    flat, treedef = jax.tree_util.tree_flatten((x,))
+    cg = lm._build_cache((x,), flat, treedef, True,
+                         lm._ensure_params_ready((x,)))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = [p.data()._data for _, p in cg.param_list]
+    vals = [on_chip(v) for v in params + [x._data, jax.random.PRNGKey(0)]]
+    fwd = cg.fwd_res_fn.lower(*vals).compile()
+    plan = cg.res_plan
+    outs, res = jax.eval_shape(cg.fwd_res_fn, *vals)
+    outs = tuple(on_chip(o) for o in outs)
+    bwd = cg.bwd_fn.lower([on_chip(r) for r in res], vals, outs,
+                          outs).compile()
+
+    fm, bm = fwd.memory_analysis(), bwd.memory_analysis()
+    logits = 8 * L * V * 4
+    mb = 1 / 2**20
+    print(f"recorded forward: outputs {fm.output_size_in_bytes * mb:.1f} MiB "
+          f"(logits {logits * mb:.1f} + residuals {plan['nbytes'] * mb:.1f}"
+          f", {plan['nbytes'] / 2 / 1e6:.1f} MB a layer), temporaries "
+          f"{fm.temp_size_in_bytes * mb:.1f} MiB; backward: arguments "
+          f"{bm.argument_size_in_bytes * mb:.1f} MiB, temporaries "
+          f"{bm.temp_size_in_bytes * mb:.1f} MiB, outputs "
+          f"{bm.output_size_in_bytes * mb:.1f} MiB")
+    assert 150e6 < plan["nbytes"] / 2 < 225e6
+    assert abs(fm.output_size_in_bytes - logits - plan["nbytes"]) < 2**20
+    assert fm.temp_size_in_bytes < 2 * 2**30
+    assert bm.temp_size_in_bytes < 2**30
+
+    # every weight is named as the forward's own input, none returned:
+    # no copy shaped like a parameter (or its transpose) in the forward
+    named = {(k, n) for k, n in plan["src"] if k != "res"}
+    assert len(named) >= 2 * 4 + 2 and all(k == "in" for k, _ in named)
+    shapes = {tuple(p.shape) for p in params if p.ndim == 2}
+    shapes |= {s[::-1] for s in shapes}
+    copies = [tuple(int(n) for n in dims.split(","))
+              for dims in re.findall(r"\[([\d,]+)\]\{[^}]*\} copy\(",
+                                     fwd.as_text())]
+    assert copies and not [c for c in copies if c in shapes]
+
+    fwd_text, bwd_text = fwd.as_text(), bwd.as_text()
+    matmuls = fwd_text.count(" convolution(")
+    assert matmuls == 2 * 4 + 1
+    assert bwd_text.count(" convolution(") == 2 * matmuls + 2 * 2
+    # forward: flash + two norms a layer, the final norm; backward: the
+    # flash kernel's dq and dkv a layer and nothing of the forward
+    assert fwd_text.count("tpu_custom_call") == 2 * 3 + 1
+    assert "_flash_forward" not in bwd_text
+    kernels = re.findall(r'custom-call\(.*tpu_custom_call.*op_name="([^"]*)"',
+                         bwd_text)
+    assert len(kernels) == 2 * 2
+    assert all("_flash_bwd_pallas" in k for k in kernels), kernels
+
+
 def _pool_report(compiled, pool, label):
     """What the chip's compiler says of a program that is handed both
     pools donated: its temporaries and the two pools, in bytes, and the
