@@ -54,7 +54,12 @@ class CacheGeometry:
     ``state_prefill_program``. ``cache_dtypes``: the pool dtypes the model
     can hold (None: those of ``_resolve_cache_dtype``); the first is its
     default. ``unsupported``: engine feature -> why this cache cannot
-    carry it yet."""
+    carry it yet. ``row_layers``: where the layers that keep K/V rows are
+    of two kinds, ``(full layers, window layers, window)`` — a full layer
+    keeps every position's row in the request's blocks (what
+    ``blocks_for`` counts), a window layer the last ``window`` rows in a
+    ring that is one of the lane's pools; the engine's gauges of the rows
+    held by family read it."""
     kind: str
     blocks_for: Callable[[int], int]
     max_positions: Optional[int] = None
@@ -62,6 +67,7 @@ class CacheGeometry:
     cache_dtypes: Optional[tuple] = None
     unsupported: dict = dataclasses.field(default_factory=dict)
     lane_state: bool = False
+    row_layers: Optional[tuple] = None
 
 
 class _StepAdapter(HybridBlock):

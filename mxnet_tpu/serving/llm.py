@@ -290,6 +290,16 @@ class LLMMetrics:
             "llm_handoff_exported_blocks_total",
             "KV blocks exported by a prefill-role engine for handoff",
             ("engine",)).labels(**eng)
+        # rows of keys and values the cache holds for live requests, by
+        # family: every position in a full layer's blocks, the last
+        # ``window`` in a window layer's ring (CacheGeometry.row_layers)
+        self._kv_rows = reg.gauge(
+            "llm_kv_rows", "K/V rows held for live requests, by the kind "
+            "of layer that holds them", ("engine", "family"))
+        self.kv_rows_full = self._kv_rows.labels(engine=engine_id,
+                                                 family="full")
+        self.kv_rows_window = self._kv_rows.labels(engine=engine_id,
+                                                   family="window")
         self.token_latency_ms = reg.histogram(
             "llm_token_latency_ms",
             "Per-token latency (decode step wall / tokens in step)",
@@ -866,11 +876,28 @@ class LLMEngine:
         with self._state_lock, self._mesh_ctx():
             return self._tick_locked()
 
+    def _observe_rows(self) -> dict:
+        """The K/V rows the cache holds for the requests in the lanes, by
+        family, where the model's layers are of two kinds (``{}`` where
+        they are not): positions x full layers, and the last ``window``
+        of them x window layers. The tick sets the two gauges here and
+        nothing else does; ``stats()`` reads them back."""
+        if self._geom.row_layers is None:
+            return {}
+        full, ring, window = self._geom.row_layers
+        at = [ln.pos for ln in self._lanes if ln is not None]
+        held = {"kv_rows_full": full * sum(at),
+                "kv_rows_window": ring * sum(min(p, window) for p in at)}
+        self.metrics.kv_rows_full.set(held["kv_rows_full"])
+        self.metrics.kv_rows_window.set(held["kv_rows_window"])
+        return held
+
     def _tick_locked(self):
         occupied = sum(1 for ln in self._lanes if ln is not None)
         with telemetry.span("llm.tick", args={
                 "active": occupied, "queue_len": len(self._queue),
-                "blocks_in_use": self._kv.blocks_in_use}) as tick:
+                "blocks_in_use": self._kv.blocks_in_use,
+                **self._observe_rows()}) as tick:
             if self._step_hook is not None:
                 # inside the containment: a hook fault (e.g. an armed
                 # serving.fleet.replica chaos rule) routes through _fault
@@ -1501,6 +1528,9 @@ class LLMEngine:
             "queue_len": len(self._queue),
             "aot": aot.stats(),
         }
+        if self._geom.row_layers is not None:
+            out["kv_rows_full"] = int(self.metrics.kv_rows_full.get())
+            out["kv_rows_window"] = int(self.metrics.kv_rows_window.get())
         if "moe_assignments" in c:
             out["expert_load_max_over_mean"] = \
                 self.metrics.expert_load_ratio.summary()
@@ -1604,7 +1634,8 @@ class LLMEngine:
                   self.metrics.lanes_total, self.metrics.pool_free,
                   self.metrics.pool_total, self.metrics.kv_spill_blocks,
                   self.metrics.kv_spill_bytes, self.metrics.shard_devices,
-                  self.metrics.shard_pool_bytes):
+                  self.metrics.shard_pool_bytes, self.metrics.kv_rows_full,
+                  self.metrics.kv_rows_window):
             g.set(0)
         self._kv.close()
 

@@ -693,3 +693,87 @@ def test_two_family_chunk_program_compiles_for_v5e(
                                                  "two-family chunk 2048")
     assert not copies, copies
     assert temp < 1.5 * 2 ** 30, temp
+
+
+# --- window layers that keep a ring beside full layers (PR 36) ---------------
+# the cell laguna-serve-backlog-32k's geometry: 24 lanes (+ the trash slot),
+# blocks of 16, a table of 2,112 blocks (33,792 positions), chunks of 1,024
+G_LANES, G_SLOTS, G_CHUNK, G_NB, G_MB, G_V = 24, 25, 1024, 28673, 2112, 12544
+
+
+@pytest.fixture(scope="module")
+def laguna_lm():
+    """The leading dense layer and one period (full, three window layers,
+    and the next period's full layer) at the published widths, one chip's
+    32 of 256 experts."""
+    from mxnet_tpu import initializer as mx_init
+    from mxnet_tpu.gluon.model_zoo import laguna
+
+    net = laguna.laguna_like(vocab_size=G_V, num_layers=5, experts_held=32,
+                             prefill_chunk=G_CHUNK, dtype="bfloat16")
+    net.initialize(mx_init.Zero())
+    return net
+
+
+def _laguna_pools():
+    rows = _s((2, G_NB, BS, 1024), "bfloat16")
+    ring = _s((3, G_SLOTS, 512, 1024), "bfloat16")
+    return rows, rows, ring, ring
+
+
+def test_ring_decode_program_compiles_for_v5e(
+        laguna_lm, one_chip, no_compile_cache, on_tpu):
+    """The engine's one decode program over K/V rows in blocks and rings
+    of 512 rows a lane, all four pools donated: one paged kernel takes 48
+    query rows over a lane's table and 72 over its ring seen as 32 fixed
+    blocks (the reshape of a ring is the same bytes: no copy shaped like
+    a pool), rows of 1,024 values copied by hand. The expert kernel's
+    blocks are 3 x 3,072 x 1,024."""
+    from mxnet_tpu.gluon.model_zoo.generation import paged_decode_program
+
+    run, params = paged_decode_program(
+        laguna_lm, max_running=G_LANES, num_blocks=G_NB, block_size=BS,
+        max_blocks_per_seq=G_MB, kv_cache_dtype="bfloat16", donate=True)
+    pools = _laguna_pools()
+    compiled = _compile(
+        run._fn,
+        (params, _s((G_LANES, 1), "int32"), *pools,
+         _s((G_LANES, G_MB), "int32"), _s((G_LANES,), "int32"),
+         _s((2,), "uint32")), one_chip, donate=(2, 3, 4, 5))
+    text = compiled.as_text()
+    assert text.count("%moe_grouped_ffn") >= 4
+    assert f"bf16[{G_LANES},72,128]" in text and \
+        f"bf16[{G_LANES},48,128]" in text
+    temp, pool_bytes, copies = _four_pool_report(compiled, pools,
+                                                 "ring decode")
+    assert not copies, copies
+    assert temp < 0.1 * pool_bytes, (temp, pool_bytes)
+
+
+def test_ring_chunk_program_compiles_for_v5e(
+        laguna_lm, one_chip, no_compile_cache, on_tpu):
+    """The one chunk-prefill program (1,024 tokens of one lane): a full
+    layer's rows through the table and its attention a block of 512 keys
+    at a time; a window layer's attention over the ring's rows and its
+    own, 256 queries against 768 keys at a time, never ``(72, 1024,
+    1536)`` scores at once; all four pools donated and updated in place.
+    Temporaries stated: the expert layer's 10,240 sorted rows in and out,
+    a key block's scores, the dense layer's 1,024 x 12,288 rows."""
+    from mxnet_tpu.gluon.model_zoo.generation import state_prefill_program
+
+    run, params = state_prefill_program(
+        laguna_lm, chunk=G_CHUNK, num_blocks=G_NB, block_size=BS,
+        max_blocks_per_seq=G_MB, kv_cache_dtype="bfloat16", donate=True)
+    pools = _laguna_pools()
+    compiled = _compile(
+        run._fn,
+        (params, _s((1, G_CHUNK), "int32"), _s((), "int32"), _s((), "int32"),
+         *pools, _s((), "int32"), _s((G_MB,), "int32"), _s((2,), "uint32")),
+        one_chip, donate=(4, 5, 6, 7))
+    text = compiled.as_text()
+    assert text.count("%moe_grouped_ffn") >= 4
+    assert "f32[8,9216,1536]" not in text and "f32[72,1024,1536]" not in text
+    temp, pool_bytes, copies = _four_pool_report(compiled, pools,
+                                                 "ring chunk 1024")
+    assert not copies, copies
+    assert temp < 1.5 * 2 ** 30, temp

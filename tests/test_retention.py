@@ -405,7 +405,9 @@ def test_the_block_path_answers_as_before():
      "mxnet_tpu.ops.experts", "mxnet_tpu.ops.pallas.gated_delta",
      "mxnet_tpu.ops.pallas.moe_ffn", "mxnet_tpu.gluon.nn.qwen3next",
      "mxnet_tpu.gluon.model_zoo.qwen3next"),
-], ids=["brumby", "qwen3next"])
+    ("mxnet_tpu.gluon.nn.laguna", "mxnet_tpu.gluon.model_zoo.laguna",
+     "mxnet_tpu.ops.gated_attention"),
+], ids=["brumby", "qwen3next", "laguna"])
 def test_existing_imports_do_not_load_the_new_modules(new):
     """Nothing a new model brings is paid for by a program that serves no
     such model: the packages the benchmark's other cells import leave the
