@@ -405,8 +405,6 @@ def rows(lo_s: float, hi_s: float,
 # ---------------------------------------------------------------------------
 # registry families (registered once at import; children created lazily)
 _reg = get_registry()
-_steps_total = _reg.counter(
-    "telemetry_steps_total", "Steps timed by telemetry.step", ("name",))
 _step_ms = _reg.histogram(
     "telemetry_step_ms", "Step wall time (ms)", ("name",))
 _bucket_ms = _reg.histogram(
@@ -580,7 +578,6 @@ class StepTimeline:
             args.update(self._annotations)
         # the row and the annotation end here; the registry is not in them
         self._span.__exit__(*exc)
-        _steps_total.labels(name=self.name).inc()
         _step_ms.labels(name=self.name).observe(self._wall * 1e3)
         for bucket, dur in att.items():
             _bucket_ms.labels(name=self.name,

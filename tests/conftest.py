@@ -51,6 +51,29 @@ def _seed_rng(request):
     print(f"[test seed: {seed}]")
 
 
+@pytest.fixture(autouse=True)
+def _flight_arming():
+    """The flight recorder's arming as it was before the test: an
+    explicit ``flight.arm`` outranks ``MXNET_TPU_FLIGHT_DIR``, so one left
+    armed sends every later test's dumps to its directory."""
+    import sys
+
+    def arming():
+        flight = sys.modules.get("mxnet_tpu.telemetry.flight")
+        if flight is None:
+            return None, None
+        rec = flight.recorder
+        return rec, (rec._dir, rec._default_dir, rec._baseline)
+
+    _, before = arming()
+    yield
+    rec, after = arming()
+    if rec is not None and after != before:
+        with rec._lock:
+            rec._dir, rec._default_dir, rec._baseline = \
+                before or (None, None, None)
+
+
 @pytest.fixture
 def lockwatch_armed(monkeypatch):
     """Opt-in runtime lock-order witness (the C001 property checked

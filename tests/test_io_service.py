@@ -226,37 +226,34 @@ def test_kill_decode_worker_mid_epoch_exactly_once(tmp_path,
     from mxnet_tpu.telemetry.registry import get_registry
 
     fdir = str(tmp_path / "flight")
-    flight.arm(fdir)
-    try:
-        src = SyntheticSource(n_batches=30, batch_size=2, dim=4, seed=3,
-                              decode_cost_s=0.05)
-        svc = DatasetService(str(tmp_path / "root"), src, num_workers=2,
-                             range_size=5, heartbeat_s=0.1,
-                             stale_after_s=0.6)
-        with svc:
-            svc.start()
-            svc.start_epoch(0)
-            # generous fetch deadline: worker spawn pays a multi-second
-            # import before the first beat, and tier-1 runs under load
-            s = svc.stream(local_fallback=False, fetch_deadline_s=120.0)
-            out = [next(s) for _ in range(2)]
-            _kill_while_holding_unserved_claim(svc, wid=0)
-            out += [next(s) for _ in range(28)]
-        # bitwise-identical to the sequential shard union
-        ids = []
-        for i, (data, label) in enumerate(out):
-            d_ref, l_ref = src.read(i)
-            assert (data == d_ref).all() and (label == l_ref).all()
-            ids.extend(int(v) for v in label[:, 0])
-        # zero lost, zero duplicated: the sample-id union is exact
-        assert sorted(ids) == list(range(30 * 2))
-        fams = get_registry().snapshot()["metrics"]
-        red = fams["io_service_ranges_redispatched_total"]["series"]
-        assert red and red[0]["value"] >= 1
-        lost = fams["io_service_workers_lost_total"]["series"]
-        assert any(sr["labels"].get("worker") == "0" for sr in lost)
-    finally:
-        flight.recorder._dir = None  # un-arm: no module-level disarm
+    flight.arm(fdir)      # the conftest restores the arming afterwards
+    src = SyntheticSource(n_batches=30, batch_size=2, dim=4, seed=3,
+                          decode_cost_s=0.05)
+    svc = DatasetService(str(tmp_path / "root"), src, num_workers=2,
+                         range_size=5, heartbeat_s=0.1,
+                         stale_after_s=0.6)
+    with svc:
+        svc.start()
+        svc.start_epoch(0)
+        # generous fetch deadline: worker spawn pays a multi-second
+        # import before the first beat, and tier-1 runs under load
+        s = svc.stream(local_fallback=False, fetch_deadline_s=120.0)
+        out = [next(s) for _ in range(2)]
+        _kill_while_holding_unserved_claim(svc, wid=0)
+        out += [next(s) for _ in range(28)]
+    # bitwise-identical to the sequential shard union
+    ids = []
+    for i, (data, label) in enumerate(out):
+        d_ref, l_ref = src.read(i)
+        assert (data == d_ref).all() and (label == l_ref).all()
+        ids.extend(int(v) for v in label[:, 0])
+    # zero lost, zero duplicated: the sample-id union is exact
+    assert sorted(ids) == list(range(30 * 2))
+    fams = get_registry().snapshot()["metrics"]
+    red = fams["io_service_ranges_redispatched_total"]["series"]
+    assert red and red[0]["value"] >= 1
+    lost = fams["io_service_workers_lost_total"]["series"]
+    assert any(sr["labels"].get("worker") == "0" for sr in lost)
     dumps = [n for n in os.listdir(fdir) if "io_worker_lost-w0" in n]
     assert dumps, f"no worker-lost flight dump in {os.listdir(fdir)}"
     with open(os.path.join(fdir, dumps[0])) as f:

@@ -376,6 +376,7 @@ def test_engine_serves_rings_beside_blocks_and_counts_the_rows():
     net = make_net(chunk=12)
     params = params_of(net)
     seen, box = [], []
+    t0 = time.perf_counter()     # the ring holds other tests' ticks too
     eng = LLMEngine(net, max_running=3, block_size=BS, max_context=96,
                     num_blocks=72, kv_cache_dtype="float32",
                     step_hook=lambda: seen.extend(e.stats() for e in box))
@@ -407,7 +408,7 @@ def test_engine_serves_rings_beside_blocks_and_counts_the_rows():
     assert all(w <= 3 * 3 * 32 for _, w in rows)
     assert max(w for _, w in rows) == 3 * 3 * 32
     assert st["kv_rows_full"] == 0 and st["kv_rows_window"] == 0
-    ticks = [a for _, _, _, a in tracing.rows(0.0, float("inf"), "llm.tick")
+    ticks = [a for _, _, _, a in tracing.rows(t0, float("inf"), "llm.tick")
              if "kv_rows_window" in a]
     assert ticks and max(a["kv_rows_window"] for a in ticks) == 288
     # K, V of 2 layers x 73 blocks x 4 rows and rings of 3 layers x 4

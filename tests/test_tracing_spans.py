@@ -373,6 +373,124 @@ def test_admission_and_prefill_carry_the_request(served):
                                                                    abs=1e-3)
 
 
+def _calls_in_order(rows):
+    """The ``llm.upload`` and ``llm.result`` rows in time order, checked
+    to alternate: every program call uploads once and then fetches once."""
+    seq = sorted((r for r in rows if r[0] in ("llm.upload", "llm.result")),
+                 key=lambda r: r[1])
+    assert [r[0] for r in seq] == ["llm.upload", "llm.result"] * (
+        len(seq) // 2)
+    return list(zip(seq[::2], seq[1::2]))
+
+
+def _only_child(rows, parent, name):
+    kids = [r for r in rows if r[3].get("parent") == parent[3]["id"]
+            and r[0] == name]
+    assert len(kids) == 1, (parent, kids)
+    return kids[0]
+
+
+def test_every_program_call_uploads_once_and_fetches_once(served):
+    rows, stats, _ = served
+    calls = _calls_in_order(rows)
+    steps = [r for r in rows if r[0] == "step[llm_decode]"]
+    prefills = [r for r in rows if r[0] == "llm.prefill"]
+    assert len(calls) == len(steps) + len(prefills) \
+        == stats["counters"]["decode_steps"] + stats["counters"]["prefills"]
+    nested = set()
+    for st in steps:
+        kids = {r[0]: r for r in rows if r[3].get("parent") == st[3]["id"]}
+        up = _only_child(rows, kids["llm.decode.launch"], "llm.upload")
+        res = _only_child(rows, kids["llm.decode.fetch"], "llm.result")
+        # toks (2, 1), the block table (2, 32 / 4), positions (2,): int32
+        assert up[3]["bytes"] == 4 * (2 + 2 * 8 + 2)
+        nested.update((up[3]["id"], res[3]["id"]))
+    admits = {r[3]["id"]: r for r in rows if r[0] == "llm.admit"}
+    by_id = {r[3]["id"]: r for r in rows}
+    for pre in prefills:
+        up = _only_child(rows, pre, "llm.upload")
+        res = _only_child(rows, pre, "llm.result")
+        assert up[2] <= res[1]
+        bucket = admits[by_id[pre[3]["parent"]][3]["parent"]][3]["bucket"]
+        # the padded prompt, the last index, the bucket's block ids
+        assert up[3]["bytes"] == 4 * (bucket + 1 + bucket // 4)
+        nested.update((up[3]["id"], res[3]["id"]))
+    assert nested == {r[3]["id"] for up_res in calls for r in up_res}
+
+
+def test_a_chunked_prefill_uploads_and_fetches_once_a_chunk():
+    from mxnet_tpu.gluon.model_zoo import brumby
+
+    onp.random.seed(0)
+    net = brumby.brumby_like(
+        vocab_size=37, units=32, hidden_size=48, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=8, max_length=256, prefill_chunk=16)
+    net.initialize()
+    eng = LLMEngine(net, max_running=2, block_size=4, max_context=64,
+                    kv_cache_dtype=None)
+    try:
+        eng.warmup()
+        t0 = time.perf_counter()
+        eng.submit(onp.arange(1, 38) % 37, 3).wait()
+        rows = _rows(t0)
+    finally:
+        eng.close()
+    chunks = sorted((r for r in rows if r[0] == "llm.prefill.chunk"),
+                    key=lambda r: r[1])
+    assert [c[3]["tokens"] for c in chunks] == [16, 16, 5]
+    for c in chunks:
+        up = _only_child(rows, c, "llm.upload")
+        res = _only_child(rows, c, "llm.result")
+        assert up[2] <= res[1]
+        # a chunk of 16 tokens, its start, its length, the state's slot
+        assert up[3]["bytes"] == 4 * (16 + 3)
+    assert len(_calls_in_order(rows)) == len(chunks) \
+        + len([r for r in rows if r[0] == "step[llm_decode]"])
+
+
+def test_a_sharded_engine_uploads_replicated_and_compiles_after_warmup_nothing():
+    import jax
+
+    from mxnet_tpu.parallel.mesh import make_mesh
+
+    onp.random.seed(0)
+    net = bert.gpt_like(vocab_size=64, units=16, hidden_size=32,
+                        num_layers=2, num_heads=4, max_length=64,
+                        dropout=0.0)
+    net.initialize()
+    prompt = onp.random.RandomState(13).randint(1, 64, (14,))
+    with LLMEngine(net, max_running=2, block_size=4, max_context=32,
+                   kv_cache_dtype="float32") as one:
+        expect = list(one.generate(prompt, 4))
+    compiled = []
+
+    def count(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(count)
+    eng = LLMEngine(net, max_running=2, block_size=4, max_context=32,
+                    kv_cache_dtype="float32",
+                    mesh=make_mesh({"tp": 4}, devices=jax.devices()[:4]))
+    try:
+        sharding = eng._upload_to
+        assert sharding.is_fully_replicated
+        assert len(sharding.device_set) == 4
+        eng.warmup(prompt_lengths=[len(prompt)])
+        before = (len(compiled), eng.stats()["counters"]["compiles"])
+        t0 = time.perf_counter()
+        got = list(eng.generate(prompt, 4))
+        after = (len(compiled), eng.stats()["counters"]["compiles"])
+        rows = _rows(t0)
+    finally:
+        eng.close()
+        jax.monitoring.unregister_event_duration_listener(count)
+    assert got == expect
+    assert after == before
+    assert len(_calls_in_order(rows)) == 1 + len(
+        [r for r in rows if r[0] == "step[llm_decode]"])
+
+
 def test_one_lane_the_second_request_waits_out_the_first():
     t0 = time.perf_counter()
     eng = _engine(_tiny_lm(), lanes=1)
